@@ -5,7 +5,7 @@ model mid-stream and affected lanes become empty predictions — the stream
 never dies. Mirrors the reference's ``withSupportStream`` dynamic API
 (SURVEY.md §4.3).
 
-Run:  python examples/dynamic_serving.py [--platform cpu]
+Run:  python examples/dynamic_serving.py
 """
 
 import pathlib
@@ -17,9 +17,9 @@ try:  # installed package (pip install -e .)
 except ImportError:  # source checkout without install: add the repo root
     sys.path.insert(0, str(pathlib.Path(__file__).resolve().parent.parent))
 
+import jax
 import numpy as np
 
-from flink_jpmml_tpu.utils.demo import demo_backend
 from flink_jpmml_tpu.assets_gen import gen_iris_lr
 from flink_jpmml_tpu.models.control import AddMessage, DelMessage
 from flink_jpmml_tpu.runtime.sources import ControlSource
@@ -27,7 +27,7 @@ from flink_jpmml_tpu.serving import DynamicScorer
 
 
 def main() -> None:
-    print(f"backend: {demo_backend()}")
+    print(f"backend: {jax.default_backend()}")
     workdir = tempfile.mkdtemp(prefix="fjt-dyn-")
     v1 = gen_iris_lr(workdir, seed=7)
     v2_dir = tempfile.mkdtemp(prefix="fjt-dyn2-")

@@ -9,7 +9,7 @@ are encoded to uint8 threshold ranks by the multithreaded bucketizer, and
 the whole micro-batch is scored by the Pallas VMEM-resident kernel (TPU)
 or the int8 einsum path. No Python object per record exists anywhere.
 
-Run:  python examples/gbm_throughput.py [--platform cpu] [--kafka]  [--trees 500 --seconds 3]
+Run:  python examples/gbm_throughput.py [--kafka]  [--trees 500 --seconds 3]
 bench.py is the driver-measured version of this same pipeline shape.
 """
 
@@ -21,9 +21,9 @@ import time
 
 sys.path.insert(0, str(pathlib.Path(__file__).resolve().parent.parent))
 
+import jax
 import numpy as np
 
-from flink_jpmml_tpu.utils.demo import demo_backend
 from flink_jpmml_tpu.assets_gen import gen_gbm
 from flink_jpmml_tpu.compile import compile_pmml
 from flink_jpmml_tpu.pmml import parse_pmml_file
@@ -32,7 +32,7 @@ from flink_jpmml_tpu.utils.config import BatchConfig, RuntimeConfig
 
 
 def main() -> None:
-    print(f"backend: {demo_backend()}")
+    print(f"backend: {jax.default_backend()}")
     ap = argparse.ArgumentParser()
     ap.add_argument("--trees", type=int, default=500)
     ap.add_argument("--features", type=int, default=32)

@@ -4,7 +4,7 @@ Reference parity: the examples module's K-Means/Iris jobs (SURVEY.md §3 row
 D2 [UNVERIFIED]). Generates the fixture, builds a pipeline with the fluent
 API, scores a finite stream, prints predictions + runtime metrics.
 
-Run:  python examples/iris_streaming.py [--platform cpu]
+Run:  python examples/iris_streaming.py
 """
 
 import pathlib
@@ -16,16 +16,16 @@ try:  # installed package (pip install -e .)
 except ImportError:  # source checkout without install: add the repo root
     sys.path.insert(0, str(pathlib.Path(__file__).resolve().parent.parent))
 
+import jax
 import numpy as np
 
-from flink_jpmml_tpu.utils.demo import demo_backend
 from flink_jpmml_tpu.assets_gen import gen_iris_lr
 from flink_jpmml_tpu.api import ModelReader, StreamEnvironment
 from flink_jpmml_tpu.utils.config import BatchConfig, RuntimeConfig
 
 
 def main() -> None:
-    print(f"backend: {demo_backend()}")
+    print(f"backend: {jax.default_backend()}")
     workdir = tempfile.mkdtemp(prefix="fjt-iris-")
     pmml_path = gen_iris_lr(workdir)
     print(f"model: {pmml_path}")

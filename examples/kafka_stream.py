@@ -7,7 +7,7 @@ to a `KafkaBlockSource` driving the production `BlockPipeline`; halfway
 through, the pipeline is stopped and a fresh one resumes from the
 checkpointed Kafka offset — every record scored exactly once.
 
-Run:  python examples/kafka_stream.py [--platform cpu]   (or on the TPU)
+Run:  python examples/kafka_stream.py
 """
 
 import argparse
@@ -21,9 +21,9 @@ try:  # installed package (pip install -e .)
 except ImportError:  # source checkout without install: add the repo root
     sys.path.insert(0, str(pathlib.Path(__file__).resolve().parent.parent))
 
+import jax
 import numpy as np
 
-from flink_jpmml_tpu.utils.demo import demo_backend
 from flink_jpmml_tpu.assets_gen import gen_gbm
 from flink_jpmml_tpu.compile import compile_pmml
 from flink_jpmml_tpu.pmml import parse_pmml_file
@@ -34,7 +34,7 @@ from flink_jpmml_tpu.utils.config import BatchConfig, RuntimeConfig
 
 
 def main() -> None:
-    print(f"backend: {demo_backend()}")
+    print(f"backend: {jax.default_backend()}")
     ap = argparse.ArgumentParser()
     ap.add_argument("--partitions", type=int, default=1,
                     help="topic partitions (round-robin interleaved "

@@ -5,7 +5,7 @@ cdist + argmin (compile/clustering.py). The anomaly signal is the distance
 to the winning centroid — records far from every center are flagged.
 Mirrors the reference's K-Means-over-Iris example job (SURVEY.md §3 D2).
 
-Run:  python examples/kmeans_anomaly.py [--platform cpu]
+Run:  python examples/kmeans_anomaly.py
 """
 
 import pathlib
@@ -17,16 +17,16 @@ try:  # installed package (pip install -e .)
 except ImportError:  # source checkout without install: add the repo root
     sys.path.insert(0, str(pathlib.Path(__file__).resolve().parent.parent))
 
+import jax
 import numpy as np
 
-from flink_jpmml_tpu.utils.demo import demo_backend
 from flink_jpmml_tpu.assets_gen import gen_kmeans
 from flink_jpmml_tpu.api import ModelReader, StreamEnvironment
 from flink_jpmml_tpu.utils.config import BatchConfig, RuntimeConfig
 
 
 def main() -> None:
-    print(f"backend: {demo_backend()}")
+    print(f"backend: {jax.default_backend()}")
     workdir = tempfile.mkdtemp(prefix="fjt-kmeans-")
     pmml = gen_kmeans(workdir, k=5, n_features=4)
     print(f"model: {pmml}")

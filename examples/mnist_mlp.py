@@ -4,7 +4,7 @@ A 784→256→10 NeuralNetwork PMML lowers to a bf16-friendly matmul chain on
 the MXU (compile/neural.py); the stream carries dense pixel vectors. The
 reference would walk JPMML's per-record neuron graph on the CPU.
 
-Run:  python examples/mnist_mlp.py [--platform cpu]
+Run:  python examples/mnist_mlp.py
 """
 
 import pathlib
@@ -16,16 +16,16 @@ try:  # installed package (pip install -e .)
 except ImportError:  # source checkout without install: add the repo root
     sys.path.insert(0, str(pathlib.Path(__file__).resolve().parent.parent))
 
+import jax
 import numpy as np
 
-from flink_jpmml_tpu.utils.demo import demo_backend
 from flink_jpmml_tpu.assets_gen import gen_mlp
 from flink_jpmml_tpu.api import ModelReader, StreamEnvironment
 from flink_jpmml_tpu.utils.config import BatchConfig, RuntimeConfig
 
 
 def main() -> None:
-    print(f"backend: {demo_backend()}")
+    print(f"backend: {jax.default_backend()}")
     workdir = tempfile.mkdtemp(prefix="fjt-mlp-")
     pmml = gen_mlp(workdir, n_inputs=784, hidden=(256,), n_classes=10)
     print(f"model: {pmml}")

@@ -6,7 +6,7 @@ streams a batch of records through the runtime against each, and prints
 a one-line summary per family. This is the "switching user" tour: the
 reference scored any JPMML-supported model class; so does this framework.
 
-Run:  python examples/model_zoo.py [--platform cpu]   (or on the TPU)
+Run:  python examples/model_zoo.py
 """
 
 import pathlib
@@ -18,9 +18,9 @@ try:  # installed package (pip install -e .)
 except ImportError:  # source checkout without install: add the repo root
     sys.path.insert(0, str(pathlib.Path(__file__).resolve().parent.parent))
 
+import jax
 import numpy as np
 
-from flink_jpmml_tpu.utils.demo import demo_backend
 from flink_jpmml_tpu.api import ModelReader, StreamEnvironment
 from flink_jpmml_tpu.assets_gen import (
     gen_gbm,
@@ -399,7 +399,7 @@ TEXTMODEL = """<PMML version="4.2"><DataDictionary>
 
 
 def main() -> None:
-    print(f"backend: {demo_backend()}")
+    print(f"backend: {jax.default_backend()}")
     workdir = tempfile.mkdtemp(prefix="fjt-zoo-")
     rng = np.random.default_rng(7)
 

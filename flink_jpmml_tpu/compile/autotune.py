@@ -1,12 +1,12 @@
 """Learned-cost-model kernel search + on-disk config cache.
 
-PR 2's warmup sweep measured two axes (encode placement, Pallas tile
-shapes) by timing every candidate. The layout catalogue
+The warmup sweep measures encode placement and the layout catalogue
 (compile/layouts.py: breadth-first SoA split order, uint8/uint16 wire
-packing, the multi-tree megakernel) crossed with those axes makes the
-candidate space ~20 configs per (model, backend) — too many to time,
-exactly the regime where "A Learned Performance Model for TPUs"
-(PAPERS.md) says to *predict then verify*:
+packing, the multi-tree megakernel) per (model, backend), the way "A
+Learned Performance Model for TPUs" (PAPERS.md) says to — *predict then
+verify*. (The Pallas tile axis ``block_b x gt`` left the space in PR 21:
+on the v5e Mosaic refuses a 1-D score block under 1024 rows, and ``gt=8``
+regroups the f32 tree sum, which breaks byte parity with the default.)
 
 1. **Predict.** A ridge cost model (compile/costmodel.py) fit on the
    accumulated kernel cost ledger (``kernel_costs.json`` — every
@@ -24,20 +24,19 @@ exactly the regime where "A Learned Performance Model for TPUs"
    the next warmup re-searches instead of trusting a stale prediction.
 
 With no usable fit yet (a cold ledger) the search *bootstraps*: it
-times a heuristic subset — the built defaults first, then one
-candidate per layout, then the remaining tiles — still capped at K,
-and fits the first model from those measurements.
+times the built default first, then the catalogue in order — still
+capped at K — and fits the first model from those measurements.
 
 The winning :class:`TunedConfig` is cached per
 ``(model_hash, backend_key)`` in ``$FJT_AUTOTUNE_CACHE`` (default
-``~/.cache/flink_jpmml_tpu/autotune.json``) consulted by
-``build_quantized_scorer`` on every compile. Every stored entry is
+``<checkout>/.fjt_cache/autotune.json``, compile/cachedir.py) consulted
+by ``build_quantized_scorer`` on every compile. Every stored entry is
 stamped with the search-space schema tag (``layouts.SPACE_TAG``): an
 entry written against an older space reads as *no entry* — silent
 re-search, the same corrupt-tolerant contract as ever (a pre-layout
 winner can never pin a new binary to an obsolete kernel config).
 ``FJT_KERNEL_SEARCH_DISABLE=1`` (the bench's ``--no-kernel-search``
-ablation) restricts the space to the legacy ref-layout tile sweep;
+ablation) restricts the space to the built default;
 ``FJT_AUTOTUNE_DISABLE=1`` (``--no-autotune``) disables all of it.
 """
 
@@ -55,30 +54,20 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
-from flink_jpmml_tpu.compile import layouts
+from flink_jpmml_tpu.compile import cachedir, layouts
 
 _CACHE_ENV = "FJT_AUTOTUNE_CACHE"
 _CACHE_VERSION = 1
 _SEARCH_DISABLE_ENV = "FJT_KERNEL_SEARCH_DISABLE"
 _TOPK_ENV = "FJT_SEARCH_TOPK"
 _DEFAULT_TOPK = 5
-# (block_b, gt) tile axis of the candidate space; None = the module
-# default. Crossed with the layout catalogue by candidate_space().
-_TILE_CANDIDATES = (
-    (None, None),
-    (512, None),
-    (256, None),
-    (None, 8),
-    (512, 8),
-)
 
 
 @dataclass
 class TunedConfig:
     """One measured winner: encode placement + kernel variant.
 
-    ``layout`` is the compile/layouts.py catalogue id; ``block_b``/
-    ``gt`` are None for the XLA backend (no tiles to pick); ``rates``
+    ``layout`` is the compile/layouts.py catalogue id; ``rates``
     keeps the per-candidate rec/s the search observed;
     ``predicted_s_per_record`` is the cost model's prediction for the
     adopted variant (the live profiler verifies it — drift re-opens
@@ -88,8 +77,6 @@ class TunedConfig:
     config came from ("default" | "sweep" | "cache")."""
 
     encode: str = "host"  # "host" | "fused"
-    block_b: Optional[int] = None
-    gt: Optional[int] = None
     layout: str = "ref"
     space: str = layouts.SPACE_TAG
     rec_s: Optional[float] = None
@@ -101,8 +88,6 @@ class TunedConfig:
     def as_dict(self) -> dict:
         return {
             "encode": self.encode,
-            "block_b": self.block_b,
-            "gt": self.gt,
             "layout": self.layout,
             "space": self.space,
             "rec_s": self.rec_s,
@@ -118,8 +103,6 @@ class TunedConfig:
         layout = d.get("layout")
         return cls(
             encode=enc if enc in ("host", "fused") else "host",
-            block_b=int(d["block_b"]) if d.get("block_b") else None,
-            gt=int(d["gt"]) if d.get("gt") else None,
             layout=layout if isinstance(layout, str) and layout else "ref",
             # absent tag = a pre-layout entry: must NOT default to the
             # current tag or stale winners would survive the schema bump
@@ -150,10 +133,7 @@ def cache_path() -> pathlib.Path:
     p = os.environ.get(_CACHE_ENV)
     if p:
         return pathlib.Path(p)
-    return (
-        pathlib.Path(os.path.expanduser("~"))
-        / ".cache" / "flink_jpmml_tpu" / "autotune.json"
-    )
+    return cachedir.state_dir() / "autotune.json"
 
 
 @contextlib.contextmanager
@@ -276,14 +256,7 @@ def backend_key(scorer) -> str:
     """Cache key half that pins WHERE the measurement holds: platform +
     device kind + which scorer backend compiled. A config measured on a
     v5e does not transfer to CPU interpret mode."""
-    try:
-        import jax
-
-        plat = jax.default_backend()
-        kind = getattr(jax.devices()[0], "device_kind", "") or ""
-    except Exception:
-        plat, kind = "unknown", ""
-    return f"{plat}:{kind.replace(' ', '_')}:{scorer.backend}"
+    return f"{platform_key()}:{scorer.backend}"
 
 
 # ---------------------------------------------------------------------------
@@ -347,13 +320,10 @@ class PackPlan:
 def platform_key() -> str:
     """Pack-plan cache key half: platform + device kind. No scorer
     backend dimension — packs are XLA-only by eligibility."""
-    try:
-        import jax
+    import jax
 
-        plat = jax.default_backend()
-        kind = getattr(jax.devices()[0], "device_kind", "") or ""
-    except Exception:
-        plat, kind = "unknown", ""
+    plat = jax.default_backend()
+    kind = getattr(jax.devices()[0], "device_kind", "") or ""
     return f"{plat}:{kind.replace(' ', '_')}"
 
 
@@ -469,70 +439,47 @@ def search_top_k(top_k: Optional[int] = None) -> int:
         return _DEFAULT_TOPK
 
 
-def candidate_space(scorer, legacy: bool = False) -> List[dict]:
-    """Every kernel variant the search may rank for this scorer:
-    (layout × Pallas tiles) on the Pallas backend, the layout
-    catalogue alone on XLA. The built defaults (ref layout, default
-    tiles) are always candidate 0. ``legacy`` restricts to the
-    pre-layout ref-only tile sweep (the ``--no-kernel-search``
-    ablation)."""
-    cands = [{"layout": "ref", "block_b": None, "gt": None}]
+def candidate_space(scorer, legacy: bool = False) -> List[str]:
+    """Every kernel variant the search may rank for this scorer: the
+    ids of the backend's layout catalogue. The built default (``ref``)
+    is always candidate 0. ``legacy`` restricts to it alone (the
+    ``--no-kernel-search`` ablation)."""
+    if legacy:
+        return ["ref"]
     if scorer.backend == "pallas" and scorer._pallas_rebuild is not None:
-        names = ("ref",) if legacy else layouts.pallas_layouts()
-        for layout in names:
-            for bb, g in _TILE_CANDIDATES:
-                if layout == "ref" and (bb, g) == (None, None):
-                    continue
-                cands.append({"layout": layout, "block_b": bb, "gt": g})
+        names = layouts.pallas_layouts()
     elif scorer.backend != "pallas" and scorer._xla_rebuild is not None:
-        if not legacy:
-            for layout in layouts.xla_layouts(scorer.wire):
-                if layout == "ref":
-                    continue
-                cands.append(
-                    {"layout": layout, "block_b": None, "gt": None}
-                )
-    return cands
+        names = layouts.xla_layouts(scorer.wire)
+    else:
+        names = ()
+    return ["ref"] + [layout for layout in names if layout != "ref"]
 
 
-def _cand_name(scorer, c: dict) -> str:
-    return layouts.variant_id(
-        scorer.backend, c["layout"], c["block_b"], c["gt"]
-    )
-
-
-def _cand_features(scorer, c: dict) -> Dict[str, float]:
+def _cand_features(scorer, layout: str) -> Dict[str, float]:
     from flink_jpmml_tpu.compile import costmodel
 
     wire_bytes = float(scorer.wire.bytes_per_record)
-    if "wirepack" in (layouts.flags(c["layout"]) or ()):
+    if "wirepack" in (layouts.flags(layout) or ()):
         wp = layouts.plan_wire_pack(scorer.wire)
         if wp is not None:
             wire_bytes = float(wp.bytes_per_record)
     return costmodel.variant_features(
-        costmodel.scorer_meta(scorer), scorer.backend,
-        c["layout"], c["block_b"], c["gt"], wire_bytes=wire_bytes,
+        costmodel.scorer_meta(scorer), scorer.backend, layout,
+        wire_bytes=wire_bytes,
     )
 
 
-def _bootstrap_order(cands: List[dict]) -> List[dict]:
-    """Cold-ledger timing order: defaults first, then one candidate
-    per distinct layout (default tiles where available), then the
-    remaining ref tiles, then everything else — so even a K-bounded
-    first search measures every layout family once."""
-    first: List[dict] = [cands[0]]
-    seen_layouts = {cands[0]["layout"]}
-    rest: List[dict] = []
-    for c in cands[1:]:
-        if c["layout"] not in seen_layouts and (
-            c["block_b"] is None and c["gt"] is None
-        ):
-            seen_layouts.add(c["layout"])
-            first.append(c)
-        else:
-            rest.append(c)
-    rest.sort(key=lambda c: (c["layout"] != "ref",))
-    return first + rest
+def _describe_serving_variant(scorer) -> None:
+    """Point the scorer's feature vector / variant id channels
+    (obs/attr.py dispatch_profile → kernel cost ledger + live drift
+    band) at the variant ACTUALLY serving."""
+    try:
+        scorer._cost_feat = _cand_features(scorer, scorer.layout)
+        scorer._cost_variant = layouts.variant_id(
+            scorer.backend, scorer.layout
+        )
+    except Exception:
+        scorer._cost_feat = None
 
 
 # ---------------------------------------------------------------------------
@@ -542,32 +489,18 @@ def _bootstrap_order(cands: List[dict]) -> List[dict]:
 
 def apply(scorer, cfg: TunedConfig) -> None:
     """Apply a config to a scorer: rebuild the kernel when the cached
-    variant (layout and/or tile shapes) differs from the built
-    defaults, then set the encode mode (gated on the scorer actually
-    supporting the fused stage — a stale "fused" entry degrades to
-    host, never crashes).
+    layout differs from the built default, then set the encode mode
+    (gated on the scorer actually supporting the fused stage — a stale
+    "fused" entry degrades to host, never crashes).
 
     A scorer is tuned at most once per lifetime, so the rebuild hooks
     are RELEASED afterwards — their closures pin the host-side packing
     tables (~11MB for the flagship GBM) that would otherwise sit next
     to the device-resident copies for as long as the model is served."""
-    from flink_jpmml_tpu.compile import costmodel, qtrees_pallas
-
     layout = cfg.layout or "ref"
-    needs_variant = False
-    if scorer.backend == "pallas":
-        needs_variant = layout != "ref" or (
-            (cfg.block_b or cfg.gt)
-            and (
-                (cfg.block_b or qtrees_pallas.DEFAULT_BLOCK_B),
-                (cfg.gt or qtrees_pallas.GT),
-            ) != (qtrees_pallas.DEFAULT_BLOCK_B, qtrees_pallas.GT)
-        )
-    else:
-        needs_variant = layout != "ref"
-    applied = not needs_variant
-    if needs_variant:
-        built = scorer.build_variant(layout, cfg.block_b, cfg.gt)
+    applied = layout == "ref"
+    if not applied:
+        built = scorer.build_variant(layout)
         if built is not None:
             scorer.adopt_variant(built, layout)
             applied = True
@@ -576,25 +509,10 @@ def apply(scorer, cfg: TunedConfig) -> None:
     scorer.encode_mode = (
         "fused" if cfg.encode == "fused" and scorer.supports_fused else "host"
     )
-    # the feature vector / variant id / prediction channels describe
-    # the variant ACTUALLY serving (obs/attr.py dispatch_profile →
-    # kernel cost ledger + live drift band). A cached variant this
-    # build degraded to defaults must not ship its tiles/prediction:
-    # the ledger row would train the cost model on a (features →
-    # cost) pair of a kernel that is not running, and the drift band
-    # would invalidate a perfectly good fit against it.
-    eff_bb = cfg.block_b if applied else None
-    eff_gt = cfg.gt if applied else None
-    try:
-        scorer._cost_feat = _cand_features(
-            scorer,
-            {"layout": scorer.layout, "block_b": eff_bb, "gt": eff_gt},
-        )
-        scorer._cost_variant = layouts.variant_id(
-            scorer.backend, scorer.layout, eff_bb, eff_gt
-        )
-    except Exception:
-        scorer._cost_feat = None
+    # a cached variant this build degraded to defaults must not ship
+    # its prediction: the drift band would invalidate a perfectly good
+    # fit against a kernel that is not running
+    _describe_serving_variant(scorer)
     scorer._pred_s_per_record = (
         cfg.predicted_s_per_record if applied else None
     )
@@ -623,8 +541,8 @@ def _variant_search(
     rates: Dict[str, float],
     top_k: Optional[int] = None,
 ):
-    """Predict-then-verify over the candidate space → (winning
-    candidate dict, predicted s/record for it, search summary).
+    """Predict-then-verify over the candidate space → (predicted
+    s/record for the adopted layout, search summary).
 
     Ranks ALL candidates by the ledger-fit cost model when one exists
     (bootstrap order otherwise), times at most K on device, adopts the
@@ -638,7 +556,8 @@ def _variant_search(
     legacy = bool(os.environ.get(_SEARCH_DISABLE_ENV))
     cands = candidate_space(scorer, legacy=legacy)
     K = search_top_k(top_k)
-    feats = {_cand_name(scorer, c): _cand_features(scorer, c) for c in cands}
+    names = {c: layouts.variant_id(scorer.backend, c) for c in cands}
+    feats = {names[c]: _cand_features(scorer, c) for c in cands}
     platform = backend_key(scorer).split(":", 1)[0]
     model = None if legacy else costmodel.current_model(platform=platform)
     predictions: Dict[str, float] = {}
@@ -647,16 +566,16 @@ def _variant_search(
         predictions = {
             n: round(p, 12) for n, p in ranked if math.isfinite(p)
         }
-        order = [next(c for c in cands if _cand_name(scorer, c) == n)
+        order = [next(c for c in cands if names[c] == n)
                  for n, _ in ranked]
         # the built default is ALWAYS verified, mispredicted or not:
         # without it a bad fit could rank the incumbent outside top-K
         # and the search would adopt-and-persist a variant slower than
         # the default it replaced (never having measured the default)
-        order = [cands[0]] + [c for c in order if c is not cands[0]]
+        order = [cands[0]] + [c for c in order if c != cands[0]]
         mode = "learned"
     else:
-        order = _bootstrap_order(cands)
+        order = cands  # cold ledger: the default, then catalogue order
         mode = "legacy" if legacy else "bootstrap"
 
     bs = X.shape[0]
@@ -674,16 +593,15 @@ def _variant_search(
             break
         if time.perf_counter() - t_start > budget_s and timed:
             break
-        name = _cand_name(scorer, c)
-        is_default = c["layout"] == "ref" and not c["block_b"] and not c["gt"]
-        if is_default:
+        name = names[c]
+        if c == "ref":
             built, params, fn, wp = (
                 None, scorer.params, scorer._jit_fn, scorer._wire_pack,
             )
         else:
-            built = scorer.build_variant(c["layout"], c["block_b"], c["gt"])
+            built = scorer.build_variant(c)
             if built is None:
-                continue  # ineligible (VMEM budget, nothing to pack, …)
+                continue  # nothing to pack
             params, fn, wp = (
                 built["params"], built["jit_fn"], built["wire_pack"],
             )
@@ -711,12 +629,12 @@ def _variant_search(
         if bs / dt > best_rate:
             best_rate, best_cand, best_built = bs / dt, c, built
     if best_built is not None:
-        scorer.adopt_variant(best_built, best_cand["layout"])
+        scorer.adopt_variant(best_built, best_cand)
     ledger.flush()
     # refit from the ledger (now including this search's rows) and
     # persist, so the NEXT search predicts from these measurements
     refit = costmodel.fit_from_ledger(platform=platform)
-    best_name = _cand_name(scorer, best_cand)
+    best_name = names[best_cand]
     # predicted-vs-measured residual over the verified candidates: the
     # honest "is the model any good yet" number in the artifact
     resid = None
@@ -742,7 +660,7 @@ def _variant_search(
         "pred_abs_log_err": resid,
         "model": (refit or model).stats if (refit or model) else None,
     }
-    return best_cand, predictions.get(best_name), search_info
+    return predictions.get(best_name), search_info
 
 
 def sweep(
@@ -769,11 +687,10 @@ def sweep(
         reps = -(-bs // X.shape[0])
         X = np.ascontiguousarray(np.tile(X, (reps, 1))[:bs])
     rates: Dict[str, float] = {}
-    chosen = {"layout": "ref", "block_b": None, "gt": None}
     predicted = None
     search_info = None
 
-    # -- kernel-variant search (layouts × tiles, host-encoded input) ------
+    # -- kernel-variant search (layouts, host-encoded input) --------------
     has_variants = (
         scorer.backend == "pallas" and scorer._pallas_rebuild is not None
     ) or (scorer.backend != "pallas" and scorer._xla_rebuild is not None)
@@ -781,7 +698,7 @@ def sweep(
         # raw (unpacked) rank codes at exactly one compile batch; each
         # candidate packs them itself when its layout calls for it
         Xq = scorer.wire.encode(X)
-        chosen, predicted, search_info = _variant_search(
+        predicted, search_info = _variant_search(
             scorer, Xq, repeats, budget_s, t_start, rates, top_k
         )
     # tuned once: release the rebuild closures so they stop pinning
@@ -811,8 +728,6 @@ def sweep(
 
     cfg = TunedConfig(
         encode=encode,
-        block_b=chosen["block_b"],
-        gt=chosen["gt"],
         layout=scorer.layout,
         rec_s=rates.get(f"encode_{encode}"),
         predicted_s_per_record=predicted,
@@ -823,20 +738,7 @@ def sweep(
     scorer.encode_mode = (
         "fused" if encode == "fused" and scorer.supports_fused else "host"
     )
-    try:
-        scorer._cost_feat = _cand_features(
-            scorer,
-            {
-                "layout": scorer.layout,
-                "block_b": chosen["block_b"],
-                "gt": chosen["gt"],
-            },
-        )
-        scorer._cost_variant = layouts.variant_id(
-            scorer.backend, scorer.layout, chosen["block_b"], chosen["gt"]
-        )
-    except Exception:
-        scorer._cost_feat = None
+    _describe_serving_variant(scorer)
     # the chosen candidate IS the serving variant here (the search
     # adopted it), so its prediction is the one the live band verifies
     scorer._pred_s_per_record = predicted
@@ -864,7 +766,7 @@ def ensure_tuned(
             flight.record(
                 "autotune_decision", source="cache", backend=key,
                 model_hash=scorer.model_hash, encode=cfg.encode,
-                block_b=cfg.block_b, gt=cfg.gt, layout=cfg.layout,
+                layout=cfg.layout,
             )
             return cfg
     cfg = sweep(
@@ -874,7 +776,7 @@ def ensure_tuned(
     flight.record(
         "autotune_decision", source="sweep", backend=key,
         model_hash=scorer.model_hash, encode=cfg.encode,
-        block_b=cfg.block_b, gt=cfg.gt, layout=cfg.layout,
+        layout=cfg.layout,
         rec_s=cfg.rec_s,
         timed=(cfg.search or {}).get("timed"),
         candidates=(cfg.search or {}).get("candidates_total"),

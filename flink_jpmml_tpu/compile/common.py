@@ -37,24 +37,16 @@ from flink_jpmml_tpu.pmml import ir
 from flink_jpmml_tpu.utils.config import CompileConfig
 from flink_jpmml_tpu.utils.exceptions import ModelCompilationException
 
-# Lazily-probed, exception-guarded backend kind. Lowering only consults
-# this to pick matmul dtypes (bf16 on TPU, f32 where there are no bf16/int8
-# dot kernels), so a backend-init failure must degrade to the f32 choice —
-# which is correct everywhere — instead of turning model *compilation* into
-# a crash (round-1 driver bench died exactly there: an unavailable backend
-# surfaced as a ModelCompilationException-shaped stack through trees.py).
+# Lazily-probed backend kind. Lowering consults this to pick matmul
+# dtypes (bf16/int8 on TPU, f32 where there are no such dot kernels). A
+# backend that fails to initialise raises here: it is not a CPU.
 _BACKEND_IS_CPU: Optional[bool] = None
 
 
 def backend_is_cpu() -> bool:
     global _BACKEND_IS_CPU
     if _BACKEND_IS_CPU is None:
-        try:
-            _BACKEND_IS_CPU = jax.default_backend() == "cpu"
-        except Exception:
-            # f32 lowering is safe on any backend; don't cache the failure
-            # so a backend that comes up later gets its bf16 paths back
-            return True
+        _BACKEND_IS_CPU = jax.default_backend() == "cpu"
     return _BACKEND_IS_CPU
 
 

@@ -12,7 +12,6 @@ becomes ``CompiledModel.predict(X, M)`` over a micro-batch; totality
 from __future__ import annotations
 
 import dataclasses
-import warnings
 from dataclasses import dataclass
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -178,35 +177,18 @@ class CompiledModel:
         if self._quantized is _UNSET:
             from flink_jpmml_tpu.compile.qtrees import build_quantized_scorer
 
-            # a probe failure must never take down the caller's pipeline —
-            # the f32 path is always available and semantically complete, so
-            # ANY failure here (compilation edge case, or a RuntimeError
-            # from the first device interaction — device_put of the Pallas
-            # group tables happens before any lazy jit executes) degrades
-            # to it rather than killing the stream
-            try:
-                self._quantized = (
-                    build_quantized_scorer(
-                        self._doc,
-                        batch_size=self.batch_size,
-                        config=self._config,
-                    )
-                    if self._doc is not None
-                    else None
+            # documents outside the fast path's contract return None
+            # without raising; a probe that raises is a defect on every
+            # backend and propagates
+            self._quantized = (
+                build_quantized_scorer(
+                    self._doc,
+                    batch_size=self.batch_size,
+                    config=self._config,
                 )
-            except Exception as e:
-                # keep the cause findable: the doc is released below, so
-                # the probe cannot be retried — a silent None would leave
-                # a 10x slowdown with no diagnostic anywhere
-                self.quantized_probe_error = e
-                warnings.warn(
-                    f"quantized-wire probe failed for "
-                    f"{self.model_name or 'model'}; scoring stays on the "
-                    f"f32 path: {e!r}",
-                    RuntimeWarning,
-                    stacklevel=2,
-                )
-                self._quantized = None
+                if self._doc is not None
+                else None
+            )
             # the parse tree is only needed for this probe — release it so a
             # long-lived served model doesn't pin the whole IR
             self._doc = None

@@ -1,21 +1,18 @@
 """Learned kernel cost model: predict device-s/record, verify top-K.
 
-The 2-axis warmup sweep (PR 2) times every candidate it considers — at
-five candidates that was fine, but the layout catalogue
-(compile/layouts.py) crossed with the Pallas tile axes makes the space
-~20 configs per model, each costing a re-pack + a compile + timed
-dispatches. Following "A Learned Performance Model for TPUs"
-(PAPERS.md), the search becomes **predict-then-verify**: a cheap ridge
-regressor over analytic kernel features — tree count/depth, padded
-leaf width, field count, tile shape, batch, wire dtype rank, layout
-flags — is fit on the accumulated kernel cost ledger
+Every candidate of the layout catalogue (compile/layouts.py) costs a
+re-pack + a compile + timed dispatches. Following "A Learned
+Performance Model for TPUs" (PAPERS.md), the search is
+**predict-then-verify**: a cheap ridge regressor over analytic kernel
+features — tree count/depth, leaf width, field count, batch, wire
+dtype rank, layout flags — is fit on the accumulated kernel cost ledger
 (``kernel_costs.json``, obs/profiler.py: every profiler sample and
 every prior sweep's timings are (features → observed device-s/record)
 training pairs), ranks the WHOLE candidate space by predicted cost,
 and only the top-K rank on device (compile/autotune.py times them).
 
 The fit is closed-form ridge in **log space** (device costs span
-orders of magnitude across backends and tile shapes; relative error is
+orders of magnitude across backends and models; relative error is
 what ranking needs), standardized features, numpy only. The fitted
 coefficients persist in ``cost_model.json`` beside the ledger through
 the same temp-file + fsync + atomic-replace discipline, so a fresh
@@ -73,8 +70,6 @@ def variant_features(
     meta: Dict[str, float],
     backend: str,
     layout: str,
-    block_b: Optional[int],
-    gt: Optional[int],
     wire_bytes: Optional[float] = None,
 ) -> Dict[str, float]:
     """Analytic feature dict for one (model, kernel-variant) pair.
@@ -103,11 +98,6 @@ def variant_features(
         "log2_wire_bytes": _log2(
             wire_bytes if wire_bytes is not None else meta.get("fields", 0.0)
         ),
-        # padded width of the block-diagonal operand (Pallas) or the
-        # dense leaf plane (XLA): the padding axis of the search space
-        "log2_padded_width": _log2((gt or 4) * max(leaves, 1.0)),
-        "log2_block_b": _log2(block_b or 1024),
-        "gt": float(gt or 4),
         "backend_pallas": 1.0 if backend == "pallas" else 0.0,
         "classification": float(meta.get("classification", 0.0)),
     }
@@ -263,7 +253,7 @@ class CostModel:
 
 # per-dispatch host+launch overhead the pack amortizes: the quantity
 # packing exists to defeat. Overridable for hosts whose measured launch
-# cost differs (a tunneled chip is worse than a local one).
+# cost differs.
 _PACK_OVERHEAD_ENV = "FJT_PACK_DISPATCH_OVERHEAD_S"
 _PACK_OVERHEAD_DEFAULT_S = 5e-4
 # relative weight of padded waste in the ranking: waste is wasted
@@ -291,7 +281,7 @@ def _member_compute_s(meta: Dict[str, float], model) -> float:
     meta = meta or {}
     b = max(float(meta.get("batch", 0.0)), 1.0)
     if model is not None:
-        f = variant_features(meta, "xla", "ref", None, None)
+        f = variant_features(meta, "xla", "ref")
         p = model.predict(f)
         if p is not None and math.isfinite(p) and p > 0:
             return p * b

@@ -48,9 +48,9 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 # bump whenever the candidate space changes shape (new layout, new
-# tile axis, changed packing semantics): stale cached winners must
+# search axis, changed packing semantics): stale cached winners must
 # re-search, not pin the old space's best onto the new binary
-SPACE_TAG = "space-v2:layouts"
+SPACE_TAG = "space-v3:layouts-default-tiles"
 
 _XLA_LAYOUTS = ("ref", "bfs", "wirepack", "bfs_wirepack")
 _PALLAS_LAYOUTS = ("ref", "bfs", "mega", "mega_bfs")
@@ -356,16 +356,7 @@ def pack_pad_waste(
     return 1.0 - used / total if total > 0 else 0.0
 
 
-def variant_id(
-    backend: str, layout: str, block_b: Optional[int], gt: Optional[int]
-) -> str:
+def variant_id(backend: str, layout: str) -> str:
     """Canonical ledger/rates key for one search candidate."""
-    if backend == "pallas":
-        from flink_jpmml_tpu.compile import qtrees_pallas
-
-        name = (
-            f"pallas_b{block_b or qtrees_pallas.DEFAULT_BLOCK_B}"
-            f"_gt{gt or qtrees_pallas.GT}"
-        )
-        return name if layout in (None, "ref") else f"{name}_{layout}"
-    return f"xla_{layout or 'ref'}"
+    kernel = "pallas" if backend == "pallas" else "xla"
+    return f"{kernel}_{layout or 'ref'}"

@@ -25,7 +25,9 @@ and scores out:
   accumulating partials (j==0 initialises).
 
 Per-record HBM traffic: 32B of codes in, 4B of score out, params once per
-call — vs ~100KB/rec for the XLA path. Eligibility: uint8 wire only
+call — vs ~100KB/rec for the XLA path. Eligibility: a fixed batch that is
+a whole number of blocks, group tensors within ``_VMEM_PARAM_BUDGET``
+(:func:`build_pallas_fn` returns None otherwise), uint8 wire only
 (uint16 ranks up to 65534 are not exactly representable in bf16, so the
 one-hot select matmul would corrupt them; carrying the codes as f32 would
 halve the MXU rate — such models stay on the XLA int-einsum path), and
@@ -36,7 +38,8 @@ normalised vote weights fold into per-leaf class rows → [B, C] vote
 shares, argmaxed outside the kernel). Everything else stays on XLA.
 
 Correctness is tested in interpret mode on CPU against the XLA quantized
-path and the f32 reference (tests/test_qtrees_pallas.py).
+path and the f32 reference (tests/test_qtrees_pallas.py), and compiled on
+the chip by ``chip_smoke.py`` at the flagship size.
 
 Round 11 adds the **multi-tree megakernel** variant
 (``build_pallas_fn(fuse_groups=True)``, the ``mega`` layout of
@@ -58,12 +61,14 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
-GT = 4  # trees per block-diagonal group (default; autotune may sweep it)
-DEFAULT_BLOCK_B = 1024  # batch rows per grid block (autotune may sweep it)
-# VMEM is ~16MB/core; params for the 500-tree GBM take ~11MB, temps at
-# Bblk=512 another ~2.5MB, so the resident-params layout fits with room
-# for the input/output pipeline. Guard eligibility on this budget.
+GT = 4  # trees per block-diagonal group
+DEFAULT_BLOCK_B = 1024  # batch rows per grid block
+# the group tensors stay resident in VMEM for the whole call. The largest
+# forest compiled on a chip so far is the 500-tree depth-6 GBM (~11MB of
+# group tensors, v5e, Mosaic's default scoped-VMEM limit); eligibility
+# stops just above it until a larger one has been run there.
 _VMEM_PARAM_BUDGET = 12 * 1024 * 1024
+_SCORE_TILE = 1024  # elements per tile of XLA's 1-D f32 layout on TPU
 
 
 def pack_groups(
@@ -82,10 +87,10 @@ def pack_groups(
 ) -> Dict[str, np.ndarray]:
     """Group-pack the per-tree tensors for the kernel (numpy, host-side).
 
-    ``gt`` is the trees-per-group tile knob (block-diagonal operand is
+    ``gt`` trees share one group (block-diagonal operand is
     ``[gt*S, gt*L]``): the default 4 makes two full 128x128 MXU tiles
-    per axis for depth-6 trees; the bench-warmup autotuner
-    (compile/autotune.py) may sweep it per model/backend.
+    per axis for depth-6 trees. Another ``gt`` regroups the f32 tree
+    sum, so its scores are not byte-identical to the default's.
 
     Classification tables MUST arrive as the bf16 hi/lo split pair
     (``vals``=hi, ``vals_lo``=lo) — the same operands the XLA path
@@ -275,27 +280,33 @@ def build_pallas_fn(
 ):
     """→ fn(group_params, Xq u8[B, F]) -> f32[B] ensemble sums (scalar
     ``vals``) or f32[B, C] vote shares (class-row ``vals``), or None when
-    the shapes don't fit this kernel (caller falls back to XLA).
+    the shapes are outside this kernel's contract (the caller serves the
+    model from the XLA rank-wire path, ``QuantizedScorer.backend ==
+    "xla"``): group tensors over ``_VMEM_PARAM_BUDGET``, a batch that is
+    not a whole number of blocks, a 1-D score block Mosaic cannot tile.
 
     ``fuse_groups=True`` builds the multi-tree megakernel (the
     ``mega`` layout of compile/layouts.py): grid ``(batch blocks,)``
     only, with the tree-group sweep fused into an in-kernel loop."""
     G = groups["fsel"].shape[0]
+    classification = groups["vals"].ndim == 3
     if param_bytes(groups) > _VMEM_PARAM_BUDGET:
         return None
     while block_b > batch_size:
         block_b //= 2
-    if batch_size % block_b:
+    if block_b < 8 or batch_size % block_b:
         return None
-    # 1-D output blocks must be 128-divisible unless the block is the whole
-    # array (single batch block)
-    if block_b % 128 and block_b != batch_size:
-        return None
-    if block_b < 8:
+    # XLA lays a 1-D f32 vector out in 1024-element tiles and Mosaic
+    # refuses a score block tiled any other way ("XLA layout
+    # {0:T(1024)S(1)} does not match Mosaic layout {0:T(512)S(1)}",
+    # v5e), unless the block is the whole vector
+    if (
+        not classification
+        and block_b % _SCORE_TILE
+        and block_b != batch_size
+    ):
         return None
     nb = batch_size // block_b
-
-    classification = groups["vals"].ndim == 3
     F = n_fields
     # the megakernel's grid has no group axis: index maps take one
     # program id; the grid form keeps its (i, j) maps

@@ -25,11 +25,11 @@ analytic FLOP/byte model — persisted as JSON beside the autotune cache
 (``kernel_costs.json``), the training data ROADMAP item 2's
 predict-then-verify cost model needs.
 
-Chip peaks are known for the TPU generations the bench knows; unknown
-device kinds (CPU test runs, new chips) fall back to a nominal
-1 TFLOP/s / 100 GB/s peak (override: ``FJT_PROF_PEAKS=flops,bytes``) so
-the gauges stay live as *trends* — the bench artifact keeps its strict
-null-on-unknown semantics via ``chip_peaks(strict=True)``.
+Chip peaks are one table keyed by ``device_kind``. An unknown kind (a
+CPU test run, a new chip) has no roofline: the ``device_mfu`` /
+``device_membw_util`` gauges are not registered at all, unless
+``FJT_PROF_PEAKS=flops,bytes`` supplies the chip's real peaks. The
+bench artifact reads the table alone (``chip_peaks(strict=True)``).
 """
 
 from __future__ import annotations
@@ -64,15 +64,14 @@ CHIP_PEAKS = (
     ("v4", (275e12, 1228e9)),
     ("v5p", (459e12, 2765e9)),
 )
-_NOMINAL_PEAKS = (1e12, 100e9)
 
 
 def chip_peaks(
     device_kind: str, strict: bool = False
 ) -> Optional[Tuple[float, float]]:
-    """(bf16 peak FLOP/s, HBM bytes/s) for a device kind. Unknown kinds
-    return None under ``strict`` (the bench's honest-null convention) or
-    the nominal/env-overridden fallback otherwise (live trend gauges)."""
+    """(bf16 peak FLOP/s, HBM bytes/s) for a device kind, or None for
+    a kind the table does not know — unless, outside ``strict``,
+    ``FJT_PROF_PEAKS`` supplies the peaks."""
     kind = (device_kind or "").lower()
     for sub, peaks in CHIP_PEAKS:
         if sub in kind:
@@ -87,7 +86,7 @@ def chip_peaks(
                 return (f, b)
         except ValueError:
             pass
-    return _NOMINAL_PEAKS
+    return None
 
 
 def roofline(
@@ -409,8 +408,6 @@ class DeviceProfiler:
         self._pred_fired: Dict[str, float] = {}
         self._g_pred_err = None
         self._samples = metrics.counter("device_samples")
-        self._g_mfu = metrics.gauge("device_mfu")
-        self._g_membw = metrics.gauge("device_membw_util")
         self._g_flops = metrics.gauge("flops_per_record")
         self._g_nsrec = metrics.gauge("device_ns_per_record")
 
@@ -485,10 +482,14 @@ class DeviceProfiler:
         mfu, membw = roofline(dev_rate, flops, bpr, peaks)
         if flops is not None:
             self._g_flops.set(float(flops))
-        if mfu is not None:
-            self._g_mfu.set(round(mfu, 6))
-        if membw is not None:
-            self._g_membw.set(round(membw, 6))
+        # registered on first use: a chip with no known peaks carries
+        # no roofline gauges at all (a 0.0 would read as a measurement)
+        reg = self._metrics_ref()
+        if reg is not None:
+            if mfu is not None:
+                reg.gauge("device_mfu").set(round(mfu, 6))
+            if membw is not None:
+                reg.gauge("device_membw_util").set(round(membw, 6))
         # the sampled device column of the attribution plane
         from flink_jpmml_tpu.obs import attr
 

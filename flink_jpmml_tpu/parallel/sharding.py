@@ -34,15 +34,6 @@ from flink_jpmml_tpu.utils.exceptions import (
     InputValidationException,
 )
 
-# ``shard_map`` moved to the top-level jax namespace only after 0.4.x;
-# on the image's jax it still lives in jax.experimental. Resolve once —
-# the call signature (mesh=, in_specs=, out_specs=) is identical.
-try:
-    _shard_map = jax.shard_map  # jax >= 0.6
-except AttributeError:  # pragma: no cover - exercised on jax 0.4.x images
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-
 @dataclass
 class ShardedModel:
     """A CompiledModel re-jitted for a mesh: same predict contract, batch
@@ -396,7 +387,7 @@ def tp_linear(
         full = jax.lax.psum(part, MODEL_AXIS)
         return full + b
 
-    fn = _shard_map(
+    fn = jax.shard_map(
         _partial_matmul,
         mesh=mesh,
         in_specs=(
@@ -514,7 +505,7 @@ def mp_gp(mesh: Mesh, model) -> "callable":
         )
         return jax.lax.psum(part, MODEL_AXIS)
 
-    smapped = _shard_map(
+    smapped = jax.shard_map(
         _partial,
         mesh=mesh,
         in_specs=(

@@ -30,11 +30,10 @@ kind               meaning / recovery ladder entry
 =================  ====================================================
 
 Classification is type-gated: only the runtime's own injected device
-faults (runtime/faults.py) and the XLA runtime error types
-(``jaxlib``'s ``XlaRuntimeError`` / ``jax.errors.JaxRuntimeError``)
-classify at all — an application ``ValueError`` can never be mistaken
-for a sick device, and an injected poison record (a ``ValueError``
-subclass) stays poison. Within the XLA types the *kind* comes from the
+faults (runtime/faults.py) and the XLA runtime error type
+(``jax.errors.JaxRuntimeError``) classify at all — an application
+``ValueError`` can never be mistaken for a sick device, and an injected
+poison record (a ``ValueError`` subclass) stays poison. Within the XLA type the *kind* comes from the
 status-message markers XLA actually emits (``RESOURCE_EXHAUSTED`` /
 "out of memory" → OOM; device-lost/halted markers → chip loss;
 everything else → transient device error), so the injected faults and
@@ -45,7 +44,9 @@ from __future__ import annotations
 
 import threading
 import time
-from typing import Optional, Tuple
+from typing import Optional
+
+from jax.errors import JaxRuntimeError
 
 from flink_jpmml_tpu.obs import recorder as flight
 
@@ -61,31 +62,6 @@ _LOST_MARKERS = (
     "device lost", "device_lost", "data_loss", "halted",
     "device unavailable", "failed to connect",
 )
-
-_XLA_TYPES: Optional[Tuple[type, ...]] = None
-
-
-def _xla_error_types() -> Tuple[type, ...]:
-    """The XLA runtime error types this build exposes (resolved once;
-    runs on the error path only — never on a hot path)."""
-    global _XLA_TYPES
-    if _XLA_TYPES is None:
-        types = []
-        try:  # the canonical type every backend raises through
-            from jaxlib.xla_extension import XlaRuntimeError
-
-            types.append(XlaRuntimeError)
-        except Exception:  # pragma: no cover - jaxlib layout varies
-            pass
-        try:  # newer jax re-exports (may alias the above)
-            from jax.errors import JaxRuntimeError
-
-            types.append(JaxRuntimeError)
-        except Exception:
-            pass
-        _XLA_TYPES = tuple(types)
-    return _XLA_TYPES
-
 
 def _kind_from_message(msg: str) -> str:
     m = msg.lower()
@@ -111,8 +87,7 @@ def classify(exc: BaseException) -> Optional[str]:
         return KIND_OOM
     if isinstance(exc, faults.InjectedDeviceError):
         return KIND_ERROR
-    xla = _xla_error_types()
-    if xla and isinstance(exc, xla):
+    if isinstance(exc, JaxRuntimeError):
         return _kind_from_message(str(exc))
     return None
 
@@ -123,7 +98,7 @@ _EVENT_MIN_PERIOD_S = 1.0
 _note_mu = threading.Lock()
 # rate limiter PER KIND: a chatty device_error stream must not
 # suppress the first (possibly only) device_oom/chip_loss event —
-# each taxonomy entry keeps its own flight-event cadence
+# each fault kind keeps its own flight-event cadence
 _last_event: dict = {}
 
 
@@ -134,7 +109,7 @@ def note(metrics, kind: str, model=None, first_off=None, n=None,
     ``device_fault`` flight event carrying the active journey's trace
     id when one is set (the fjt-trace pivot). Shared by the block
     path's failover plane, the record engine, and the dynamic scorer so
-    the taxonomy cannot drift between them."""
+    the fault kinds cannot drift between them."""
     if metrics is not None:
         metrics.counter(f'device_fault_total{{kind="{kind}"}}').inc()
     now = time.monotonic()

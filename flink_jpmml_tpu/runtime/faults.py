@@ -101,7 +101,7 @@ SITES = {
     # kill); with ``offset=`` it fires only when that offset is in the
     # batch, the shape of a record that hard-crashes the process
     "worker_crash": "score_loop",
-    # device faults (runtime/devfault.py's taxonomy): default to the
+    # device faults (runtime/devfault.py's fault kinds): default to the
     # readback site — async dispatch errors surface where the host
     # first blocks, like the real thing; ``site=device_dispatch``
     # moves them to launch time

@@ -407,7 +407,7 @@ def summary(struct: dict) -> Optional[dict]:
     """Failover-plane summary from a metrics struct (``fjt-top
     --failover``, bench artifacts): circuit state per model, fallback
     share of delivered records, redispatch/OOM-shrink counts, the
-    device-fault taxonomy totals, and the checkpoint-suspension flag.
+    device-fault kind totals, and the checkpoint-suspension flag.
     None when the struct carries no failover telemetry at all."""
     gauges = struct.get("gauges") or {}
     counters = struct.get("counters") or {}

@@ -307,6 +307,16 @@ class TestDeviceProfilerRateLimiter:
         assert spent / clk.t <= 0.01 + per_sample / clk.t
         assert spent > 0  # the limiter throttles, it doesn't starve
 
+    def test_supplied_peaks_register_the_roofline_gauges(
+        self, tmp_path, monkeypatch
+    ):
+        monkeypatch.setenv("FJT_PROF_PEAKS", "1e12,1e11")
+        m, prof = self._prof(tmp_path, FakeClock(10.0))
+        prof.record_sample(0.001, _profile(records=1000), overhead_s=0.002)
+        g = m.struct_snapshot()["gauges"]
+        assert g["device_mfu"]["value"] > 0
+        assert g["device_membw_util"]["value"] > 0
+
     def test_disabled_by_env(self, tmp_path, monkeypatch):
         monkeypatch.setenv("FJT_PROF_SAMPLE", "off")
         prof = profiler.DeviceProfiler(
@@ -328,10 +338,9 @@ class TestDeviceProfilerRateLimiter:
             1000.0
         )
         assert snap["gauges"]["flops_per_record"]["value"] == 1280.0
-        # unknown (CPU) device kind → nominal-peak fallback keeps the
-        # live gauges present and positive
-        assert snap["gauges"]["device_mfu"]["value"] > 0
-        assert snap["gauges"]["device_membw_util"]["value"] > 0
+        # unknown (CPU) device kind → no roofline gauges at all
+        assert "device_mfu" not in snap["gauges"]
+        assert "device_membw_util" not in snap["gauges"]
         h = Histogram.from_state(
             snap["histograms"][attr.stage_metric_name("device")]
         )
@@ -384,16 +393,19 @@ class TestKernelCostLedger:
 
 
 class TestRoofline:
-    def test_known_chip_strict_and_fallback(self):
+    def test_known_chip_and_unknown(self, monkeypatch):
+        monkeypatch.delenv("FJT_PROF_PEAKS", raising=False)
         assert profiler.chip_peaks("TPU v4") == (275e12, 1228e9)
+        assert profiler.chip_peaks("TPU v5 lite") == (197e12, 819e9)
         assert profiler.chip_peaks("weird chip", strict=True) is None
-        assert profiler.chip_peaks("weird chip") == (1e12, 100e9)
+        assert profiler.chip_peaks("weird chip") is None
 
-    def test_peaks_env_override(self, monkeypatch):
+    def test_peaks_env_supplies_an_unknown_chip(self, monkeypatch):
         monkeypatch.setenv("FJT_PROF_PEAKS", "2e12,5e11")
         assert profiler.chip_peaks("weird chip") == (2e12, 5e11)
+        assert profiler.chip_peaks("weird chip", strict=True) is None
         monkeypatch.setenv("FJT_PROF_PEAKS", "garbage")
-        assert profiler.chip_peaks("weird chip") == (1e12, 100e9)
+        assert profiler.chip_peaks("weird chip") is None
 
     def test_roofline_math(self):
         mfu, membw = profiler.roofline(1e6, 1280.0, 6.0, (1e12, 1e9))
@@ -443,7 +455,7 @@ class TestDispatcherSampling:
         snap = m.struct_snapshot()
         assert snap["counters"]["device_samples"] >= 1
         assert attr.stage_metric_name("device") in snap["histograms"]
-        assert snap["gauges"]["device_mfu"]["value"] > 0
+        assert snap["gauges"]["device_ns_per_record"]["value"] > 0
 
     def test_device_sample_excludes_dispatch_host_time(self, tmp_path):
         """The sampling bracket times only the post-dispatch wait:

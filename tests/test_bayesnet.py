@@ -174,7 +174,7 @@ class TestBayesianNetwork:
         import jax
 
         if len(jax.devices()) < 8:
-            # FJT_TEST_PLATFORM=default on a 1-chip host: the virtual
+            # JAX_PLATFORMS=tpu on a 1-chip host: the virtual
             # 8-CPU mesh is unavailable; the sharding path is covered by
             # the CPU-mesh run (tests/conftest.py)
             pytest.skip("needs the 8-device virtual mesh")

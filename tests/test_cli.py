@@ -54,7 +54,7 @@ class TestScoreCli:
 
         for inp in (csv_p, jsonl_p):
             out_p = str(pathlib.Path(tmp_path, "out.jsonl"))
-            rc = score_main([iris, inp, "-o", out_p, "--platform", "cpu"])
+            rc = score_main([iris, inp, "-o", out_p])
             assert rc == 0
             got = [
                 json.loads(ln)
@@ -80,8 +80,7 @@ class TestScoreCli:
         csv_p, _ = _write_inputs(tmp_path, fields, rows)
         out_p = str(pathlib.Path(tmp_path, "out.jsonl"))
         assert score_main(
-            [iris, csv_p, "-o", out_p, "--replace-nan", "0.0",
-             "--platform", "cpu"]
+            [iris, csv_p, "-o", out_p, "--replace-nan", "0.0"]
         ) == 0
         got = [
             json.loads(ln)
@@ -102,7 +101,7 @@ class TestScoreCli:
         monkeypatch.setattr(
             sys, "stdin", io.StringIO(json.dumps(rec) + "\n")
         )
-        assert score_main([iris, "-", "--platform", "cpu"]) == 0
+        assert score_main([iris, "-"]) == 0
         out = capsys.readouterr().out.strip().splitlines()
         assert len(out) == 1
         ref = cm.score_records([rec])[0]
@@ -114,18 +113,16 @@ class TestScoreCli:
         bad = pathlib.Path(tmp_path, "bad.jsonl")
         bad.write_text("{not json}\n")
         with pytest.raises(SystemExit, match="invalid JSON"):
-            score_main([iris, str(bad), "--platform", "cpu"])
+            score_main([iris, str(bad)])
 
     def test_missing_files_are_typed_exits(self, tmp_path, iris):
         with pytest.raises(SystemExit, match="cannot read"):
-            score_main([iris, str(tmp_path / "nope.csv"),
-                        "--platform", "cpu"])
+            score_main([iris, str(tmp_path / "nope.csv")])
         good = pathlib.Path(tmp_path, "ok.jsonl")
         good.write_text("{}\n")
         with pytest.raises(SystemExit, match="cannot write"):
             score_main([iris, str(good), "-o",
-                        str(tmp_path / "no" / "dir" / "out.jsonl"),
-                        "--platform", "cpu"])
+                        str(tmp_path / "no" / "dir" / "out.jsonl")])
 
     def test_csv_numeric_looking_categoricals_ride_the_codec(self, tmp_path):
         # a CSV cell "2" for a string-categorical field must stay a
@@ -151,7 +148,7 @@ class TestScoreCli:
         csv_p.write_text("c\n2\n3\n")
         out_p = str(pathlib.Path(tmp_path, "out.jsonl"))
         assert score_main(
-            [str(model), str(csv_p), "-o", out_p, "--platform", "cpu"]
+            [str(model), str(csv_p), "-o", out_p]
         ) == 0
         got = [
             json.loads(ln)

@@ -4,7 +4,7 @@ The contracts under test:
 
 - classification (runtime/devfault.py): injected device faults and
   XLA runtime errors classify into the OOM / transient / chip-loss
-  taxonomy; record poison NEVER classifies as a device fault;
+  fault kinds; record poison NEVER classifies as a device fault;
 - the recovery ladder on both hot paths: transient errors re-dispatch
   the host-retained staging copy, OOM bisects the BATCH SIZE and feeds
   the AdaptiveBatcher cap, persistent streaks trip the circuit breaker
@@ -117,22 +117,37 @@ class TestClassify:
         assert devfault.classify(MemoryError()) is None
 
     def test_real_xla_runtime_errors(self):
-        try:
-            from jaxlib.xla_extension import XlaRuntimeError
-        except Exception:
-            pytest.skip("jaxlib layout exposes no XlaRuntimeError")
+        from jax.errors import JaxRuntimeError
+
         assert devfault.classify(
-            XlaRuntimeError(
+            JaxRuntimeError(
                 "RESOURCE_EXHAUSTED: Out of memory allocating "
                 "1073741824 bytes"
             )
         ) == devfault.KIND_OOM
         assert devfault.classify(
-            XlaRuntimeError("INTERNAL: Failed to execute XLA runtime")
+            JaxRuntimeError("INTERNAL: Failed to execute XLA runtime")
         ) == devfault.KIND_ERROR
         assert devfault.classify(
-            XlaRuntimeError("UNAVAILABLE: device lost: core halted")
+            JaxRuntimeError("UNAVAILABLE: device lost: core halted")
         ) == devfault.KIND_LOST
+
+    def test_mosaic_compile_refusal_classifies_as_device_error(self):
+        # the compiler's own sentence from the v5e (PR 21, a 512-row
+        # score block). Today it lands in the TRANSIENT kind — a
+        # deterministic compile refusal that the ladder will retry and
+        # then serve from the fallback tier; recorded, not endorsed
+        from jax.errors import JaxRuntimeError
+
+        assert devfault.classify(
+            JaxRuntimeError(
+                "INVALID_ARGUMENT: Mosaic failed to compile TPU kernel: "
+                "Failed to verify layout for Mosaic kernel operand 9: "
+                "XLA layout ({0:T(1024)S(1)}) does not match Mosaic "
+                "layout ({0:T(512)S(1)}) for an operand of shape "
+                "f32[16384]."
+            )
+        ) == devfault.KIND_ERROR
 
 
 # ---------------------------------------------------------------------------

@@ -444,7 +444,6 @@ class TestCrashLoopFingerprint:
         env["JAX_PLATFORMS"] = "cpu"
         env["FJT_FAULTS"] = "worker_crash:site=score_batch:offset=117"
         env["FJT_POISON_RESTARTS"] = "1"
-        env["FJT_XLA_CACHE"] = str(tmp_path / "xla")
         env.pop("FJT_RESTART_STREAK", None)
         deaths = 0
         for attempt in range(14):

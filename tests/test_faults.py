@@ -421,7 +421,6 @@ class TestMidBatchKillReplayBoundary:
         gen_gbm(str(tmp_path), n_trees=3, depth=3, n_features=4)
         env = dict(os.environ)
         env["JAX_PLATFORMS"] = "cpu"
-        env["FJT_XLA_CACHE"] = str(tmp_path / "xla")
         env.pop("FJT_RESTART_STREAK", None)
         # incarnation 1: die mid-batch (after drain+dispatch of the
         # batch holding offset 130, before its commit)

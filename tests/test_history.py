@@ -457,71 +457,3 @@ def test_govern_frame_matches_struct_governor_exactly():
         history.merge_frames([governed, dict(governed)])
     )
     assert a == b
-
-
-# ---------------------------------------------------------------------------
-# bench-trend tripwire
-# ---------------------------------------------------------------------------
-
-
-def _trend(repo, *extra):
-    return subprocess.run(
-        [
-            sys.executable,
-            os.path.join(REPO, "tools", "bench_trend.py"),
-            "--repo", str(repo), *extra,
-        ],
-        capture_output=True, text=True, timeout=60,
-    )
-
-
-def _write_round(repo, n, value, latency_ms):
-    with open(os.path.join(str(repo), f"BENCH_r{n}.json"), "w") as f:
-        json.dump(
-            {
-                "n": n,
-                "parsed": {
-                    "metric": "gbm_tput", "backend": "tpu",
-                    "value": value, "latency_ms": latency_ms,
-                },
-            },
-            f,
-        )
-
-
-def test_bench_trend_tripwire(tmp_path):
-    _write_round(tmp_path, 1, 100.0, 5.0)
-    _write_round(tmp_path, 2, 104.0, 4.9)
-    p = _trend(tmp_path)
-    assert p.returncode == 0, p.stdout + p.stderr
-    assert "REGRESSED" not in p.stdout
-
-    # latest throughput regresses >10% vs the best prior -> exit 2
-    _write_round(tmp_path, 3, 80.0, 4.9)
-    p = _trend(tmp_path)
-    assert p.returncode == 2, p.stdout + p.stderr
-    assert "gbm_tput.value" in p.stdout and "REGRESSED" in p.stdout
-    # ...and a wider tolerance forgives the same point
-    assert _trend(tmp_path, "--tolerance", "0.5").returncode == 0
-
-    # latency fields trend LOWER-better: a latency spike trips even
-    # when throughput recovers
-    _write_round(tmp_path, 4, 105.0, 9.0)
-    p = _trend(tmp_path, "--metric", "gbm_tput.latency_ms")
-    assert p.returncode == 2, p.stdout + p.stderr
-    assert "gbm_tput.latency_ms" in p.stdout
-
-    # a cpu-fallback capture is a separate series, never judged
-    # against the tpu best
-    with open(os.path.join(str(tmp_path), "BENCH_r5.json"), "w") as f:
-        json.dump(
-            {
-                "n": 5,
-                "parsed": {
-                    "metric": "gbm_tput", "backend": "cpu",
-                    "value": 1.0, "latency_ms": 500.0,
-                },
-            },
-            f,
-        )
-    assert _trend(tmp_path, "--metric", "gbm_tput.value").returncode == 0

@@ -106,12 +106,28 @@ class TestEligibility:
         doc = parse_pmml(xml)
         assert build_quantized_scorer(doc) is None
         cm = compile_pmml(doc)
-        assert cm.quantized_scorer() is None  # guarded probe, no raise
+        assert cm.quantized_scorer() is None  # outside the contract
         # and the f32 path still scores it (incl. the halt semantics)
         [pred] = cm.score_records([{"a": 1.0}])
         assert pred.score.value == pytest.approx(2.0)
         [pred] = cm.score_records([{}])
         assert pred.score.value == pytest.approx(0.5)
+
+    def test_probe_that_raises_propagates(self, tmp_path, monkeypatch):
+        # a document outside the fast path's contract returns None; a
+        # probe that RAISES is a defect and must not become a silent
+        # f32 scorer, on any backend
+        from flink_jpmml_tpu.compile import qtrees
+
+        def boom(*a, **kw):
+            raise RuntimeError("rank-wire build broke")
+
+        monkeypatch.setattr(qtrees, "build_quantized_scorer", boom)
+        cm = compile_pmml(
+            _gbm(tmp_path, n_trees=5, depth=3, n_features=4), batch_size=32
+        )
+        with pytest.raises(RuntimeError, match="rank-wire build broke"):
+            cm.quantized_scorer()
 
     def test_classification_not_eligible(self):
         xml = """<PMML xmlns="http://www.dmg.org/PMML-4_3" version="4.3">

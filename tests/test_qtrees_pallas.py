@@ -91,6 +91,66 @@ class TestPallasParity:
         assert qa is not None and qa.backend == "xla"
 
 
+class TestKernelContract:
+    """What build_pallas_fn declines is the kernel's documented
+    contract, not an error: the model is served by the XLA rank-wire
+    scorer, visible as ``backend == "xla"``."""
+
+    def _small(self, tmp_path):
+        return _doc(tmp_path, n_trees=5, depth=3, n_features=4)
+
+    def test_batch_not_a_whole_number_of_blocks_is_served_by_xla(
+        self, tmp_path
+    ):
+        doc = self._small(tmp_path)
+        for B in (1000, 1536):
+            assert build_quantized_scorer(
+                doc, batch_size=B, backend="pallas", pallas_interpret=True
+            ) is None
+            qa = build_quantized_scorer(
+                doc, batch_size=B, backend="auto", pallas_interpret=True
+            )
+            assert qa is not None and qa.backend == "xla"
+
+    def test_forest_over_the_vmem_budget_is_served_by_xla(
+        self, tmp_path, monkeypatch
+    ):
+        from flink_jpmml_tpu.compile import qtrees_pallas
+
+        doc = self._small(tmp_path)
+        monkeypatch.setattr(qtrees_pallas, "_VMEM_PARAM_BUDGET", 1024)
+        assert build_quantized_scorer(
+            doc, batch_size=64, backend="pallas", pallas_interpret=True
+        ) is None
+        qa = build_quantized_scorer(
+            doc, batch_size=64, backend="auto", pallas_interpret=True
+        )
+        assert qa is not None and qa.backend == "xla"
+
+    def test_score_block_is_1024_rows_or_the_whole_batch(self, tmp_path):
+        # what Mosaic refused on the v5e (PR 21): a 1-D f32 score block
+        # that is neither a multiple of 1024 rows nor the whole vector
+        from flink_jpmml_tpu.compile import qtrees_pallas
+
+        qp = build_quantized_scorer(
+            self._small(tmp_path), batch_size=64, backend="pallas",
+            pallas_interpret=True,
+        )
+        groups = {k: np.asarray(v) for k, v in qp.params.items()}
+
+        def build(batch, block_b):
+            return qtrees_pallas.build_pallas_fn(
+                groups, batch, 4, 255, block_b=block_b, interpret=True
+            )
+
+        assert build(2048, 512) is None
+        assert build(2048, 256) is None
+        assert build(2048, 1024) is not None
+        assert build(4096, 2048) is not None
+        assert build(512, 512) is not None  # the block is the batch
+        assert build(512, 1024) is not None  # halves down to the batch
+
+
 from flink_jpmml_tpu.pmml import parse_pmml
 from test_qtrees import _forest_xml
 

@@ -6,8 +6,7 @@ The pytest suite pins fixed seed ranges so CI stays deterministic and
 fast; this driver reuses the exact same generators and the exact same
 lane-by-lane compiled-vs-oracle assertion, but sweeps as many seeds as
 a soak budget allows, on whichever backend the session resolves
-(run plainly for the real chip; FJT_TEST_PLATFORM-style CPU pinning is
-the test suite's business, not this tool's).
+(run plainly for the real chip, under ``JAX_PLATFORMS=cpu`` for the host).
 
 Usage:
   python tools/fuzz_soak.py [--families trees,mining,regression,...]
@@ -865,7 +864,6 @@ def _soak_stateful_chaos(seed):
                 "FJT_RETRY_BASE_S": "0.01",
                 "FJT_FAILOVER_COOLDOWN_S": "0.1",
                 "FJT_FAILOVER_GREENS": "1",
-                "FJT_XLA_CACHE": os.path.join(tmp, "xla"),
                 "FJT_AUTOTUNE_CACHE": os.path.join(tmp, "autotune"),
             })
 

@@ -27,7 +27,7 @@ fails loudly on exactly the regressions new concurrency code breeds:
   ``fjt_records_out`` is non-zero and whose histogram ``_count``
   matches its ``+Inf`` bucket — the fleet dashboard's ground truth —
   and, since the attribution plane landed, non-zero per-stage
-  ``fjt_stage_seconds`` histograms, a live ``fjt_device_mfu`` gauge,
+  ``fjt_stage_seconds`` histograms, a live ``fjt_device_ns_per_record`` gauge,
   and at least one Prometheus exemplar whose trace id resolves to a
   ``latency_exemplar`` flight-recorder event;
 - **observability overhead**: the stage ledger + sampled device
@@ -100,7 +100,7 @@ sys.path.insert(0, str(pathlib.Path(__file__).resolve().parent.parent))
 WATCHDOG_S = float(os.environ.get("FJT_SMOKE_WATCHDOG_S", 150.0))
 
 # hermetic autotune cache: the smoke must neither inherit a developer's
-# real ~/.cache entries (a cached "fused" config would change which
+# .fjt_cache entries (a cached "fused" config would change which
 # path check_block_pipeline exercises) nor pollute them
 os.environ["FJT_AUTOTUNE_CACHE"] = os.path.join(
     tempfile.mkdtemp(prefix="fjt-smoke-at-"), "autotune.json"
@@ -488,8 +488,8 @@ def check_kernel_search() -> None:
             assert autotune.lookup(q.model_hash, key) is None, (
                 "an obsolete-space cache entry was honoured"
             )
-            # the --no-kernel-search ablation gate: legacy ref-only
-            # tile sweep, no layout candidates
+            # the --no-kernel-search ablation gate: the built default
+            # alone, no layout candidates
             os.environ["FJT_KERNEL_SEARCH_DISABLE"] = "1"
             try:
                 q2 = build_quantized_scorer(
@@ -521,7 +521,8 @@ def check_obs_scrape() -> None:
     assert the scrape is a truthful Prometheus rendering — non-zero
     ``fjt_records_out``, histogram ``_count`` == ``+Inf`` bucket,
     non-zero per-stage ``fjt_stage_seconds`` attribution, a live
-    ``fjt_device_mfu`` gauge (the sampled profiler fired), and ≥1
+    ``fjt_device_ns_per_record`` gauge (the sampled profiler fired; the
+    roofline gauges need a chip whose peaks are known), and ≥1
     exemplar resolving to a ``latency_exemplar`` flight event."""
     import re
     import urllib.request
@@ -602,13 +603,13 @@ def check_obs_scrape() -> None:
         for stage in ("encode", "sink"):
             key = f'fjt_stage_seconds_count{{stage="{stage}"}}'
             assert metrics.get(key, 0) > 0, f"{key} missing/zero"
-        # the live roofline: the sampled device profiler must have
-        # fired at least once during a real pipeline run
+        # the sampled device profiler must have fired at least once
+        # during a real pipeline run
         assert metrics.get("fjt_device_samples", 0) >= 1, (
             "device profiler never sampled"
         )
-        assert metrics.get("fjt_device_mfu", 0) > 0, (
-            "live fjt_device_mfu gauge missing/zero"
+        assert metrics.get("fjt_device_ns_per_record", 0) > 0, (
+            "live fjt_device_ns_per_record gauge missing/zero"
         )
         # ≥1 exemplar on the wire, resolvable to its flight event
         tids = re.findall(r'# \{trace_id="([^"]+)"\}', text)
