@@ -1,0 +1,195 @@
+"""The load generator: a jax-free child process that holds the Kafka
+broker and the producer, so that neither shares the interpreter lock
+with the pipeline under test.
+
+Protocol: one JSON object per line on stdin (commands) and stdout
+(replies). ``init`` → broker address; ``produce`` appends a stretch at
+once (the warm-up stream); ``start`` begins the measured producer;
+``delivered`` tells the producer how far the sink is and is answered
+with how far the log is, so the harness can take the log's lead itself;
+``stop`` ends the producer and returns its own account of the run.
+
+One producer, parameterised by the traffic file alone:
+``closed_backlog`` keeps ``produced - delivered`` at ``backlog_records``
+and reports the least it saw on any turn after the backlog was first
+full (the shape of ``bench._measure_kafka_mode``,
+flink_jpmml_tpu/bench.py:491, without its seek back to offset 0:
+offsets are fresh and increasing). ``producer_max_records_per_s``, null
+in every cell, holds it back: ``rehearse.py --starve`` proves with it
+that a producer slower than the pipeline fails the run.
+
+The broker is the program's ``MiniKafkaBroker``. Its public
+``append_rows`` keeps a Python ``bytes`` per record (0.2-0.5M records/s
+on one core, below what the pipeline drains), so ``_BulkBroker`` stores
+encoded segments only; it relies on the broker's ``_mu``, ``_segs`` and
+``_next`` (runtime/kafka.py:2040-2054) and says so if they are gone.
+"""
+
+from __future__ import annotations
+
+import json
+import os
+import sys
+import threading
+import time
+
+import numpy as np
+
+_BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, _BENCH)                   # lib
+sys.path.insert(1, os.path.dirname(_BENCH))  # the program's broker
+
+from lib.stream import Stream  # noqa: E402
+
+_SEG = 512  # records per stored record batch (MiniKafkaBroker._SEG_RECORDS)
+
+
+def _make_broker(topic: str):
+    from flink_jpmml_tpu.runtime import native
+    from flink_jpmml_tpu.runtime.kafka import MiniKafkaBroker
+
+    class _BulkBroker(MiniKafkaBroker):
+        """Appends pre-encoded segments without the per-record Python
+        objects; one producer thread, one partition."""
+
+        def __init__(self, *a, **kw):
+            super().__init__(*a, **kw)
+            for attr in ("_mu", "_segs", "_next"):
+                if not hasattr(self, attr):
+                    raise RuntimeError(
+                        f"MiniKafkaBroker has no {attr}: the benchmark's "
+                        "bulk append needs a new way in"
+                    )
+            self.produced = 0
+
+        def _publish(self, segs, n: int) -> None:
+            with self._mu:
+                if self._next[0] != self.produced:
+                    raise RuntimeError("log head moved under the producer")
+                self._segs[0].extend(segs)
+                self._next[0] = self.produced = self.produced + n
+                self._mu.notify_all()
+
+        def append_rows_bulk(self, rows: np.ndarray) -> None:
+            raw = np.ascontiguousarray(rows, np.float32).view(
+                np.uint8).reshape(rows.shape[0], -1)
+            base, segs = self.produced, []
+            for i in range(0, raw.shape[0], _SEG):
+                chunk = raw[i:i + _SEG]
+                blob = native.kafka_encode_fixed(chunk, base + i)
+                if blob is None:
+                    raise RuntimeError(
+                        "native record-batch encoder unavailable: "
+                        f"{native.build_error()}"
+                    )
+                segs.append((base + i, base + i + chunk.shape[0], blob))
+            self._publish(segs, raw.shape[0])
+
+    return _BulkBroker(topic=topic)
+
+
+class Generator:
+    def __init__(self, init: dict):
+        self.stream = Stream(
+            init["seed"], init["n_features"], init["key_domain"],
+            init["key_mix"], init["pool_rows"],
+        )
+        self.broker = _make_broker(init["topic"])
+        self._stop = threading.Event()
+        self._thread = None
+        self._delivered = 0
+        self._stats = {}
+        self._error = None
+
+    def append(self, lo: int, hi: int) -> None:
+        self.broker.append_rows_bulk(self.stream.rows(lo, hi))
+
+    def note_delivered(self, n: int) -> None:
+        self._delivered = int(n)
+
+    def start(self, traffic: dict, delivered: int) -> dict:
+        self._delivered = int(delivered)
+        target = {"closed_backlog": self._run_closed}[traffic["loop"]]
+        t0 = time.monotonic() + 0.05
+        self._thread = threading.Thread(
+            target=self._guard, args=(target, traffic, t0), daemon=True,
+        )
+        self._thread.start()
+        return {"t0": t0, "first_offset": self.broker.produced}
+
+    def _guard(self, target, traffic, t0) -> None:
+        try:
+            target(traffic, t0)
+        except BaseException as e:  # reported by stop(), which re-raises
+            self._error = e
+
+    def _run_closed(self, traffic: dict, t0: float) -> None:
+        chunk = int(traffic["chunk_records"])
+        want = int(traffic["backlog_records"])
+        cap = traffic.get("producer_max_records_per_s")
+        first = self.broker.produced
+        least, filled = None, False
+        while not self._stop.is_set():
+            backlog = self.broker.produced - self._delivered
+            if filled:
+                # every turn counts once the backlog has been full:
+                # before that the producer has not yet had its chance
+                least = backlog if least is None else min(least, backlog)
+            held = cap is not None and (
+                self.broker.produced - first
+                >= cap * (time.monotonic() - t0)
+            )
+            if backlog < want and not held:
+                lo = self.broker.produced
+                self.append(lo, lo + chunk)
+                continue
+            filled = filled or backlog >= want
+            time.sleep(0.002)
+        self._stats = {"least_backlog_records": least}
+
+    def stop(self) -> dict:
+        self._stop.set()
+        if self._thread is not None:
+            self._thread.join(timeout=30.0)
+            if self._thread.is_alive():
+                raise RuntimeError("producer thread did not stop")
+        if self._error is not None:
+            raise self._error
+        return dict(self._stats, produced=self.broker.produced)
+
+
+def main() -> None:
+    out = sys.stdout
+    gen = None
+
+    def reply(obj) -> None:
+        out.write(json.dumps(obj) + "\n")
+        out.flush()
+
+    try:
+        for line in sys.stdin:
+            msg = json.loads(line)
+            cmd = msg["cmd"]
+            if cmd == "init":
+                gen = Generator(msg)
+                reply({"host": gen.broker.host, "port": gen.broker.port})
+            elif cmd == "produce":
+                lo = gen.broker.produced
+                gen.append(lo, lo + int(msg["n"]))
+                reply({"produced": gen.broker.produced})
+            elif cmd == "start":
+                reply(gen.start(msg["traffic"], msg["delivered"]))
+            elif cmd == "delivered":
+                gen.note_delivered(msg["n"])
+                reply({"produced": gen.broker.produced})
+            elif cmd == "stop":
+                reply(gen.stop())
+            elif cmd == "exit":
+                break
+    finally:
+        if gen is not None:
+            gen.broker.close()
+
+
+if __name__ == "__main__":
+    main()

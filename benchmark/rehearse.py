@@ -1,0 +1,188 @@
+#!/usr/bin/env python3
+"""Rehearsals that cost no chip time. Run by hand, not part of tier-1:
+
+    JAX_PLATFORMS=cpu python3 benchmark/rehearse.py            # every cell, tiny
+    JAX_PLATFORMS=cpu python3 benchmark/rehearse.py --compile  # real size, for the v5e
+    python3 benchmark/rehearse.py --starve                     # on the chip: the guard trips
+
+The first runs every cell of ``BENCHMARK.json`` end to end on the CPU at
+a tiny size (20 trees, 61,001 slots with 45,000 keys resident, batch
+1024, a 2 s window) through ``run.run_cell``, once untraced and once
+traced, and asserts the result line's keys and that a CPU run reports
+no metric; then once more with the producer held to a tenth of what the
+pipeline drains, and asserts that the run is NOT correct and says why.
+On the CPU the rank wire scores on its XLA twin, not on the Pallas
+kernel.
+
+The second compiles the table's fill (``lib.prefill.device_table``) and
+the state fold (``statekernel._state_step``) for a described
+``v5e:2x2`` topology, one device, at each configuration's real table
+size and batch, and prints ``memory_analysis()``: what the 6.4 GB
+buffer, its donation and the scatters cost is known before chip time is
+spent. Nothing runs; it is not a chip run.
+
+The third is a chip run: every saturated cell at its real size for 10 s
+with the producer held to ``STARVED_RECORDS_PER_S``, well under what
+the pipeline drains. It has to end ``correct: false`` with the log's
+lead named as the fault, and exits non-zero if it does not.
+"""
+
+import argparse
+import json
+import os
+import sys
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+sys.path.insert(0, HERE)
+
+TINY = {
+    "cfg": {
+        "model": {"n_trees": 20},
+        "table_slots": 61001,  # not a power of two: keys are displaced, one wraps
+        "key_domain": 45000,
+        "resident_keys_at_start": 45000,
+        "compile_batch": 1024,
+        "pipeline": {"queue_capacity": 4096},
+        "warmup_records": 4196,
+    },
+    "traffic": {
+        "backlog_records": 131072, "least_backlog_allowed": 32768,
+        "chunk_records": 8192, "pool_rows": 512,
+        "settle_s": 0.5, "trace_seconds": 0.5,
+    },
+}
+TINY_STARVED = {
+    "cfg": TINY["cfg"],
+    "traffic": dict(TINY["traffic"], producer_max_records_per_s=20000),
+}
+STARVED_RECORDS_PER_S = 40000
+RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+
+
+def cells():
+    import run
+
+    with open(os.path.join(run.ROOT, "BENCHMARK.json")) as fh:
+        return [w["name"] for w in json.load(fh)["workloads"]]
+
+
+def expect_starved(cell: str, res: dict) -> None:
+    line = json.dumps(res)
+    print(line, flush=True)
+    if res["correct"] or res["failed"]:
+        sys.exit(f"{cell}: a starved run has to end correct: false with "
+                 f"nothing failed: {line}")
+
+
+def rehearse_cells() -> None:
+    import run
+
+    for cell in cells():
+        for trace in (0, 1):
+            args = argparse.Namespace(
+                workload=cell, seed=2**31 + 11, seconds=2.0, trace=trace
+            )
+            res = run.run_cell(args, overrides=TINY, on_chip=False)
+            line = json.dumps(res)
+            print(line, flush=True)
+            missing = RESULT_KEYS - set(res)
+            if missing:
+                sys.exit(f"{cell}: result lacks {sorted(missing)}")
+            if res["metrics"]:
+                sys.exit(f"{cell}: a CPU run printed metrics")
+            if res["device"]["platform"] == "tpu":
+                sys.exit("rehearse.py is for the CPU; use run.py on the chip")
+            if not res["correct"] or res["failed"]:
+                sys.exit(f"{cell} trace={trace}: not correct: {line}")
+        args = argparse.Namespace(
+            workload=cell, seed=2**31 + 11, seconds=2.0, trace=0
+        )
+        expect_starved(cell, run.run_cell(
+            args, overrides=TINY_STARVED, on_chip=False))
+    print("rehearsal: every cell ran end to end on the CPU, and failed "
+          "when its producer was held back", flush=True)
+
+
+def starve_on_chip() -> None:
+    import run
+
+    for cell in cells():
+        args = argparse.Namespace(
+            workload=cell, seed=2**31 + 11, seconds=10.0, trace=0
+        )
+        expect_starved(cell, run.run_cell(args, overrides={"traffic": {
+            "producer_max_records_per_s": STARVED_RECORDS_PER_S}}))
+    print("starved: every cell failed when its producer was held back",
+          flush=True)
+
+
+def compile_for_v5e() -> None:
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    import run
+
+    sys.path.insert(0, run.ROOT)
+    from flink_jpmml_tpu.compile import statekernel
+    from lib import prefill
+
+    jax.config.update("jax_enable_compilation_cache", False)
+    topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    one = SingleDeviceSharding(topo.devices[0])
+    with open(os.path.join(run.ROOT, "BENCHMARK.json")) as fh:
+        configs = json.load(fh)["configs"]
+    for c in configs:
+        with open(os.path.join(run.ROOT, c["file"])) as fh:
+            cfg = json.load(fh)
+        cap, B = int(cfg["table_slots"]), int(cfg["compile_batch"])
+        # rows as KeyedStateTable pads them (capacity + scratch, to 256)
+        rows = -(-(cap + 1) // 256) * 256
+
+        def fold(S, score, slots, rel, w, reset):
+            return statekernel._state_step(
+                S, score, slots, rel, w, reset, cap, 0.999
+            )
+
+        def sds(shape, dt):
+            return jax.ShapeDtypeStruct(shape, dt, sharding=one)
+
+        def report(what, compiled):
+            ma = compiled.memory_analysis()
+            print(f"{c['name']}: {what}: arguments "
+                  f"{ma.argument_size_in_bytes / 1e9:.2f} GB, aliased "
+                  f"{ma.alias_size_in_bytes / 1e9:.2f} GB, temporaries "
+                  f"{ma.temp_size_in_bytes / 1e9:.2f} GB, output "
+                  f"{ma.output_size_in_bytes / 1e9:.2f} GB", flush=True)
+
+        report(f"table fill [{rows}, 8] f32", prefill.table_program(
+            rows, cap).lower(sds((), jnp.uint32)).compile())
+        for donate in (True, False):
+            try:
+                compiled = jax.jit(
+                    fold, donate_argnums=(0,) if donate else ()
+                ).lower(
+                    sds((rows, 8), jnp.float32), sds((B,), jnp.float32),
+                    sds((B,), jnp.int32), sds((B,), jnp.float32),
+                    sds((B,), jnp.float32), sds((B,), jnp.bool_),
+                ).compile()
+                report(f"state fold [{rows}, 8] f32, batch {B}, "
+                       f"donated={donate}", compiled)
+            except Exception as e:  # the compiler's refusal is the finding
+                print(f"{c['name']}: donated={donate}: REFUSED "
+                      f"{type(e).__name__}: {str(e)[:400]}", flush=True)
+
+
+if __name__ == "__main__":
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--compile", action="store_true")
+    ap.add_argument("--starve", action="store_true")
+    a = ap.parse_args()
+    if a.starve:
+        starve_on_chip()
+    elif os.environ.get("JAX_PLATFORMS") != "cpu":
+        sys.exit("rehearse.py runs under JAX_PLATFORMS=cpu")
+    else:
+        compile_for_v5e() if a.compile else rehearse_cells()
