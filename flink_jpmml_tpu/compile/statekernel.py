@@ -76,44 +76,49 @@ def _state_step(S, score, slots, rel, w, reset, scratch, decay):
     score = score.astype(f32)
     rel = rel.astype(f32)
     w = w.astype(f32)
-    init = jnp.asarray(_INIT_ROW, f32)
-    # fresh slots re-initialize; rows with nothing to reset aim the
-    # write at the scratch row (re-zeroed at the end regardless)
-    sel = jnp.where(reset, slots, scratch)
-    S = S.at[sel].set(init)
-    pre = S[slots]
-    count = pre[:, COL_COUNT]
-    seen = count > 0
-    safe = jnp.maximum(count, 1.0)
-    mean = pre[:, COL_SUM] / safe
-    var = jnp.maximum(pre[:, COL_SQSUM] / safe - mean * mean, 0.0)
-    # product form: stored U = Σ λ^-rel_i, decayed count as of this
-    # record's stride = U · λ^rel (≤ U); the decayed mean is the
-    # ratio, where λ^rel cancels — epoch-independent by construction
-    dcount = pre[:, COL_DCOUNT] * jnp.power(f32(decay), rel)
-    dmean = pre[:, COL_DSUM] / jnp.maximum(
-        pre[:, COL_DCOUNT], _DCOUNT_FLOOR
-    )
-    gap = rel - pre[:, COL_LAST_T]
-    derived = jnp.stack(
-        [count, mean, var, dcount, dmean, gap,
-         pre[:, COL_MIN], pre[:, COL_MAX]],
-        axis=1,
-    )
-    derived = jnp.where(seen[:, None], derived, f32(0.0))
-    # commutative scatter updates: the five accumulator columns are
-    # contiguous, so they ride one column-sliced scatter-add
-    adds = jnp.stack(
-        [jnp.ones_like(score), score, score * score, w, w * score],
-        axis=1,
-    )
-    S = S.at[slots, COL_COUNT:COL_DSUM + 1].add(adds)
-    S = S.at[slots, COL_LAST_T].max(rel)
-    S = S.at[slots, COL_MIN].min(score)
-    S = S.at[slots, COL_MAX].max(score)
-    # bypass/pad contributions all landed on the scratch row — zero it
-    # so snapshots stay clean and the next batch's bypass reads zeros
-    S = S.at[scratch].set(jnp.zeros((STATE_WIDTH,), f32))
+    # the scopes are metadata on the traced ops (the op_name a device
+    # trace shows them under); the lowered program is the same without
+    with jax.named_scope("fjt.fold.gather"):
+        init = jnp.asarray(_INIT_ROW, f32)
+        # fresh slots re-initialize; rows with nothing to reset aim the
+        # write at the scratch row (re-zeroed at the end regardless)
+        sel = jnp.where(reset, slots, scratch)
+        S = S.at[sel].set(init)
+        pre = S[slots]
+        count = pre[:, COL_COUNT]
+        seen = count > 0
+        safe = jnp.maximum(count, 1.0)
+        mean = pre[:, COL_SUM] / safe
+        var = jnp.maximum(pre[:, COL_SQSUM] / safe - mean * mean, 0.0)
+        # product form: stored U = Σ λ^-rel_i, decayed count as of this
+        # record's stride = U · λ^rel (≤ U); the decayed mean is the
+        # ratio, where λ^rel cancels — epoch-independent by construction
+        dcount = pre[:, COL_DCOUNT] * jnp.power(f32(decay), rel)
+        dmean = pre[:, COL_DSUM] / jnp.maximum(
+            pre[:, COL_DCOUNT], _DCOUNT_FLOOR
+        )
+        gap = rel - pre[:, COL_LAST_T]
+        derived = jnp.stack(
+            [count, mean, var, dcount, dmean, gap,
+             pre[:, COL_MIN], pre[:, COL_MAX]],
+            axis=1,
+        )
+        derived = jnp.where(seen[:, None], derived, f32(0.0))
+    with jax.named_scope("fjt.fold.scatter"):
+        # commutative scatter updates: the five accumulator columns are
+        # contiguous, so they ride one column-sliced scatter-add
+        adds = jnp.stack(
+            [jnp.ones_like(score), score, score * score, w, w * score],
+            axis=1,
+        )
+        S = S.at[slots, COL_COUNT:COL_DSUM + 1].add(adds)
+        S = S.at[slots, COL_LAST_T].max(rel)
+        S = S.at[slots, COL_MIN].min(score)
+        S = S.at[slots, COL_MAX].max(score)
+        # bypass/pad contributions all landed on the scratch row — zero
+        # it so snapshots stay clean and the next batch's bypass reads
+        # zeros
+        S = S.at[scratch].set(jnp.zeros((STATE_WIDTH,), f32))
     return derived, S
 
 
@@ -150,7 +155,8 @@ def entry_for(q, kind: str, K: int, donate: bool,
     inner = base if K == 1 else q._scan_over(base, K)
 
     def state_fn(p, X, S, slots, rel, w, reset):
-        out = inner(p, X)
+        with jax.named_scope("fjt.forest"):
+            out = inner(p, X)
         derived, S2 = _state_step(
             S, _score_of(out), slots, rel, w, reset, scratch, decay
         )
@@ -182,7 +188,8 @@ def packed_entry(pack, donate: bool, decay: float, scratch: int,
     base = getattr(pack._jit_fn, "__wrapped__", pack._jit_fn)
 
     def state_fn(pps, Xp, S, slots, rel, w, reset):
-        outs = base(pps, Xp)
+        with jax.named_scope("fjt.forest"):
+            outs = base(pps, Xp)
         derived, S2 = _state_step(
             S, _score_of(outs[member]), slots, rel, w, reset,
             scratch, decay,

@@ -6,7 +6,8 @@ long batches took but not WHERE the time went. This module is the
 missing decomposition: every scored batch's wall time splits into the
 pipeline stages
 
-    fetch → decode → encode → h2d → queue_wait → device → readback → sink
+    fetch → decode → drain → encode → route → h2d → queue_wait →
+    device → readback → sink → commit
 
 each recorded into a ``stage_seconds{stage="..."}`` histogram in the
 caller's :class:`~flink_jpmml_tpu.utils.metrics.MetricsRegistry`. The
@@ -15,6 +16,19 @@ fleet metric uses, so per-stage attribution aggregates across workers
 exactly like the PR 3 quantiles: heartbeats piggyback them, the
 supervisor's ``/metrics`` merges them, and ``fjt-top`` renders the
 fleet-wide ranked list of which stage to attack next.
+
+**One span per stage** (:meth:`StageLedger.span`): a site wraps its
+work once and the interval lands, under ONE name, in three places —
+the stage histogram; the chrome-trace span file when ``FJT_TRACE_DIR``
+is set (obs/spans.py); and, as ``fjt.<stage>``, a
+``jax.profiler.TraceAnnotation`` on the thread that did the work, so
+whenever a profiler session runs (``jax.profiler.start_trace`` — no
+knob of this package arms it) the program's stages lie on
+``/host:CPU`` of the same xplane and the same clock as the device's
+``XLA Ops``. The span's keyword arguments ride the chrome span's
+``args`` and the annotation's stats: ``first_off``/``n`` identify the
+dispatch (the key the journey plane and the sink use), ``bytes`` sizes
+an ``h2d``, ``records`` a ``decode``.
 
 Stage semantics (who observes what):
 
@@ -27,10 +41,19 @@ Stage semantics (who observes what):
                   residual ingest cost once fetch+decode moved
                   off-thread — if this ranks high, the sidecar is the
                   bottleneck, not the hot path;
+- ``drain``     — the score thread taking a batch off the ring:
+                  ``ring.drain`` (its fill-or-deadline wait included)
+                  and the multi-chunk aggregation's copies;
 - ``encode``    — host featurize+align on the dispatch path
                   (``dispatch_quantized``; ≈0 when the encode is fused
                   on-device);
-- ``h2d``       — host-side staging + async dispatch issue;
+- ``route``     — keyed state's host routing on a state-armed
+                  dispatch: key hashing, ``maybe_renorm``,
+                  ``assign_slots``, the pad rows;
+- ``h2d``       — host-side staging + async dispatch issue (on the
+                  trace its two halves are the child annotations
+                  ``fjt.h2d.put`` and ``fjt.h2d.launch``: a long
+                  ``h2d`` reads as a copy or as a call that blocked);
 - ``queue_wait``— a ready batch waiting for an in-flight window slot
                   (``OverlappedDispatcher.launch`` on a full window);
 - ``device``    — SAMPLED pure device execution time (the profiler's
@@ -38,7 +61,14 @@ Stage semantics (who observes what):
                   sampled distribution, not every batch);
 - ``readback``  — host blocked fetching results (``finish_oldest`` /
                   ``wait``);
-- ``sink``      — sink delivery (block pipelines' ``_complete``).
+- ``sink``      — sink delivery (block pipelines' ``_complete``);
+- ``commit``    — the checkpoint tick after delivery
+                  (``CheckpointManager.maybe_save``);
+- ``prof_sample`` — the sampled device profiler's own bubble: its
+                  drain of the in-flight window and its bracket wait
+                  on the new dispatch (``OverlappedDispatcher.launch``
+                  once per ``FJT_PROF_SAMPLE`` interval), two
+                  intervals a sample.
 
 **Exemplars**: an observation landing at (or above) the highest bucket
 a stage has ever filled gets a trace id attached — recorded as a
@@ -55,9 +85,11 @@ of it records a ``stage_stall`` flight event (rate-limited: the flight
 ring is for rare events).
 
 Steady-state cost with nothing special happening: one dict lookup, one
-``bisect``, one locked histogram increment per stage per batch — the
-perf-smoke observability-overhead tripwire holds the total under 2% of
-hand-loop throughput.
+``bisect``, one locked histogram increment per stage per batch, plus
+the span's two clock reads and an unarmed ``TraceAnnotation`` (about a
+microsecond with no profiler session) — the perf-smoke
+observability-overhead tripwire holds the total under 2% of hand-loop
+throughput.
 """
 
 from __future__ import annotations
@@ -74,8 +106,8 @@ from flink_jpmml_tpu.obs import trace as trace_mod
 from flink_jpmml_tpu.utils.metrics import Histogram, MetricsRegistry
 
 STAGES = (
-    "fetch", "decode", "prefetch_wait", "encode", "h2d",
-    "queue_wait", "device", "readback", "sink",
+    "fetch", "decode", "prefetch_wait", "drain", "encode", "route", "h2d",
+    "queue_wait", "device", "readback", "sink", "commit", "prof_sample",
 )
 
 # which thread each stage is observed on — rendered as the fjt-top
@@ -88,13 +120,20 @@ STAGE_THREADS = {
     "fetch": "ingest",
     "decode": "ingest",
     "prefetch_wait": "ring-feed",  # hot path waiting on the handoff
+    "drain": "score",
     "encode": "score",
+    "route": "score",
     "h2d": "score",
     "queue_wait": "score",
     "device": "device",
     "readback": "score",
     "sink": "score",
+    "commit": "score",
+    "prof_sample": "score",
 }
+
+# the name a stage's span carries on the profiler's clock
+ANNOTATION_PREFIX = "fjt."
 
 _STALL_MS_ENV = "FJT_SLO_TARGET_MS"
 _STALL_FRAC_ENV = "FJT_SLO_STALL_FRAC"
@@ -120,6 +159,70 @@ def new_trace_id() -> str:
     return f"{os.getpid():x}-{seq:x}"
 
 
+_TRACE_ANNOTATION = None
+
+
+def trace_only(name: str, **args):
+    """A ``jax.profiler.TraceAnnotation`` named ``fjt.<name>``: an
+    interval on the profiler's clock and nowhere else (no histogram, no
+    chrome span) — the children of a stage (``h2d.put``/``h2d.launch``)
+    and the carrier inside every :class:`StageSpan`. jax is imported at
+    the first span, not with this module: fjt-top and the other
+    struct-reading tools stay jax-free."""
+    global _TRACE_ANNOTATION
+    if _TRACE_ANNOTATION is None:
+        from jax.profiler import TraceAnnotation
+
+        _TRACE_ANNOTATION = TraceAnnotation
+    return _TRACE_ANNOTATION(ANNOTATION_PREFIX + name, **args)
+
+
+class StageSpan:
+    """One interval of one stage: see :meth:`StageLedger.span`. After
+    the span ended, ``t0`` (``time.monotonic``) and ``seconds`` say
+    when and how long, for a caller that feeds a counter of its own
+    from the same interval."""
+
+    __slots__ = ("_ledger", "stage", "args", "t0", "seconds", "_ann")
+
+    def __init__(self, ledger: "StageLedger", stage: str, args: dict):
+        self._ledger = ledger
+        self.stage = stage
+        self.args = args
+        self.t0 = 0.0
+        self.seconds = 0.0
+        self._ann = None
+
+    def __enter__(self) -> "StageSpan":
+        # the annotation opens first and closes last: it encloses the
+        # booked interval by a microsecond, never the other way round
+        self._ann = trace_only(self.stage, **self.args)
+        self._ann.__enter__()
+        self.t0 = time.monotonic()
+        return self
+
+    def note(self, **args) -> None:
+        """Add what the site learned only while it worked (a drain's
+        ``n``) to the open span's arguments."""
+        self.args.update(args)
+        self._ann.set_metadata(**args)
+
+    def end(self) -> float:
+        """Close the span → its seconds."""
+        self.seconds = dt = time.monotonic() - self.t0
+        self._ann.__exit__(None, None, None)
+        if self._ledger.booked:
+            self._ledger.observe(self.stage, dt)
+        spans.emit(self.stage, self.t0, dt, **self.args)
+        return dt
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        # time spent is booked even when the work raised: the thread
+        # WAS in this stage for that long either way
+        self.end()
+        return False
+
+
 def stage_metric_name(stage: str) -> str:
     """The registry-name convention for the per-stage family (the obs
     server renders the suffix as a real Prometheus label, like
@@ -135,12 +238,16 @@ class StageLedger:
     score thread observes the dispatch-side stages.
     """
 
-    def __init__(self, metrics: MetricsRegistry):
+    def __init__(self, metrics: Optional[MetricsRegistry]):
         # weak: the _LEDGERS cache is keyed weakly on the registry, and
         # a strong back-reference from the cached VALUE would keep the
         # key alive forever (the documented WeakKeyDictionary caveat) —
         # every ephemeral bench/test registry would leak
-        self._metrics_ref = weakref.ref(metrics)
+        self._metrics_ref = (
+            weakref.ref(metrics) if metrics is not None else lambda: None
+        )
+        # False only for UNBOOKED: spans without a registry to book in
+        self.booked = metrics is not None
         self._hists: Dict[str, Histogram] = {}
         self._mu = threading.Lock()
         # per-stage exemplar state: [max bucket idx, last capture t,
@@ -170,8 +277,26 @@ class StageLedger:
             self._hists[stage] = h
         return h
 
+    def span(self, stage: str, **args) -> StageSpan:
+        """Context manager over one interval of ``stage`` on the
+        calling thread. On exit the interval is booked into
+        ``stage_seconds{stage=...}`` exactly as :meth:`observe` books a
+        duration, emitted as the chrome span ``<stage>`` when
+        ``FJT_TRACE_DIR`` is set, and — held over the whole interval —
+        it is the ``jax.profiler.TraceAnnotation`` ``fjt.<stage>``, so
+        a running profiler session sees it beside the device's ops.
+        ``args`` ride the chrome span and the annotation."""
+        return StageSpan(self, stage, args)
+
+    def begin(self, stage: str, **args) -> StageSpan:
+        """:meth:`span` for a site that cannot wrap its work: → the
+        started span; the site calls its ``end()`` on the same
+        thread."""
+        return StageSpan(self, stage, args).__enter__()
+
     def observe(self, stage: str, seconds: float) -> None:
-        """Record one batch's time in ``stage``; captures an exemplar
+        """Record one batch's time in ``stage`` for a caller that has
+        only a duration (the sampled ``device`` stage); captures an exemplar
         when the observation lands in the stage's top-ever bucket and
         a ``stage_stall`` flight event when a ``queue_wait`` crosses
         the configured deadline fraction."""
@@ -256,6 +381,12 @@ class StageLedger:
 # ephemeral bench registries die normally
 _LEDGERS: "weakref.WeakKeyDictionary" = weakref.WeakKeyDictionary()
 _LEDGERS_MU = threading.Lock()
+
+
+# spans for a caller that has no registry (a bare dispatch_quantized, a
+# source built without metrics): on the chrome trace and the profiler's
+# clock like any other, booked nowhere
+UNBOOKED = StageLedger(None)
 
 
 def ledger_for(metrics: Optional[MetricsRegistry]) -> Optional[StageLedger]:

@@ -1,8 +1,9 @@
 """Chrome-tracing span export (Perfetto-loadable), env-gated.
 
 ``FJT_TRACE_DIR=/tmp/fjt-trace`` makes the runtime's host-side stages
-(featurize / h2d+dispatch / readback / sink via ``profiling.StageTimer``
-and ``annotate``) and the :class:`OverlappedDispatcher` in-flight window
+(the block path's ``StageLedger.span`` stages, obs/attr.py: fetch /
+decode / prefetch_wait / drain / encode / route / h2d / queue_wait /
+readback / sink / commit; the record path's ``profiling.StageTimer``)
 emit complete-events (``"ph": "X"``) into
 ``$FJT_TRACE_DIR/spans-<pid>.trace.json`` — load the file in
 https://ui.perfetto.dev or chrome://tracing to see where stream time
@@ -183,8 +184,8 @@ def emit(name: str, t0_s: float, dur_s: float, **args) -> None:
     w = writer()
     if w is not None:
         # causal linkage (obs/trace.py): when a journey context is
-        # active on this thread, every span — StageTimer stages,
-        # annotate blocks, featurize/h2d/readback/sink — carries the
+        # active on this thread, every span — StageTimer stages, the
+        # ledger's encode/route/h2d/readback/sink — carries the
         # journey's trace/span ids, so fjt-trace can attach the span
         # timeline to the record journey it belongs to. One
         # thread-local read; only paid when tracing is on at all.
@@ -206,8 +207,3 @@ def flush() -> None:
 
 
 atexit.register(flush)
-
-
-def span_clock() -> float:
-    """The clock spans are stamped on (`time.monotonic`)."""
-    return time.monotonic()

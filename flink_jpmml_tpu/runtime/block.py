@@ -34,7 +34,6 @@ from flink_jpmml_tpu.obs import drift as drift_mod
 from flink_jpmml_tpu.obs import freshness as fresh_mod
 from flink_jpmml_tpu.obs import pressure as pressure_mod
 from flink_jpmml_tpu.obs import recorder as flight
-from flink_jpmml_tpu.obs import spans
 from flink_jpmml_tpu.obs import trace as trace_mod
 from flink_jpmml_tpu.runtime import devfault
 from flink_jpmml_tpu.runtime import faults
@@ -819,7 +818,9 @@ class BlockPipelineBase:
                 metrics=self.metrics,
                 donation_hits=self._donation_hits,
                 state=st,
-                offsets=self._cur_offsets if st is not None else None,
+                # the state stage's decay clock + replay guard, and the
+                # dispatch's first_off on its encode/route/h2d spans
+                offsets=self._cur_offsets,
             )
         if self._state is not None and not self._state_bypass:
             raise InputValidationException(
@@ -865,7 +866,7 @@ class BlockPipelineBase:
         scored, so bisection isolates an injected poison the same way
         it isolates a real one."""
         faults.fire("score_batch", offsets=offsets)
-        self._cur_offsets = offsets  # state-stage decay clock + replay guard
+        self._cur_offsets = offsets  # read by _dispatch_bound
         return self._dispatch(handle, X, n)
 
     def _on_dispatch_error(self, out, meta, error) -> bool:
@@ -1556,7 +1557,8 @@ class BlockPipelineBase:
                 self.committed_offset = first_off + n
                 if freshness is not None:
                     freshness.discard_stamps(first_off, n)
-                self._ckpt.maybe_save(self._ckpt_state)
+                with ledger.span("commit", first_off=first_off, n=n):
+                    self._ckpt.maybe_save(self._ckpt_state)
                 if monitor is not None:
                     monitor.maybe_tick()
                 return
@@ -1569,20 +1571,14 @@ class BlockPipelineBase:
                 # plane under the model's "#state" label (state
                 # corruption surfaces as feature drift)
                 out, derived = state_mod.split_output(out)
-            t_sink = time.monotonic()
             # the completing batch's OWN context wraps the sink: its
             # span (and any exemplar the sink stage captures) must
             # carry THIS journey's ids, not whichever batch the score
             # loop happens to be launching right now
             with trace_mod.use(jctx):
-                self._emit(out, n, first_off, decode)
-                t_done = time.monotonic()
-                spans.emit(
-                    "sink", t_sink, t_done - t_sink, n=n,
-                    first_off=first_off,
-                )
-                if ledger is not None:
-                    ledger.observe("sink", t_done - t_sink)
+                with ledger.span("sink", first_off=first_off, n=n) as sp:
+                    self._emit(out, n, first_off, decode)
+            t_done = sp.t0 + sp.seconds
             if dplane is not None:
                 # score-distribution sketch at the sink (sampled): shed
                 # batches never reach here, so a shed record can no
@@ -1634,7 +1630,8 @@ class BlockPipelineBase:
                 # range: record_staleness_s books + the sink-stage
                 # watermark (watermark_ts) advance here, after delivery
                 freshness.observe_sink(first_off, n)
-            self._ckpt.maybe_save(self._ckpt_state)
+            with ledger.span("commit", first_off=first_off, n=n):
+                self._ckpt.maybe_save(self._ckpt_state)
             if self._slo is not None:
                 self._slo.maybe_tick()
             if monitor is not None:
@@ -1680,24 +1677,28 @@ class BlockPipelineBase:
                     monitor.note_ring(
                         min(len(self._ring) / ring_cap, 1.0)
                     )
-                if self._carry_drain:
-                    X, offsets = self._carry_drain.pop(0)
-                else:
-                    X, offsets = self._ring.drain(
-                        batch_cfg.deadline_us, idle_us
-                    )
-                n = X.shape[0]
-                # ring fill fraction AFTER the drain: the producer-side
-                # saturation input to the pressure score (1.0 = the
-                # ingest thread is blocked pushing)
-                ring_occ.set(min(len(self._ring) / ring_cap, 1.0))
-                if (
-                    n == self._batch_size  # drain limit = model batch
-                    and self._max_dispatch_chunks > 1
-                ):
-                    X, offsets, n = self._aggregate_full_batches(
-                        X, offsets, self._batch_size
-                    )
+                # drain ends before the batch's offsets are known: its
+                # span carries n alone
+                with ledger.span("drain") as sp:
+                    if self._carry_drain:
+                        X, offsets = self._carry_drain.pop(0)
+                    else:
+                        X, offsets = self._ring.drain(
+                            batch_cfg.deadline_us, idle_us
+                        )
+                    n = X.shape[0]
+                    # ring fill fraction AFTER the drain: the
+                    # producer-side saturation input to the pressure
+                    # score (1.0 = the ingest thread is blocked pushing)
+                    ring_occ.set(min(len(self._ring) / ring_cap, 1.0))
+                    if (
+                        n == self._batch_size  # drain limit = model batch
+                        and self._max_dispatch_chunks > 1
+                    ):
+                        X, offsets, n = self._aggregate_full_batches(
+                            X, offsets, self._batch_size
+                        )
+                    sp.note(n=n)
                 if n == 0:
                     if self._ring.closed:
                         break
@@ -1753,6 +1754,10 @@ class BlockPipelineBase:
                                 time.monotonic(), True, None, None, None,
                             ),
                             accounted=False,
+                            ident={
+                                "first_off": int(offsets[0]) if n else 0,
+                                "n": n,
+                            },
                         )
                         continue
                 handle = self._acquire(disp.finish_oldest)
@@ -1867,6 +1872,7 @@ class BlockPipelineBase:
                                 offsets if self._retain_batches else None,
                                 jctx,
                             ),
+                            ident={"first_off": first_off, "n": n},
                             # opts this launch into the sampled
                             # device-timing pool (rate-limited;
                             # obs/profiler.py) — the live MFU/membw

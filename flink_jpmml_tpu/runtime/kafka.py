@@ -1153,7 +1153,7 @@ class _KafkaSourceBase:
         )
         # resolved once, like _fetch_hist: the per-registry lookup is a
         # lock + WeakKeyDictionary hit, too much for the per-fetch path
-        self._ledger = attr.ledger_for(metrics)
+        self._ledger = attr.ledger_for(metrics) or attr.UNBOOKED
         # event-time freshness (obs/freshness.py): the tracker is the
         # per-REGISTRY singleton — the pipeline sharing this registry
         # consumes at its sink the stamps this source writes at fetch —
@@ -1250,15 +1250,10 @@ class _KafkaSourceBase:
             pass
 
     def _observe_fetch(self, part: int, offset: int, hw: int,
-                       t0: float) -> None:
+                       dt: float) -> None:
         if self._metrics is None:
             return
-        dt = time.monotonic() - t0
         self._fetch_hist.observe(dt)
-        # the attribution plane's fetch column (obs/attr.py): kafka
-        # fetch RPC time per fetch, merged fleet-wide like every stage
-        if self._ledger is not None:
-            self._ledger.observe("fetch", dt)
         g = self._lag_gauges.get(part)
         if g is None:
             g = self._metrics.gauge(f'kafka_lag{{partition="{part}"}}')
@@ -1274,56 +1269,50 @@ class _KafkaSourceBase:
     def _fetch_part(
         self, part: int, offset: int, max_wait_ms: Optional[int] = None
     ) -> List[Tuple[int, bytes]]:
-        t0 = time.monotonic()
-        try:
-            # fault hooks INSIDE the try: an injected broker death rides
-            # the same except → reconnect/backoff path a real one does,
-            # and an injected slow fetch lands in the fetch histogram
-            faults.fire("kafka_fetch")
-            hw, record_set = self._client.fetch_raw(
-                self._topic, part, offset,
-                max_wait_ms=(
-                    self._max_wait_ms if max_wait_ms is None else max_wait_ms
-                ),
-                max_bytes=self._max_bytes,
-            )
-        except KafkaPartitionError:
-            raise  # misconfiguration: fail fast, don't reconnect-loop
-        except (OSError, ConnectionError, KafkaProtocolError):
-            self._reconnect()
-            self._sweep_lag_age()
-            return []
-        self._backoff.reset()  # a successful fetch closes the streak
-        self._note_event_times(part, record_set)
-        self._observe_fetch(part, offset, hw, t0)
         return [
             rec
-            for rec in decode_record_batches(record_set)
+            for rec in decode_record_batches(
+                self._fetch_raw_part(part, offset, max_wait_ms)
+            )
             if rec[0] >= offset
         ]
 
     def _fetch_raw_part(
         self, part: int, offset: int, max_wait_ms: Optional[int] = None
     ) -> bytes:
-        t0 = time.monotonic()
-        try:
-            faults.fire("kafka_fetch")  # see _fetch_part
-            hw, raw = self._client.fetch_raw(
-                self._topic, part, offset,
-                max_wait_ms=(
-                    self._max_wait_ms if max_wait_ms is None else max_wait_ms
-                ),
-                max_bytes=self._max_bytes,
-            )
-        except KafkaPartitionError:
-            raise  # misconfiguration: fail fast, don't reconnect-loop
-        except (OSError, ConnectionError, KafkaProtocolError):
+        # the attribution plane's fetch column (obs/attr.py): kafka
+        # fetch RPC time per fetch, a failed one included, merged
+        # fleet-wide like every stage. The span holds the event-time
+        # walk and not the reconnect's backoff sleep
+        raw = None
+        with self._ledger.span("fetch", part=part, offset=offset) as sp:
+            try:
+                # fault hooks INSIDE the try: an injected broker death
+                # rides the same except → reconnect/backoff path a real
+                # one does, and an injected slow fetch lands in the
+                # fetch histogram
+                faults.fire("kafka_fetch")
+                hw, raw = self._client.fetch_raw(
+                    self._topic, part, offset,
+                    max_wait_ms=(
+                        self._max_wait_ms if max_wait_ms is None
+                        else max_wait_ms
+                    ),
+                    max_bytes=self._max_bytes,
+                )
+            except KafkaPartitionError:
+                raise  # misconfiguration: fail fast, don't reconnect-loop
+            except (OSError, ConnectionError, KafkaProtocolError):
+                pass  # reconnect below, once the span has closed
+            else:
+                self._backoff.reset()  # a successful fetch closes the streak
+                self._note_event_times(part, raw)
+                sp.note(bytes=len(raw))
+        if raw is None:
             self._reconnect()
             self._sweep_lag_age()
             return b""
-        self._backoff.reset()
-        self._note_event_times(part, raw)
-        self._observe_fetch(part, offset, hw, t0)
+        self._observe_fetch(part, offset, hw, sp.seconds)
         return raw
 
     def _note_decode_error(self, part, off: int, value: bytes, exc) -> None:
@@ -1763,23 +1752,20 @@ class KafkaBlockSource(_KafkaSourceBase, BlockSource):
         historical ValueError propagates (a skip nobody can see would
         be silent data loss); the strict interleave also re-raises —
         its round-robin bijection cannot tolerate a dropped lane."""
-        t0 = time.monotonic() if self._decode_s is not None else None
-        try:
+        with self._ledger.span("decode", bytes=len(raw)) as sp:
             try:
                 offs, rows = decode_record_batches_rows(raw, self._cols)
-                return offs, rows, None
+                out = offs, rows, None
             except ValueError:
                 if self._strict and self._multi:
                     raise
                 if self._dlq is None and self._metrics is None:
                     raise
-                return self._decode_rows_lenient(raw, part)
-        finally:
-            if t0 is not None:
-                dt = time.monotonic() - t0
-                self._decode_s.inc(dt)
-                if self._ledger is not None:
-                    self._ledger.observe("decode", dt)
+                out = self._decode_rows_lenient(raw, part)
+            sp.note(records=len(out[0]))
+        if self._decode_s is not None:
+            self._decode_s.inc(sp.seconds)
+        return out
 
     def _decode_rows_lenient(self, raw: bytes, part):
         """Per-record decode isolating wrong-length values (CRC and

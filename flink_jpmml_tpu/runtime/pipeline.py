@@ -67,7 +67,6 @@ from flink_jpmml_tpu.obs import attr as attr_mod
 from flink_jpmml_tpu.obs import drift as drift_mod
 from flink_jpmml_tpu.obs import profiler as prof_mod
 from flink_jpmml_tpu.obs import recorder as flight
-from flink_jpmml_tpu.obs import spans
 from flink_jpmml_tpu.runtime import faults
 from flink_jpmml_tpu.utils.exceptions import FlinkJpmmlTpuError
 from flink_jpmml_tpu.utils.metrics import MetricsRegistry
@@ -204,60 +203,63 @@ def dispatch_quantized(
     (callers unwrap via ``state.is_state_output``). ``state_keys`` are
     precomputed uint32 key hashes (default: hash the table's key
     column of ``X``); ``offsets`` are the records' ring offsets —
-    the decay clock and the exactly-once replay guard. Unarmed cost is
-    one ``is None`` check."""
+    the decay clock and the exactly-once replay guard, and, state or
+    none, the ``first_off`` this dispatch's ``encode``/``route``/``h2d``
+    spans carry (obs/attr.py). Unarmed cost is one ``is None`` check."""
     enc, h2d = (
         _wire_counters(metrics) if metrics is not None else (None, None)
     )
+    # per-batch stage attribution (obs/attr.py): one span a stage, and
+    # the same registry's stage_seconds{stage=...} histograms merge
+    # fleet-wide like every other metric
+    ledger = attr_mod.ledger_for(metrics) or attr_mod.UNBOOKED
+    offs = np.asarray(offsets, np.int64) if offsets is not None else None
+    # the dispatch's identity on its encode/route/h2d spans: the
+    # (first_off, n) key the journey plane and the sink already use
+    ident = {"n": len(X)}
+    if offs is not None and offs.size:
+        ident["first_off"] = int(offs[0])
     # data-drift profiling (obs/drift.py) on the RAW batch, before any
     # encode touches it: None + one env lookup when FJT_DRIFT_SAMPLE is
     # unset (the pinned zero-records contract); rate-limited + overhead-
-    # budgeted when armed. Outside the encode timing window below so
-    # encode_s / the encode stage ledger stay honest.
+    # budgeted when armed. Outside the encode span below so encode_s /
+    # the encode stage stay honest.
     dplane = drift_mod.plane_for(metrics)
     if dplane is not None:
         dplane.record_features(q, X, M)
-    t0 = time.monotonic()
     fused = getattr(q, "encode_mode", "host") == "fused" and q.supports_fused
-    if fused:
-        owned = False  # does X already sit in a buffer only we hold?
-        if M is not None and np.asarray(M).any():
-            X = np.where(M, np.nan, np.asarray(X, np.float32))
-            owned = True
-        payload, K = q.pad_f32(X)
-        if payload is X and not owned:
-            # an unpadded f32-contiguous batch passes through pad_f32
-            # unchanged, and the caller's array may alias a REUSED ring
-            # drain buffer — which jax's CPU backend can zero-copy
-            # alias straight into the async dispatch, letting the next
-            # drain overwrite an in-flight batch. The host path never
-            # hits this (wire.encode always allocates); the fused path
-            # must ship a private copy. (One memcpy per batch — the
-            # same cost the ring drain itself pays.)
-            payload = np.array(payload, copy=True)
-        predict = q.predict_fused_padded
-    else:
-        # layout-aware staging: pad_wire routes the codes through the
-        # scorer's adopted wire packing (compile/layouts.py WirePack)
-        # when the kernel search chose one, so the staged payload,
-        # h2d_bytes, and the donation accounting all see the packed
-        # wire without any per-call-site knowledge
-        payload, K = q.pad_wire(q.wire.encode(X, M))
-        predict = q.predict_padded
-    t1 = time.monotonic()
-    spans.emit(
-        "featurize", t0, t1 - t0, fused=fused,
-        layout=getattr(q, "layout", "ref"),
-    )
-    # per-batch stage attribution (obs/attr.py): the same registry's
-    # stage_seconds{stage=...} histograms merge fleet-wide like every
-    # other metric; encode covers featurize+align, h2d the host-side
-    # staging + async dispatch issue
-    ledger = attr_mod.ledger_for(metrics)
+    # encode covers featurize+align
+    with ledger.span(
+        "encode", fused=fused, layout=getattr(q, "layout", "ref"), **ident
+    ) as sp:
+        if fused:
+            owned = False  # does X already sit in a buffer only we hold?
+            if M is not None and np.asarray(M).any():
+                X = np.where(M, np.nan, np.asarray(X, np.float32))
+                owned = True
+            payload, K = q.pad_f32(X)
+            if payload is X and not owned:
+                # an unpadded f32-contiguous batch passes through
+                # pad_f32 unchanged, and the caller's array may alias a
+                # REUSED ring drain buffer — which jax's CPU backend can
+                # zero-copy alias straight into the async dispatch,
+                # letting the next drain overwrite an in-flight batch.
+                # The host path never hits this (wire.encode always
+                # allocates); the fused path must ship a private copy.
+                # (One memcpy per batch — the same cost the ring drain
+                # itself pays.)
+                payload = np.array(payload, copy=True)
+            predict = q.predict_fused_padded
+        else:
+            # layout-aware staging: pad_wire routes the codes through
+            # the scorer's adopted wire packing (compile/layouts.py
+            # WirePack) when the kernel search chose one, so the staged
+            # payload, h2d_bytes, and the donation accounting all see
+            # the packed wire without any per-call-site knowledge
+            payload, K = q.pad_wire(q.wire.encode(X, M))
+            predict = q.predict_padded
     if enc is not None:
-        enc.inc(t1 - t0)
-    if ledger is not None:
-        ledger.observe("encode", t1 - t0)
+        enc.inc(sp.seconds)
     if h2d is not None:
         h2d.inc(payload.nbytes)
     st_args = None
@@ -265,79 +267,66 @@ def dispatch_quantized(
         # keyed state routing (host-side slot assignment; the state
         # gather/update itself is traced into the dispatch below) —
         # one vectorized pass per batch, zero per-record host work
-        n_rec = np.asarray(X).shape[0]
-        khash = (
-            np.asarray(state_keys, np.uint32)
-            if state_keys is not None
-            else state.hash_keys(state.extract_keys(X))
-        )
-        offs = (
-            np.asarray(offsets, np.int64) if offsets is not None
-            else None
-        )
-        first = (
-            int(offs[0]) if offs is not None and offs.size
-            else state.applied_hi
-        )
-        state.maybe_renorm(first)
-        slots, reset, rel, w = state.assign_slots(khash, offs)
-        pad = payload.shape[0] - n_rec
-        if pad > 0:
-            # alignment rows ride the scratch slot with zero weight —
-            # by construction they cannot touch any key's state
-            slots = np.concatenate(
-                [slots, np.full(pad, state.scratch, np.int32)]
+        with ledger.span("route", **ident):
+            khash = (
+                np.asarray(state_keys, np.uint32)
+                if state_keys is not None
+                else state.hash_keys(state.extract_keys(X))
             )
-            reset = np.concatenate([reset, np.zeros(pad, bool)])
-            rel = np.concatenate([rel, np.zeros(pad, np.float32)])
-            w = np.concatenate([w, np.zeros(pad, np.float32)])
-        st_args = (slots, rel, w, reset)
+            state.maybe_renorm(ident.get("first_off", state.applied_hi))
+            slots, reset, rel, w = state.assign_slots(khash, offs)
+            pad = payload.shape[0] - ident["n"]
+            if pad > 0:
+                # alignment rows ride the scratch slot with zero weight
+                # — by construction they cannot touch any key's state
+                slots = np.concatenate(
+                    [slots, np.full(pad, state.scratch, np.int32)]
+                )
+                reset = np.concatenate([reset, np.zeros(pad, bool)])
+                rel = np.concatenate([rel, np.zeros(pad, np.float32)])
+                w = np.concatenate([w, np.zeros(pad, np.float32)])
+            st_args = (slots, rel, w, reset)
         predict_state = (
             q.predict_fused_padded_state if fused
             else q.predict_padded_state
         )
-    if not donate:
-        if st_args is None:
-            out = predict(payload, K)  # async dispatch
-        else:
-            out, derived, S2 = predict_state(payload, K, state,
-                                             *st_args)
-            state.commit(S2)
-            out = (out, derived)
-        t2 = time.monotonic()
-        spans.emit("h2d_dispatch", t1, t2 - t1, bytes=payload.nbytes)
-        if ledger is not None:
-            ledger.observe("h2d", t2 - t1)
-        return out
-    import jax
-
-    if fused:
-        filter_donate_warning(rf"float32\[\d+,{payload.shape[1]}\]")
-    staged = jax.device_put(payload)  # async H2D staging copy
-    if st_args is None:
-        out = predict(staged, K, donate=True)
-    else:
-        # the state buffer donates alongside the batch: its update is
-        # in-place on device (one [rows, 8] buffer in steady state)
-        filter_donate_warning(r"float32\[\d+,8\]")
-        if not fused:
-            # the uint wire payload rides the same donated call and can
-            # never output-alias its scores — the same inert warning
-            # the block pipelines' uint-wire filter suppresses
-            filter_donate_warning(
-                rf"uint(?:8|16)\[\d+,{payload.shape[1]}\]"
-            )
-        out, derived, S2 = predict_state(staged, K, state, *st_args,
-                                         donate=True)
-        state.commit(S2)
-        out = (out, derived)
-    t2 = time.monotonic()
-    spans.emit("h2d_dispatch", t1, t2 - t1, bytes=payload.nbytes)
-    if ledger is not None:
-        ledger.observe("h2d", t2 - t1)
-    deleted = getattr(staged, "is_deleted", None)
-    if deleted is not None and deleted() and donation_hits is not None:
-        donation_hits.inc()
+    # h2d: the host-side staging + async dispatch issue, and nothing
+    # else; its two halves are children on the profiler's clock only
+    staged = None
+    with ledger.span("h2d", bytes=payload.nbytes, **ident):
+        if donate:
+            if fused:
+                filter_donate_warning(rf"float32\[\d+,{payload.shape[1]}\]")
+            if st_args is not None:
+                # the state buffer donates alongside the batch: its
+                # update is in-place on device (one [rows, 8] buffer in
+                # steady state)
+                filter_donate_warning(r"float32\[\d+,8\]")
+                if not fused:
+                    # the uint wire payload rides the same donated call
+                    # and can never output-alias its scores — the same
+                    # inert warning the block pipelines' uint-wire
+                    # filter suppresses
+                    filter_donate_warning(
+                        rf"uint(?:8|16)\[\d+,{payload.shape[1]}\]"
+                    )
+            with attr_mod.trace_only("h2d.put"):
+                # async H2D staging copy
+                payload = staged = jax.device_put(payload)
+        kw = {"donate": True} if donate else {}
+        with attr_mod.trace_only("h2d.launch"):
+            if st_args is None:
+                out = predict(payload, K, **kw)  # async dispatch
+            else:
+                out, derived, S2 = predict_state(
+                    payload, K, state, *st_args, **kw
+                )
+                state.commit(S2)
+                out = (out, derived)
+    if staged is not None and donation_hits is not None:
+        deleted = getattr(staged, "is_deleted", None)
+        if deleted is not None and deleted():
+            donation_hits.inc()
     return out
 
 
@@ -348,12 +337,17 @@ class _InFlight:
     fetch failure when it left poisoned — a later ``wait`` re-raises it
     instead of handing back a never-synchronized result."""
 
-    __slots__ = ("out", "meta", "t_launch", "done", "error", "accounted")
+    __slots__ = (
+        "out", "meta", "t_launch", "done", "error", "accounted", "ident",
+    )
 
     def __init__(self, out: Any, meta: Any, t_launch: float,
-                 accounted: bool = True):
+                 accounted: bool = True, ident: Optional[dict] = None):
         self.out = out
         self.meta = meta
+        # what the wait on this entry carries on its span (the block
+        # pipelines pass the dispatch's first_off and n)
+        self.ident = ident or {}
         self.t_launch = t_launch
         self.done = False
         self.error: Optional[BaseException] = None
@@ -451,6 +445,7 @@ class OverlappedDispatcher:
         meta: Any = None,
         profile: Optional[dict] = None,
         accounted: bool = True,
+        ident: Optional[dict] = None,
     ) -> _InFlight:
         """Dispatch asynchronously and admit the result to the window.
 
@@ -470,6 +465,10 @@ class OverlappedDispatcher:
         ``device_mfu``/``device_membw_util`` gauges and the kernel cost
         ledger. Unsampled launches pay one predicate check.
 
+        ``ident`` (``{"first_off": ..., "n": ...}``) names this
+        dispatch on the span of whoever later waits on it
+        (``queue_wait``/``readback``).
+
         ``accounted=False`` keeps this entry out of the ``dispatches``
         and window-full counters: the admission controller's SHED
         no-ops ride the window only for FIFO offset commits — counting
@@ -487,6 +486,7 @@ class OverlappedDispatcher:
         # record poison
         faults.fire("dispatch")
         faults.fire("device_dispatch")
+        ident = ident or {}
         prof = self._profiler
         sampling = (
             prof is not None
@@ -494,47 +494,54 @@ class OverlappedDispatcher:
             and prof.should_sample()
         )
         if sampling:
-            t_pre = time.monotonic()
             # drain so the bracket times THIS dispatch, not the tail of
             # whatever the device was already running (entries stay in
-            # the window: FIFO completion/callbacks are untouched)
-            try:
-                for h in self._window:
-                    _block_ready(h.out)
-            except Exception:
-                # a poisoned in-flight batch: its error belongs to
-                # finish_oldest (right meta, right caller) — this
-                # launch just forfeits its sample
-                sampling = False
+            # the window: FIFO completion/callbacks are untouched). The
+            # sampler's two waits are the prof_sample stage: the bubble
+            # it puts between host and device has a name of its own
+            with self._ledger.span(
+                "prof_sample", wait="drain", **ident
+            ) as drained:
+                try:
+                    for h in self._window:
+                        _block_ready(h.out)
+                except Exception:
+                    # a poisoned in-flight batch: its error belongs to
+                    # finish_oldest (right meta, right caller) — this
+                    # launch just forfeits its sample
+                    sampling = False
         if sampling:
-            t_drained = time.monotonic()
+            # dispatch_fn books its own encode/route/h2d, outside both
+            # prof_sample intervals: its host work (featurize/staging
+            # on the host-encode path) happens BEFORE the device kernel
+            # is queued, so folding it into the bracket would book host
+            # time as device time — inflating device_ns_per_record,
+            # poisoning the kernel cost ledger, and double-booking the
+            # interval dispatch_quantized already attributed
             out = dispatch_fn()
-            # bracket only the post-dispatch wait: dispatch_fn's host
-            # work (featurize/staging on the host-encode path) happens
-            # BEFORE the device kernel is queued, so folding it in
-            # would book host time as device time — inflating
-            # device_ns_per_record, poisoning the kernel cost ledger,
-            # and double-booking the interval dispatch_quantized
-            # already attributed to encode/h2d
-            t_disp = time.monotonic()
-            try:
-                _block_ready(out)
-            except Exception:
-                pass  # the finish path re-raises with attribution
-            else:
-                t1 = time.monotonic()
+            with self._ledger.span(
+                "prof_sample", wait="bracket", **ident
+            ) as bracket:
+                try:
+                    _block_ready(out)
+                except Exception:
+                    sampling = False  # the finish path re-raises with
+                    # attribution
+            if sampling:
                 # overhead = drain + bracket wait; dispatch_fn's own
                 # host time is work the caller pays regardless, so it
                 # must not eat the sampling budget
                 prof.record_sample(
-                    t1 - t_disp,
+                    bracket.seconds,
                     profile,
-                    overhead_s=(t_drained - t_pre) + (t1 - t_disp),
+                    overhead_s=drained.seconds + bracket.seconds,
                 )
         else:
             out = dispatch_fn()
         _prefetch_host(out)
-        handle = _InFlight(out, meta, time.monotonic(), accounted=accounted)
+        handle = _InFlight(
+            out, meta, time.monotonic(), accounted=accounted, ident=ident
+        )
         self._window.append(handle)
         if accounted:
             self._dispatches.inc()
@@ -576,42 +583,39 @@ class OverlappedDispatcher:
         if not self._window:
             return None
         handle = self._window[0]
-        depth = len(self._window)
-        t0 = time.monotonic()
         error: Optional[BaseException] = None
-        try:
-            # readback-time device-fault injection: raises inside the
-            # same try as the real fetch, so an injected device error
-            # takes exactly the real error path (handle.error +
-            # on_error classification); shed no-ops (accounted=False)
-            # launched no device work and are skipped
-            if handle.accounted:
-                faults.fire("device_readback")
-            _block_ready(handle.out)
-        except BaseException as e:
-            handle.error = e  # wait() on this handle re-raises, never
-            # returns the unsynchronized result as if it completed
-            error = e
-        finally:
-            # stall time counts even when the wait raised: the host WAS
-            # gated on the device for that long either way
-            dt = time.monotonic() - t0
-            self._stall.inc(dt)
-            # the in-flight window on the trace: how long the host sat
-            # on the oldest dispatch, and how deep the window was
-            spans.emit("readback", t0, dt, inflight=depth)
-            if self._ledger is not None:
-                # ONLY the blocking wait is booked, and under the
-                # caller's stage — launch's overflow loop passes
-                # queue_wait, every other caller is a readback; the
-                # complete-callback below books its own time (sink),
-                # so one wall-clock interval never lands in two stages
-                self._ledger.observe(_stage, dt)
-            # the entry leaves the window regardless — a poisoned batch
-            # must not wedge every later flush
-            self._window.popleft()
-            handle.done = True
-            self._gauge.set(len(self._window))
+        # ONLY the blocking wait is booked, and under the caller's
+        # stage — launch's overflow loop passes queue_wait, every other
+        # caller is a readback; the complete-callback below books its
+        # own time (sink), so one wall-clock interval never lands in
+        # two stages. On the trace: how long the host sat on the oldest
+        # dispatch, and how deep the window was
+        with self._ledger.span(
+            _stage, inflight=len(self._window), **handle.ident
+        ) as sp:
+            try:
+                # readback-time device-fault injection: raises inside
+                # the same try as the real fetch, so an injected device
+                # error takes exactly the real error path (handle.error
+                # + on_error classification); shed no-ops
+                # (accounted=False) launched no device work and are
+                # skipped
+                if handle.accounted:
+                    faults.fire("device_readback")
+                _block_ready(handle.out)
+            except BaseException as e:
+                handle.error = e  # wait() on this handle re-raises,
+                # never returns the unsynchronized result as if it
+                # completed
+                error = e
+        # stall time counts even when the wait raised: the host WAS
+        # gated on the device for that long either way
+        self._stall.inc(sp.seconds)
+        # the entry leaves the window regardless — a poisoned batch
+        # must not wedge every later flush
+        self._window.popleft()
+        handle.done = True
+        self._gauge.set(len(self._window))
         if error is not None:
             if (
                 self._on_error is not None
@@ -636,7 +640,7 @@ class OverlappedDispatcher:
         while not handle.done and self._window:
             self.finish_oldest()
         if not handle.done:
-            t0 = time.monotonic()
+            sp = self._ledger.begin("readback", **handle.ident)
             try:
                 if handle.accounted:
                     faults.fire("device_readback")
@@ -645,10 +649,7 @@ class OverlappedDispatcher:
                 handle.error = e
                 raise
             finally:
-                dt = time.monotonic() - t0
-                self._stall.inc(dt)
-                if self._ledger is not None:
-                    self._ledger.observe("readback", dt)
+                self._stall.inc(sp.end())
                 handle.done = True
         if handle.error is not None:
             raise handle.error

@@ -111,13 +111,13 @@ class _PrefetchedSourceBase:
             self._c_records = metrics.counter("prefetch_records")
             self._c_stall = metrics.counter("prefetch_stall_s")
             self._c_block = metrics.counter("prefetch_block_s")
-            self._ledger = attr_mod.ledger_for(metrics)
             self._monitor = pressure_mod.pressure_for(metrics)
         else:
             self._g_depth = self._g_occ = None
             self._c_batches = self._c_records = None
             self._c_stall = self._c_block = None
-            self._ledger = self._monitor = None
+            self._monitor = None
+        self._ledger = attr_mod.ledger_for(metrics) or attr_mod.UNBOOKED
 
     # marks the wrapper so maybe_wrap_* never double-wraps
     prefetch_wrapped = True
@@ -217,8 +217,11 @@ class _PrefetchedSourceBase:
                 self._monitor.note_prefetch(occ)
 
     def _take(self):
-        """→ (item | None, waited_s). Bounded wait on an empty queue;
-        sticky sidecar errors re-raise here."""
+        """→ item | None. Bounded wait on an empty queue; sticky
+        sidecar errors re-raise here. Each wait on the condition is one
+        ``prefetch_wait`` span — the hot path's residual ingest cost
+        once fetch/decode moved off-thread, ranked by fjt-top next to
+        them — and feeds ``prefetch_stall_s`` from the same interval."""
         self._ensure_started()
         t0 = None
         while True:
@@ -227,30 +230,21 @@ class _PrefetchedSourceBase:
                     item = self._q.popleft()
                     self._note_queue()
                     self._cv.notify_all()
-                    break
+                    return item
                 if self._exc is not None:
                     raise self._exc
                 if self._eos or self._stopped:
-                    return None, 0.0
+                    return None
                 now = time.monotonic()
                 if t0 is None:
                     t0 = now
                 remaining = t0 + _POLL_WAIT_S - now
                 if remaining <= 0:
-                    return None, now - t0
-                self._cv.wait(remaining)
-        waited = 0.0 if t0 is None else time.monotonic() - t0
-        return item, waited
-
-    def _account_wait(self, waited: float) -> None:
-        if waited <= 0.0:
-            return
-        if self._c_stall is not None:
-            self._c_stall.inc(waited)
-        if self._ledger is not None:
-            # the hot path's residual ingest cost once fetch/decode
-            # moved off-thread — ranked by fjt-top next to them
-            self._ledger.observe("prefetch_wait", waited)
+                    return None
+                with self._ledger.span("prefetch_wait") as sp:
+                    self._cv.wait(remaining)
+                if self._c_stall is not None:
+                    self._c_stall.inc(sp.seconds)
 
     # -- lifecycle / source protocol --------------------------------------
 
@@ -343,9 +337,7 @@ class PrefetchedBlockSource(_PrefetchedSourceBase):
         return int(item[1].shape[0])
 
     def poll(self):
-        item, waited = self._take()
-        self._account_wait(waited)
-        return item
+        return self._take()
 
 
 class PrefetchedRecordSource(_PrefetchedSourceBase):
@@ -375,14 +367,11 @@ class PrefetchedRecordSource(_PrefetchedSourceBase):
         out = list(self._pending)
         if out:
             self._pending.clear()
-        waited = 0.0
         while len(out) < max_n:
-            item, w = self._take()
-            waited += w
+            item = self._take()
             if item is None:
                 break
             out.extend(item)
-        self._account_wait(waited)
         if len(out) > max_n:
             self._pending.extend(out[max_n:])
             del out[max_n:]

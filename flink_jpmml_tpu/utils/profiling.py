@@ -3,18 +3,16 @@
 The reference exposes Flink's web-UI metrics and backpressure monitors; the
 TPU-native equivalents here are:
 
-- :func:`trace` — a context manager around ``jax.profiler`` emitting a
-  TensorBoard-loadable trace directory (XLA op timeline, HBM usage);
 - :class:`StageTimer` — lightweight wall-clock accounting per pipeline
-  stage (featurize / h2d+dispatch / readback / sink), feeding the metrics
-  registry so ``snapshot()`` shows where stream time goes;
-- :func:`annotate` — a ``TraceAnnotation`` wrapper so runtime stages show
-  up as named spans inside the device trace.
+  stage of the record path (featurize / h2d+dispatch / readback /
+  sink), feeding the metrics registry so ``snapshot()`` shows where
+  stream time goes; with ``FJT_TRACE_DIR`` set it additionally emits
+  host-side chrome://tracing spans (obs/spans.py);
+- :func:`overlap_stats` / :func:`wire_stats` — the bench's overlap and
+  encode-placement accounting.
 
-With ``FJT_TRACE_DIR`` set, :class:`StageTimer` and :func:`annotate`
-additionally emit host-side chrome://tracing spans (obs/spans.py) —
-Perfetto-loadable without TensorBoard, bounded file size, survives a
-killed worker.
+The block path's stages are :meth:`obs.attr.StageLedger.span`, which
+also puts each on the clock of a ``jax.profiler.start_trace`` session.
 """
 
 from __future__ import annotations
@@ -25,39 +23,6 @@ from typing import Dict, Iterator, Optional
 
 from flink_jpmml_tpu.obs import spans
 from flink_jpmml_tpu.utils.metrics import MetricsRegistry
-
-
-@contextlib.contextmanager
-def trace(log_dir: str) -> Iterator[None]:
-    """Capture a jax.profiler trace into ``log_dir`` (TensorBoard format).
-
-    Usage::
-
-        with profiling.trace("/tmp/fjt-trace"):
-            pipeline.run_until_exhausted()
-    """
-    import jax
-
-    jax.profiler.start_trace(log_dir)
-    try:
-        yield
-    finally:
-        jax.profiler.stop_trace()
-
-
-@contextlib.contextmanager
-def annotate(name: str) -> Iterator[None]:
-    """Named span inside the device trace (no-op overhead when not
-    tracing); also a host-side chrome://tracing span when
-    ``FJT_TRACE_DIR`` is set."""
-    import jax
-
-    t0 = time.monotonic()
-    try:
-        with jax.profiler.TraceAnnotation(name):
-            yield
-    finally:
-        spans.emit(name, t0, time.monotonic() - t0)
 
 
 def overlap_stats(

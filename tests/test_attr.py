@@ -4,9 +4,11 @@ and kernel cost ledger (obs/profiler.py), the SLO burn-rate tracker
 (obs/slo.py), the buffered span writer's bounded-loss contract
 (obs/spans.py), and the fjt-top renderer (cli.py).
 
-Everything here runs jax-free and in milliseconds: the profiler and
-SLO tracker take injectable clocks, the ledger is plain dict+histogram
-work, and fjt-top consumes struct dumps.
+The profiler and SLO tracker take injectable clocks, the ledger is
+plain dict+histogram work, and fjt-top consumes struct dumps: those
+run jax-free and in milliseconds. The span classes at the end run small
+real pipelines (ISSUE 24): ``StageLedger.span`` on the profiler's
+clock, and the per-thread no-overlap check of a starved pipeline.
 """
 
 import json
@@ -567,6 +569,337 @@ class TestDispatcherSampling:
         # close() drains the remaining two → readback
         assert Histogram.from_state(snap["histograms"][qname]).count() == 3
         assert Histogram.from_state(snap["histograms"][rname]).count() == 2
+
+
+# ---------------------------------------------------------------------------
+# One span per stage: histogram, chrome span and profiler annotation
+# ---------------------------------------------------------------------------
+
+
+def _fjt_events(xplane_dir):
+    """→ [(name, thread line, start_ns, duration_ns, stats)] of the
+    ``fjt.*`` annotations on ``/host:CPU`` of a profiler session."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    (xp,) = glob.glob(
+        os.path.join(xplane_dir, "plugins", "profile", "*", "*.xplane.pb")
+    )
+    out = []
+    for plane in ProfileData.from_file(xp).planes:
+        if plane.name != "/host:CPU":
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith(attr.ANNOTATION_PREFIX):
+                    out.append((
+                        e.name, line.name, e.start_ns, e.duration_ns,
+                        dict(e.stats),
+                    ))
+    return out
+
+
+_SPAN_BATCH = 64
+
+
+@pytest.fixture(scope="module")
+def gbm(tmp_path_factory):
+    """The small compiled GBM both span classes run their pipelines on."""
+    from assets.generate import gen_gbm
+    from flink_jpmml_tpu.compile import compile_pmml
+    from flink_jpmml_tpu.pmml import parse_pmml_file
+
+    tmp = tmp_path_factory.mktemp("span_gbm")
+    doc = parse_pmml_file(
+        gen_gbm(str(tmp), n_trees=10, depth=3, n_features=4)
+    )
+    return compile_pmml(doc, batch_size=_SPAN_BATCH)
+
+
+class TestStageSpans:
+    """``StageLedger.span`` at the block path's sites: every interval is
+    booked once, under one name, into the stage histogram and — while a
+    profiler session runs — onto ``/host:CPU`` of its xplane."""
+
+    B, BLOCKS = _SPAN_BATCH, 12
+
+    def _run(self, gbm, keyed):
+        import numpy as np
+
+        from flink_jpmml_tpu.runtime.block import (
+            BlockPipeline, FiniteBlockSource,
+        )
+        from flink_jpmml_tpu.runtime.state import StateSpec
+
+        rng = np.random.default_rng(24)
+        data = rng.normal(0.0, 1.0, size=(self.B * self.BLOCKS, 4)).astype(
+            np.float32
+        )
+        data[:, 0] = rng.integers(0, 9, size=data.shape[0])
+        delivered = []
+        m = MetricsRegistry()
+        pipe = BlockPipeline(
+            FiniteBlockSource(data, block_size=self.B), gbm,
+            lambda out, n, first_off: delivered.append((first_off, n)),
+            metrics=m, use_native=False, in_flight=2,
+            state=StateSpec(capacity=64, key_col=0) if keyed else None,
+        )
+        pipe.run_until_exhausted(timeout=60.0)
+        assert sum(n for _, n in delivered) == data.shape[0]
+        return m.struct_snapshot()["histograms"], delivered
+
+    @pytest.mark.parametrize("keyed", [False, True],
+                             ids=["stateless", "keyed"])
+    def test_spans_lie_on_the_profilers_clock(
+        self, gbm, keyed, tmp_path, monkeypatch
+    ):
+        import jax
+
+        monkeypatch.delenv("FJT_TRACE_DIR", raising=False)
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+        try:
+            hists, delivered = self._run(gbm, keyed)
+        finally:
+            jax.profiler.stop_trace()
+        events = _fjt_events(str(tmp_path))
+        by_stage = {}
+        for name, _, _, dur, _ in events:
+            st = by_stage.setdefault(name, [0, 0.0])
+            st[0] += 1
+            st[1] += dur / 1e9
+        want = {"fjt.drain", "fjt.encode", "fjt.h2d", "fjt.sink"}
+        if keyed:
+            want.add("fjt.route")
+        assert want <= set(by_stage), sorted(by_stage)
+        assert {"fjt.queue_wait", "fjt.readback"} & set(by_stage)
+        if not keyed:
+            assert "fjt.route" not in by_stage
+        # the children of h2d are on the trace and in no histogram
+        assert "fjt.h2d.launch" in by_stage
+        assert attr.stage_metric_name("h2d.launch") not in hists
+        # histogram and trace hold the same intervals: equal counts,
+        # and sums within 5% or 1 ms
+        for stage in attr.STAGES:
+            if stage == "device":
+                continue  # a sampled duration, the one observe() caller
+            h = hists.get(attr.stage_metric_name(stage))
+            n_span, s_span = by_stage.get("fjt." + stage, (0, 0.0))
+            assert n_span == (h["n"] if h else 0), stage
+            if h:
+                assert abs(s_span - h["sum"]) <= max(
+                    0.05 * h["sum"], 1e-3
+                ), (stage, s_span, h["sum"])
+        # one dispatch, one first_off, on every span it owns; the
+        # score thread did all of them
+        per_dispatch = ["fjt.encode", "fjt.h2d", "fjt.sink"] + (
+            ["fjt.route"] if keyed else []
+        )
+        firsts = {
+            name: sorted(
+                st["first_off"] for nm, _, _, _, st in events if nm == name
+            )
+            for name in per_dispatch
+        }
+        assert firsts["fjt.sink"] == sorted(f for f, _ in delivered)
+        for name in per_dispatch:
+            assert firsts[name] == firsts["fjt.sink"], name
+        waited = sorted(
+            st["first_off"] for nm, _, _, _, st in events
+            if nm in ("fjt.queue_wait", "fjt.readback")
+        )
+        assert waited == firsts["fjt.sink"]
+        assert len({ln for nm, ln, _, _, _ in events
+                    if nm in per_dispatch + ["fjt.drain"]}) == 1
+        drained = sum(
+            st["n"] for nm, _, _, _, st in events if nm == "fjt.drain"
+        )
+        assert drained == self.B * self.BLOCKS
+
+    def test_histograms_fill_without_a_session(
+        self, gbm, tmp_path, monkeypatch
+    ):
+        monkeypatch.delenv("FJT_TRACE_DIR", raising=False)
+        hists, delivered = self._run(gbm, keyed=True)
+        for stage in ("drain", "encode", "route", "h2d", "sink", "commit"):
+            h = hists[attr.stage_metric_name(stage)]
+            assert h["n"] >= len(delivered), stage
+        waits = sum(
+            hists.get(attr.stage_metric_name(s), {"n": 0})["n"]
+            for s in ("queue_wait", "readback")
+        )
+        assert waits == len(delivered)
+        assert not list(tmp_path.iterdir())  # nothing armed, no file
+
+    def test_chrome_span_carries_the_stage_name(
+        self, gbm, tmp_path, monkeypatch
+    ):
+        """With ``FJT_TRACE_DIR`` set the chrome span of a stage has the
+        name of its histogram (``encode``/``h2d``, not the old
+        ``featurize``/``h2d_dispatch``) and the dispatch's identity."""
+        monkeypatch.setenv("FJT_TRACE_DIR", str(tmp_path))
+        try:
+            hists, delivered = self._run(gbm, keyed=True)
+        finally:
+            spans.flush()
+        (f,) = [p for p in os.listdir(tmp_path) if p.endswith(".json")]
+        evs = [
+            e for e in _span_events(os.path.join(tmp_path, f))
+            if e.get("ph") == "X" and not e["name"].endswith("_exemplar")
+        ]
+        names = {e["name"] for e in evs}
+        assert {"drain", "encode", "route", "h2d", "sink", "commit"} <= names
+        assert not names & {"featurize", "h2d_dispatch"}
+        for stage in ("encode", "route", "h2d", "sink"):
+            got = sorted(
+                e["args"]["first_off"] for e in evs if e["name"] == stage
+            )
+            assert got == sorted(f for f, _ in delivered), stage
+            assert len(got) == hists[attr.stage_metric_name(stage)]["n"]
+        assert all(
+            e["args"]["bytes"] > 0 for e in evs if e["name"] == "h2d"
+        )
+
+    def test_unbooked_ledger_spans_book_nothing(self):
+        """A caller without a registry still gets the span (chrome,
+        profiler); no histogram exists to fill."""
+        with attr.UNBOOKED.span("encode", n=3) as sp:
+            pass
+        assert sp.seconds >= 0.0 and not attr.UNBOOKED._hists
+        assert attr.ledger_for(None) is None
+
+    def test_begin_end_books_like_with(self):
+        m = MetricsRegistry()
+        led = attr.ledger_for(m)
+        sp = led.begin("drain")
+        sp.note(n=7)
+        dt = sp.end()
+        with led.span("drain", n=1):
+            pass
+        h = m.struct_snapshot()["histograms"][attr.stage_metric_name("drain")]
+        assert h["n"] == 2 and h["sum"] >= dt
+        assert sp.args == {"n": 7}
+
+    def test_prof_sample_books_the_samplers_two_waits(self, tmp_path):
+        """A sampled launch books its window drain and its bracket wait
+        as ``prof_sample`` — two intervals — and ``dispatch_fn``'s own
+        time in neither."""
+        import time as _time
+
+        from flink_jpmml_tpu.runtime.pipeline import OverlappedDispatcher
+
+        m = MetricsRegistry()
+        prof = profiler.DeviceProfiler(
+            m, interval_s=1e-9,
+            cost_ledger=profiler.KernelCostLedger(
+                path=str(tmp_path / "kc.json")
+            ),
+        )
+        disp = OverlappedDispatcher(depth=2, metrics=m, profiler=prof)
+
+        class _SlowReady:
+            def block_until_ready(self):
+                _time.sleep(0.02)
+
+        def dispatch():
+            _time.sleep(0.08)
+            return _SlowReady()
+
+        disp.launch(dispatch, profile=_profile(),
+                    ident={"first_off": 0, "n": 64})
+        h = m.struct_snapshot()["histograms"][
+            attr.stage_metric_name("prof_sample")
+        ]
+        assert h["n"] == 2
+        assert 0.02 <= h["sum"] < 0.06, h["sum"]
+        disp.close()
+
+
+class TestStarvedPipelineBooksNoMoreThanWallTime:
+    """PERF.md's doubt: on a starved pipeline fetch/decode/
+    prefetch_wait read more than wall time. Per thread, the spans of a
+    pipeline fed by a slow producer must not overlap, and must sum to
+    at most the wall time."""
+
+    def test_spans_of_a_thread_do_not_overlap(
+        self, gbm, tmp_path, monkeypatch
+    ):
+        import threading
+        import time as _time
+
+        import numpy as np
+
+        from flink_jpmml_tpu.runtime.block import BlockPipeline
+        from flink_jpmml_tpu.runtime.kafka import (
+            KafkaBlockSource, MiniKafkaBroker,
+        )
+
+        trace_dir = tmp_path / "spans"
+        monkeypatch.setenv("FJT_TRACE_DIR", str(trace_dir))
+        rng = np.random.default_rng(5)
+        broker = MiniKafkaBroker(topic="starved")
+        m = MetricsRegistry()
+        delivered = []
+        src = KafkaBlockSource(
+            broker.host, broker.port, "starved", n_cols=4, max_wait_ms=20,
+            metrics=m,
+        )
+        pipe = BlockPipeline(
+            src, gbm,
+            lambda out, n, first_off: delivered.append(n),
+            metrics=m, use_native=False, in_flight=2, prefetch=True,
+        )
+        stop = threading.Event()
+
+        def produce():  # 16 records every 25 ms: far under the pipeline
+            while not stop.is_set():
+                broker.append_rows(
+                    rng.normal(size=(16, 4)).astype(np.float32)
+                )
+                _time.sleep(0.025)
+
+        feeder = threading.Thread(target=produce, daemon=True)
+        t0 = _time.monotonic()
+        try:
+            pipe.start()
+            feeder.start()
+            _time.sleep(1.0)
+        finally:
+            stop.set()
+            feeder.join(timeout=5.0)
+            pipe.stop()
+            pipe.join(timeout=30.0)
+            src.close()
+            broker.close()
+            spans.flush()
+        wall = _time.monotonic() - t0
+        assert not feeder.is_alive() and sum(delivered) > 0
+        (f,) = [p for p in os.listdir(trace_dir) if p.endswith(".json")]
+        by_tid = {}
+        for e in _span_events(os.path.join(trace_dir, f)):
+            if e.get("ph") == "X" and e["name"] in attr.STAGES:
+                by_tid.setdefault(e["tid"], []).append(
+                    (e["ts"], e["ts"] + e["dur"], e["name"])
+                )
+        stages = {nm for evs in by_tid.values() for _, _, nm in evs}
+        assert {"fetch", "decode", "prefetch_wait", "drain"} <= stages
+        assert len(by_tid) >= 3  # sidecar, ring feed, score thread
+        for tid, evs in by_tid.items():
+            evs.sort()
+            for (_, e0, n0), (s1, _, n1) in zip(evs, evs[1:]):
+                # the span file rounds to 0.1 us
+                assert s1 >= e0 - 0.2, (tid, n0, n1, e0, s1)
+            total = sum(e - s for s, e, _ in evs) / 1e6
+            assert total <= wall, (tid, total, wall)
+        # and the histograms say the same: no stage above the wall time
+        for stage, h in (
+            (s, m.struct_snapshot()["histograms"].get(
+                attr.stage_metric_name(s)))
+            for s in ("fetch", "decode", "prefetch_wait")
+        ):
+            assert h is not None and h["sum"] <= wall, (stage, h)
 
 
 # ---------------------------------------------------------------------------

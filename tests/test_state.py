@@ -276,6 +276,53 @@ class TestFusedFold:
         assert tables[0].tobytes() == tables[1].tobytes()
 
 
+class TestNamedScopes:
+    def test_scopes_are_metadata_only(self, gbm, monkeypatch):
+        """``fjt.forest`` / ``fjt.fold.gather`` / ``fjt.fold.scatter``
+        (compile/statekernel.py) name the program's parts on a device
+        trace and change nothing else: the lowered program without
+        its location metadata is byte-identical with and without
+        them."""
+        import contextlib
+
+        import jax
+
+        from flink_jpmml_tpu.compile import statekernel
+
+        q = gbm.quantized_scorer()
+        t, _ = _table(capacity=64)
+        (X, offs) = _batches(1, keys=8)[0]
+        payload, K = q.pad_wire(q.wire.encode(X))
+        slots, reset, rel, w = t.assign_slots(
+            t.hash_keys(t.extract_keys(X)), offs
+        )
+
+        def lowered():
+            for k in [k for k in q._multi_fns if k[0] == "state"]:
+                del q._multi_fns[k]
+            fn = statekernel.entry_for(
+                q, "wire", K, False, t.spec.decay, t.scratch
+            )
+            low = fn.lower(
+                q.params, payload, t.values, slots, rel, w, reset
+            )
+            # the program as lowered, without and with its locations
+            # (the op names a device trace shows)
+            return low.as_text(), low.as_text(debug_info=True)
+
+        scoped = lowered()
+        for scope in ("fjt.forest", "fjt.fold.gather", "fjt.fold.scatter"):
+            assert f"/{scope}/" in scoped[1], scope
+        monkeypatch.setattr(
+            jax, "named_scope", lambda name: contextlib.nullcontext()
+        )
+        bare = lowered()
+        monkeypatch.undo()
+        assert "fjt." not in bare[1]
+        assert "fjt." not in scoped[0]
+        assert scoped[0] == bare[0]
+
+
 class TestCheckpointRoundtrip:
     def _folded(self, gbm, capacity=64):
         import jax
