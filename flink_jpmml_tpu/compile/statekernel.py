@@ -9,14 +9,30 @@ program per batch:
     S'             = scatter-add/min/max(S, slots, f(out, w, rel))
 
 The state stage is pure XLA gather/scatter over the batch's slot
-vector — O(batch) work appended to the scoring program, never
-O(capacity) — and composes with EVERY backend the scorer already has:
-XLA, Pallas (the state ops wrap the scan-chunked kernel, outside the
-Pallas grid), fused-encode, and cross-model packs. No new Pallas
-kernel is warranted: per the accelerator guide, TPU scatter of a
-``[B, 8]`` update against a ``[rows, 8]`` table is bandwidth-trivial
-next to the tree-ensemble gathers it rides with, and XLA already fuses
-the gather into the kernel epilogue.
+vector, appended to the scoring program, and composes with EVERY
+backend the scorer already has: XLA, Pallas (the state ops wrap the
+scan-chunked kernel, outside the Pallas grid), fused-encode, and
+cross-model packs. No new Pallas kernel is warranted — but the work is
+O(batch) only where a write covers WHOLE ROWS. On the chip the table
+is column-major, ``f32[rows, 8]{0,1:T(8,128)}``: one slot's row is one
+lane of an (8, 128) tile. What the TPU compiler makes of a table write
+(read off a v5e compile; tests/test_v5e_compile.py, PERF.md §5):
+
+- a scatter of whole rows (``S.at[slots].set/.max/.min(rows8)``) stays
+  a native scatter, in place on the donated buffer: ~5.5 ms for 65,536
+  records at 200M slots, duplicates and all;
+- a scatter into ONE column (``S.at[slots, c].max``, what last_t, min
+  and max were until PR 25) flattens the table a column at a time into
+  ``f32[rows * 8]``, scatters there and copies back: O(table) a
+  dispatch, 9.6 GB of temporaries, ~430 of a dispatch's 665 ms. Those
+  three are now two whole-row scatters whose other columns carry the
+  operation's identity (−inf for max, +inf for min);
+- a scatter into a SLICE of columns (the add of columns 0..4 below) is
+  expanded to a ``while`` of one iteration a record, ~3.5 µs each:
+  ~230 ms for 65,536 records. The same mechanism cures it
+  (``S.at[slots].add`` of rows padded with 0: ~46 ms a dispatch all
+  told), and it is held back only because the benchmark's load
+  generator cannot yet out-run the pipeline that results (PERF.md §7).
 
 Batch-consistent read semantics: every record's DERIVED features
 reflect the table as of the BATCH start (one gather before the
@@ -64,6 +80,16 @@ _INIT_ROW = (0.0, 0.0, 0.0, 0.0, 0.0, 0.0, float("inf"), float("-inf"))
 _DCOUNT_FLOOR = 1e-30
 
 
+def _rows(like, identity, cols):
+    """``[B, STATE_WIDTH]`` update rows for one whole-row scatter:
+    ``cols`` maps a column to its ``[B]`` values, every other column
+    carries the scatter operation's ``identity``."""
+    fill = jnp.full_like(like, identity)
+    return jnp.stack(
+        [cols.get(c, fill) for c in range(STATE_WIDTH)], axis=1
+    )
+
+
 def _state_step(S, score, slots, rel, w, reset, scratch, decay):
     """One batch's state transition (traced inside the dispatch jit).
 
@@ -105,16 +131,21 @@ def _state_step(S, score, slots, rel, w, reset, scratch, decay):
         )
         derived = jnp.where(seen[:, None], derived, f32(0.0))
     with jax.named_scope("fjt.fold.scatter"):
-        # commutative scatter updates: the five accumulator columns are
-        # contiguous, so they ride one column-sliced scatter-add
+        # commutative scatter updates. The extrema are whole-row
+        # scatters, native and in place on the TPU (module docstring):
+        # a column an operation does not touch carries that operation's
+        # identity — max(x, -inf) and min(x, +inf) are exact, also on a
+        # fresh row's ±inf. The five accumulator columns still ride one
+        # column-sliced scatter-add, a loop over the records there
         adds = jnp.stack(
             [jnp.ones_like(score), score, score * score, w, w * score],
             axis=1,
         )
         S = S.at[slots, COL_COUNT:COL_DSUM + 1].add(adds)
-        S = S.at[slots, COL_LAST_T].max(rel)
-        S = S.at[slots, COL_MIN].min(score)
-        S = S.at[slots, COL_MAX].max(score)
+        S = S.at[slots].max(_rows(
+            score, -jnp.inf, {COL_LAST_T: rel, COL_MAX: score}
+        ))
+        S = S.at[slots].min(_rows(score, jnp.inf, {COL_MIN: score}))
         # bypass/pad contributions all landed on the scratch row — zero
         # it so snapshots stay clean and the next batch's bypass reads
         # zeros

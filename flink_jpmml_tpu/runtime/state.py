@@ -26,10 +26,15 @@ Division of labor — host routes, device accumulates:
   per slot (counts, sums, decayed counters in product form, last-seen
   stride, min/max) — live in a single ``[rows, STATE_WIDTH]`` device
   buffer that only the fused kernel reads or writes, via gather +
-  scatter-add/min/max over the batch's slot vector (O(batch), never
-  O(capacity)). The buffer is DONATED to each dispatch, so the update
-  is in-place: steady-state state memory is one buffer, not one per
-  in-flight batch.
+  scatter-add/min/max over the batch's slot vector. That is O(batch)
+  only as far as the compiler keeps each scatter native: on the TPU
+  the buffer is column-major, tiled ``T(8,128)`` (a slot's row is one
+  lane of a tile), whole-row scatters run in place, and a write of
+  part of a row used to cost a flat copy of the table, O(capacity) a
+  dispatch, or a loop over the records (compile/statekernel.py says
+  which write is which today). The buffer is DONATED to each dispatch,
+  so the update is in-place: steady-state state memory is one buffer,
+  not one per in-flight batch.
 
 Decayed counters ride in **product form**: a record at stride
 ``t = offset // stride`` contributes ``λ^(epoch - t)`` (≥ 1) to the

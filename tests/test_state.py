@@ -20,9 +20,11 @@ from flink_jpmml_tpu.runtime import state as state_mod
 from flink_jpmml_tpu.runtime.state import (
     COL_COUNT,
     COL_DCOUNT,
+    COL_DSUM,
     COL_LAST_T,
     COL_MAX,
     COL_MIN,
+    COL_SQSUM,
     COL_SUM,
     KeyedStateTable,
     StateSpec,
@@ -274,6 +276,197 @@ class TestFusedFold:
             jax.block_until_ready(t.values)
             tables.append(np.asarray(t.values).copy())
         assert tables[0].tobytes() == tables[1].tobytes()
+
+
+_ROWS, _SCRATCH, _DECAY = 1024, 1000, 0.999
+_INIT = np.array([0, 0, 0, 0, 0, 0, np.inf, -np.inf], np.float32)
+_EXACT_COLS = [COL_COUNT, COL_LAST_T, COL_MIN, COL_MAX]
+_SUM_COLS = [COL_SUM, COL_SQSUM, COL_DCOUNT, COL_DSUM]
+
+
+def _prior_table(rng):
+    """A ``[_ROWS, 8]`` table as earlier dispatches leave it: rows
+    0..199 hold folded state, the rest are fresh (±inf extrema), the
+    scratch row zero."""
+    S = np.tile(_INIT, (_ROWS, 1))
+    n = rng.integers(1, 40, size=200).astype(np.float32)
+    mean = rng.normal(0.0, 2.0, size=200).astype(np.float32)
+    S[:200, COL_COUNT] = n
+    S[:200, COL_SUM] = n * mean
+    S[:200, COL_SQSUM] = n * (mean * mean + 1.0)
+    S[:200, COL_DCOUNT] = 0.9 * n
+    S[:200, COL_DSUM] = 0.9 * n * mean
+    S[:200, COL_LAST_T] = rng.integers(0, 5, size=200)
+    S[:200, COL_MIN] = mean - 2.0
+    S[:200, COL_MAX] = mean + 2.0
+    S[_SCRATCH] = 0.0
+    return S
+
+
+def _heavy_duplicates(rng, B):
+    # four fifths of the dispatch on one slot, the rest on a few others
+    slots = np.where(
+        rng.random(B) < 0.8, 3, rng.integers(0, 12, size=B)
+    )
+    return slots, np.zeros(B, bool), np.zeros(B, bool)
+
+
+def _fresh_slots(rng, B):
+    # every record lands on a freshly claimed slot: half of them rows
+    # that held another key's state, half rows that held ±inf already
+    slots = np.concatenate([
+        rng.integers(100, 130, size=B // 2),
+        rng.integers(300, 330, size=B - B // 2),
+    ])
+    return slots, np.ones(B, bool), np.zeros(B, bool)
+
+
+def _bypassed_rows(rng, B):
+    # shed replays and pad rows ride the scratch slot with weight 0,
+    # beside live records (some on fresh slots)
+    slots = rng.integers(0, 400, size=B)
+    bypass = rng.random(B) < 0.4
+    return slots, (slots >= 200) & ~bypass, bypass
+
+
+def _scratch_only(rng, B):
+    return np.zeros(B, np.int64), np.zeros(B, bool), np.ones(B, bool)
+
+
+def _numpy_fold(S, score, slots, rel, w, reset):
+    """The same transition in float64 numpy, one ufunc.at a column."""
+    S = S.astype(np.float64)
+    S[np.where(reset, slots, _SCRATCH)] = _INIT
+    pre = S[slots]
+    n = np.maximum(pre[:, COL_COUNT], 1.0)
+    mean = pre[:, COL_SUM] / n
+    derived = np.stack([
+        pre[:, COL_COUNT], mean,
+        np.maximum(pre[:, COL_SQSUM] / n - mean * mean, 0.0),
+        pre[:, COL_DCOUNT] * np.power(_DECAY, rel),
+        pre[:, COL_DSUM] / np.maximum(pre[:, COL_DCOUNT], 1e-30),
+        rel - pre[:, COL_LAST_T], pre[:, COL_MIN], pre[:, COL_MAX],
+    ], axis=1)
+    derived[pre[:, COL_COUNT] <= 0] = 0.0
+    terms = np.zeros((len(slots), 8))
+    terms[:, COL_COUNT] = 1.0
+    terms[:, COL_SUM] = score
+    terms[:, COL_SQSUM] = score * score
+    terms[:, COL_DCOUNT] = w
+    terms[:, COL_DSUM] = w * score
+    np.add.at(S, slots, terms)
+    # what float32 summation in any order may lose, per row and column
+    mass = np.abs(S)
+    np.add.at(mass, slots, np.abs(terms))
+    np.maximum.at(S[:, COL_LAST_T], slots, rel)
+    np.minimum.at(S[:, COL_MIN], slots, score)
+    np.maximum.at(S[:, COL_MAX], slots, score)
+    S[_SCRATCH] = 0.0
+    return derived, S, (S[:, COL_COUNT] + 2.0)[:, None] * mass
+
+
+def _jit_step():
+    import jax
+
+    from flink_jpmml_tpu.compile import statekernel
+
+    return jax.jit(
+        lambda S, *a: statekernel._state_step(S, *a, _SCRATCH, _DECAY)
+    )
+
+
+class TestStateStep:
+    @pytest.mark.parametrize(
+        "case",
+        [_heavy_duplicates, _fresh_slots, _bypassed_rows, _scratch_only],
+        ids=lambda f: f.__name__.strip("_"),
+    )
+    def test_matches_float64_numpy_fold(self, case):
+        rng = np.random.default_rng(25)
+        B = 512
+        slots, reset, bypass = case(rng, B)
+        slots = np.where(bypass, _SCRATCH, slots).astype(np.int32)
+        score = rng.normal(0.0, 3.0, size=B).astype(np.float32)
+        rel = rng.integers(3, 9, size=B).astype(np.float32)
+        w = np.where(
+            bypass, 0.0, np.power(_DECAY, -rel.astype(np.float64))
+        ).astype(np.float32)
+        S0 = _prior_table(rng)
+        derived, S1 = (np.asarray(a) for a in _jit_step()(
+            S0, score, slots, rel, w, reset
+        ))
+        want_d, want, lost = _numpy_fold(
+            S0, score.astype(np.float64), slots,
+            rel.astype(np.float64), w.astype(np.float64), reset,
+        )
+        eps = np.finfo(np.float32).eps
+        # counts, last_t and the extrema are exact (±inf of a row no
+        # record reached included); the sums are float32 sums
+        assert np.array_equal(S1[:, _EXACT_COLS], want[:, _EXACT_COLS])
+        assert (
+            np.abs(S1[:, _SUM_COLS] - want[:, _SUM_COLS])
+            <= eps * lost[:, _SUM_COLS]
+        ).all()
+        assert not S1[_SCRATCH].any()
+        untouched = np.setdiff1d(np.arange(_ROWS), slots)
+        assert S1[untouched].tobytes() == S0[untouched].tobytes()
+        # derived features read the table as of the batch start
+        assert np.array_equal(derived[:, [0, 5, 6, 7]],
+                              want_d[:, [0, 5, 6, 7]])
+        np.testing.assert_allclose(
+            derived[:, [1, 3, 4]], want_d[:, [1, 3, 4]], rtol=1e-5
+        )
+        np.testing.assert_allclose(
+            derived[:, 2], want_d[:, 2], rtol=1e-4, atol=1e-4
+        )
+        assert not derived[bypass].any()
+
+    def test_every_table_scatter_writes_whole_rows(self):
+        """Tripwire: on the TPU the table is column-major, tiled
+        (8, 128), and a scatter into part of a row is lowered to a flat
+        copy of the whole table (one column) or a loop over the records
+        (a slice of columns). Every scatter of the fold whose operand
+        is the table has to carry an update window of all 8 columns;
+        the one exception on record is the add of the five accumulator
+        columns (PERF.md §7: it goes whole-row, and this list empty,
+        once the benchmark's producer can feed what results)."""
+        import re
+
+        import jax
+        import jax.numpy as jnp
+
+        B, f32 = 64, jnp.float32
+        sds = jax.ShapeDtypeStruct
+        module = _jit_step().lower(
+            sds((_ROWS, 8), f32), sds((B,), f32), sds((B,), jnp.int32),
+            sds((B,), f32), sds((B,), f32), sds((B,), jnp.bool_),
+        ).compiler_ir("stablehlo")
+        windows = []
+
+        def walk(op):
+            for region in op.regions:
+                for block in region.blocks:
+                    for o in block.operations:
+                        walk(o.operation)
+                        if o.operation.name != "stablehlo.scatter":
+                            continue
+                        table, _, upd = (x.type for x in o.operands)
+                        if list(table.shape) != [_ROWS, 8]:
+                            continue
+                        # an empty window is left out of the text
+                        m = re.search(
+                            r"update_window_dims = \[([\d, ]*)\]",
+                            str(o.attributes["scatter_dimension_numbers"]),
+                        )
+                        windows.append(int(np.prod([
+                            upd.shape[int(d)]
+                            for d in re.findall(r"\d+", m.group(1) if m else "")
+                        ])))
+
+        walk(module.operation)
+        # reset, add, max, min, and the scratch row's zeroing
+        assert len(windows) >= 4, windows
+        assert [wd for wd in windows if wd != 8] == [5], windows
 
 
 class TestNamedScopes:
