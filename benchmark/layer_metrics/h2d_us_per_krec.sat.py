@@ -1,6 +1,7 @@
 """Pipeline: staging + dispatch-issue self-time (the ledger's ``h2d``
-stage, which on a state-armed dispatch also holds the host's slot
-routing, ``assign_slots``) per thousand records."""
+stage: ``device_put`` of the encoded payload and the launch; the
+host's slot routing has been the ``route`` stage since PR 24) per
+thousand records."""
 from lib.readers import us_per_krec
 
 

@@ -1,14 +1,12 @@
-"""Entity-key generators, copied from the program's own drill.
+"""How a key rank rides the wire (the ranks themselves come from the
+traffic file's key mix, ``lib/keymix/<kind>.py``).
 
-- ``zipf_ranks``: zipf a=1.1 folded into the key domain,
-  ``(zipf - 1) % domain`` — ``bench.run_stateful_bench``'s mix
-  (flink_jpmml_tpu/bench.py:2689-2691).
-- ``rank_to_f32``: the block path reads the key from a float32 feature
-  column (``KeyedStateTable.extract_keys``, runtime/state.py:271-274),
-  and float32 holds only 2**24 consecutive integers. Rank ``r`` becomes
-  the float32 whose bit pattern is ``0x4B000000 + r``: every float32 at
-  or above 2**23 is a whole number, so each rank is a distinct integer
-  id that ``astype(int64)`` recovers exactly (``f32_to_id``).
+``rank_to_f32``: the block path reads the key from a float32 feature
+column (``KeyedStateTable.extract_keys``, runtime/state.py:271-274),
+and float32 holds only 2**24 consecutive integers. Rank ``r`` becomes
+the float32 whose bit pattern is ``0x4B000000 + r``: every float32 at
+or above 2**23 is a whole number, so each rank is a distinct integer
+id that ``astype(int64)`` recovers exactly (``f32_to_id``).
 """
 
 from __future__ import annotations
@@ -18,11 +16,6 @@ import numpy as np
 _F32_INT_BASE = 0x4B000000  # bit pattern of 2**23
 # ranks whose bit pattern stays below +inf (0x7F800000)
 MAX_F32_RANKS = 0x7F800000 - _F32_INT_BASE
-
-
-def zipf_ranks(rng: np.random.Generator, n: int, domain: int,
-               a: float) -> np.ndarray:
-    return ((rng.zipf(a, size=n) - 1) % domain).astype(np.int64)
 
 
 def rank_to_f32(ranks: np.ndarray) -> np.ndarray:

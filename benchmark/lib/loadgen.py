@@ -18,6 +18,19 @@ offsets are fresh and increasing). ``producer_max_records_per_s``, null
 in every cell, holds it back: ``rehearse.py --starve`` proves with it
 that a producer slower than the pipeline fails the run.
 
+The producer has to out-run the program's own ingest (one prefetch
+sidecar fetches and decodes 1.86M records/s on the chip's host, PERF.md
+§5), or the window measures the producer. The stream's rows are made at
+6M records/s; the native record-batch encode (its CRC32C over every
+byte) takes 0.5 us a record and releases the interpreter lock. So one
+producer thread decides what to append, as before, and ``ENCODERS``
+threads draw and encode it in pieces of ``PIECE`` records, which the
+producer publishes in offset order as they come ready, handing out the
+next chunk while the last is still being encoded; the keys of the
+block after are drawn meanwhile. The log holds the same record at the
+same offset in the same segments of 512 whatever the thread count or
+the piece size (``benchmark/tests`` decodes it back).
+
 The broker is the program's ``MiniKafkaBroker``. Its public
 ``append_rows`` keeps a Python ``bytes`` per record (0.2-0.5M records/s
 on one core, below what the pipeline drains), so ``_BulkBroker`` stores
@@ -27,6 +40,8 @@ encoded segments only; it relies on the broker's ``_mu``, ``_segs`` and
 
 from __future__ import annotations
 
+import collections
+import concurrent.futures
 import json
 import os
 import sys
@@ -42,6 +57,8 @@ sys.path.insert(1, os.path.dirname(_BENCH))  # the program's broker
 from lib.stream import Stream  # noqa: E402
 
 _SEG = 512  # records per stored record batch (MiniKafkaBroker._SEG_RECORDS)
+ENCODERS = 4  # of the host's 13 cores; the pipeline's threads keep theirs
+PIECE = 16 * _SEG  # records one encoder draws and encodes at a time
 
 
 def _make_broker(topic: str):
@@ -62,18 +79,24 @@ def _make_broker(topic: str):
                     )
             self.produced = 0
 
-        def _publish(self, segs, n: int) -> None:
+        def publish(self, segs) -> None:
+            """Encoded segments that start at the log's head."""
             with self._mu:
-                if self._next[0] != self.produced:
+                if self._next[0] != self.produced or (
+                        segs[0][0] != self.produced):
                     raise RuntimeError("log head moved under the producer")
                 self._segs[0].extend(segs)
-                self._next[0] = self.produced = self.produced + n
+                self._next[0] = self.produced = segs[-1][1]
                 self._mu.notify_all()
 
-        def append_rows_bulk(self, rows: np.ndarray) -> None:
+        @staticmethod
+        def encode(rows: np.ndarray, base: int):
+            """float32 rows of offsets ``base``... → segments of ``_SEG``
+            records, ``(first offset, one past last, batch bytes)``. Any
+            thread: the native call holds no lock of the interpreter."""
             raw = np.ascontiguousarray(rows, np.float32).view(
                 np.uint8).reshape(rows.shape[0], -1)
-            base, segs = self.produced, []
+            segs = []
             for i in range(0, raw.shape[0], _SEG):
                 chunk = raw[i:i + _SEG]
                 blob = native.kafka_encode_fixed(chunk, base + i)
@@ -83,7 +106,7 @@ def _make_broker(topic: str):
                         f"{native.build_error()}"
                     )
                 segs.append((base + i, base + i + chunk.shape[0], blob))
-            self._publish(segs, raw.shape[0])
+            return segs
 
     return _BulkBroker(topic=topic)
 
@@ -95,14 +118,38 @@ class Generator:
             init["key_mix"], init["pool_rows"],
         )
         self.broker = _make_broker(init["topic"])
+        self._encoders = concurrent.futures.ThreadPoolExecutor(
+            max_workers=ENCODERS, thread_name_prefix="encode")
         self._stop = threading.Event()
         self._thread = None
         self._delivered = 0
         self._stats = {}
         self._error = None
 
+    def _piece(self, lo: int, hi: int):
+        return self.broker.encode(self.stream.rows(lo, hi), lo)
+
+    def _submit(self, lo: int, hi: int) -> list:
+        """Offsets [lo, hi) handed to the encoders → their pieces, in
+        offset order. The keys of the block after ``hi`` are drawn
+        first, beside the pieces, so that no piece of the next stretch
+        waits for them."""
+        # (its result is the stream's cache; what it raises, the piece
+        # that needs the block raises again)
+        self._encoders.submit(self.stream.ranks, hi, hi + 1)
+        return [
+            self._encoders.submit(self._piece, a, min(a + PIECE, hi))
+            for a in range(lo, hi, PIECE)
+        ]
+
     def append(self, lo: int, hi: int) -> None:
-        self.broker.append_rows_bulk(self.stream.rows(lo, hi))
+        """Offsets [lo, hi) at the log's head, published in order."""
+        for piece in self._submit(lo, hi):
+            self.broker.publish(piece.result())
+
+    def close(self) -> None:
+        self._encoders.shutdown(wait=True, cancel_futures=True)
+        self.broker.close()
 
     def note_delivered(self, n: int) -> None:
         self._delivered = int(n)
@@ -127,24 +174,32 @@ class Generator:
         chunk = int(traffic["chunk_records"])
         want = int(traffic["backlog_records"])
         cap = traffic.get("producer_max_records_per_s")
-        first = self.broker.produced
+        first = submitted = self.broker.produced
+        pieces = collections.deque()  # handed to the encoders, unpublished
         least, filled = None, False
         while not self._stop.is_set():
+            while pieces and pieces[0].done():
+                self.broker.publish(pieces.popleft().result())
             backlog = self.broker.produced - self._delivered
             if filled:
                 # every turn counts once the backlog has been full:
                 # before that the producer has not yet had its chance
                 least = backlog if least is None else min(least, backlog)
             held = cap is not None and (
-                self.broker.produced - first
-                >= cap * (time.monotonic() - t0)
+                submitted - first >= cap * (time.monotonic() - t0)
             )
-            if backlog < want and not held:
-                lo = self.broker.produced
-                self.append(lo, lo + chunk)
+            if (submitted - self._delivered < want and not held
+                    and len(pieces) < 2 * ENCODERS):
+                pieces.extend(self._submit(submitted, submitted + chunk))
+                submitted += chunk
                 continue
             filled = filled or backlog >= want
-            time.sleep(0.002)
+            if pieces:
+                concurrent.futures.wait([pieces[0]], timeout=0.002)
+            else:
+                time.sleep(0.002)
+        for piece in pieces:
+            piece.cancel()
         self._stats = {"least_backlog_records": least}
 
     def stop(self) -> dict:
@@ -188,7 +243,7 @@ def main() -> None:
                 break
     finally:
         if gen is not None:
-            gen.broker.close()
+            gen.close()
 
 
 if __name__ == "__main__":

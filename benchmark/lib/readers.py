@@ -14,8 +14,9 @@ line. ``ctx`` is a plain dict:
 - ``snap0`` / ``snap1``: the program's ``MetricsRegistry.struct_snapshot()``
   at the window's start and end (counters, gauges, stage histograms);
 - ``gen``: the load generator's own account (least backlog);
-- ``trace``: ``lib.xtrace.reduce_trace`` of the traced stretch, or
-  ``None`` in an untraced run;
+- ``trace``: ``lib.xtrace.reduce_trace`` of the traced stretch (host
+  spans: the program's, ``SPAN_PREFIX``), or ``None`` in an untraced
+  run;
 - ``setup_s``; ``cfg`` / ``traffic``: the cell's two files;
 - ``peaks``: the chip's row of ``lib.peaks``.
 """
@@ -25,6 +26,7 @@ from __future__ import annotations
 from typing import Optional, Tuple
 
 PROGRAM_PREFIX = "jit_state_fn"  # the state-armed scoring program's module
+SPAN_PREFIX = "fjt."  # the program's host spans and named scopes
 
 
 def counter_delta(ctx: dict, name: str) -> Optional[float]:
@@ -92,6 +94,21 @@ def program_mean_s(ctx: dict) -> Optional[float]:
 def program_mean_ms(ctx: dict) -> Optional[float]:
     mean = program_mean_s(ctx)
     return None if mean is None else 1e3 * mean
+
+
+def scope_ms_per_dispatch(ctx: dict, *scopes: str) -> Optional[float]:
+    """Device self time under the named scopes (``jax.named_scope``)
+    per execution of the scoring program, over the executions that lie
+    wholly inside the traced stretch. A program that names none of them
+    reports nothing."""
+    tr = ctx.get("trace") or {}
+    n, secs, seen = 0, 0.0, False
+    for name, rec in tr.get("scopes", {}).items():
+        if name.startswith(PROGRAM_PREFIX):
+            n += rec["n"]
+            seen = seen or any(s in rec["seconds"] for s in scopes)
+            secs += sum(rec["seconds"].get(s, 0.0) for s in scopes)
+    return 1e3 * secs / n if n and seen else None
 
 
 def records_per_dispatch(ctx: dict) -> Optional[float]:

@@ -3,19 +3,24 @@ generator (a child process) and the correctness check (the parent)
 both derive any stretch of the stream from the seed alone, so nothing
 but parameters crosses the process boundary.
 
-The stream is cut into blocks of ``BLOCK`` records; block ``b`` draws
-its keys from ``default_rng([seed, 1, b])``, so a block never depends on
+The stream is cut into blocks of ``BLOCK`` records; the traffic file's
+key mix (``key_mix.kind`` names ``lib/keymix/<kind>.py``) draws block
+``b``'s keys from the seed and ``b`` alone, so a block never depends on
 how the stream was chunked on the wire. Feature payloads cycle through a
 seeded pool (``pool_rows`` rows); the key sequence does not cycle.
 """
 
 from __future__ import annotations
 
+import threading
+
 import numpy as np
 
+from . import byname
 from . import keys as keys_mod
 
 BLOCK = 65536
+_BLOCKS_KEPT = 4  # two chunks in the producer's hands span three, and one ahead
 
 
 class Stream:
@@ -32,20 +37,30 @@ class Stream:
         self.pool = rng.normal(
             0.0, 1.5, size=(int(pool_rows), self.n_features)
         ).astype(np.float32)
-        self._block = (-1, None)
+        self._mix_ranks = byname.load(
+            "lib/keymix", self.mix["kind"]).ranks
+        # block → [lock, ranks]: several threads of the producer ask
+        # for the same block; one draws it and the others wait for that
+        # block alone
+        self._blocks, self._mu = {}, threading.Lock()
 
     def _block_ranks(self, b: int) -> np.ndarray:
-        if self._block[0] != b:
-            kind = self.mix["kind"]
-            if kind == "zipf":
-                rng = np.random.default_rng([self.seed, 1, b])
-                r = keys_mod.zipf_ranks(
-                    rng, BLOCK, self.domain, float(self.mix["a"])
-                )
-            else:
-                raise ValueError(f"unknown key mix {kind!r}")
-            self._block = (b, r)
-        return self._block[1]
+        with self._mu:
+            slot = self._blocks.get(b)
+            if slot is None:
+                slot = self._blocks[b] = [threading.Lock(), None]
+                while len(self._blocks) > _BLOCKS_KEPT:
+                    del self._blocks[next(iter(self._blocks))]
+        with slot[0]:
+            if slot[1] is None:
+                r = np.asarray(self._mix_ranks(
+                    b, self.seed, self.domain, self.mix, BLOCK), np.int64)
+                if r.shape != (BLOCK,) or r.min() < 0 or r.max() >= self.domain:
+                    raise ValueError(
+                        f"key mix {self.mix['kind']!r}: block {b} is not "
+                        f"{BLOCK} ranks below {self.domain}")
+                slot[1] = r
+        return slot[1]
 
     def ranks(self, lo: int, hi: int) -> np.ndarray:
         """Key ranks of offsets [lo, hi)."""

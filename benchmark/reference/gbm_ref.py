@@ -3,9 +3,8 @@ nothing of the program in it.
 
 ``scores`` is a numpy tree walk over the generator's own arrays
 (benchmark/lib/gbm.py) in float32 comparisons and a float64 sum — no
-rank wire, no bucketizer, no kernel. ``KeyTally`` is the keyed state a
-stream of (key, score) pairs must leave behind: per key, how many
-records and the sum of their scores.
+rank wire, no bucketizer, no kernel. The keyed state's reference is
+``reference/state_ref.py``.
 """
 
 from __future__ import annotations
@@ -33,17 +32,3 @@ def scores(g, X: np.ndarray, chunk: int = 8192) -> np.ndarray:
             g.leaf[trees, node - n_inner].sum(axis=1) + g.base_score
         )
     return out
-
-
-class KeyTally:
-    """Dict tally of per-key record count and score sum."""
-
-    def __init__(self):
-        self.count = {}
-        self.total = {}
-
-    def fold(self, keys, values) -> None:
-        for k, v in zip(np.asarray(keys).tolist(),
-                        np.asarray(values, np.float64).tolist()):
-            self.count[k] = self.count.get(k, 0) + 1
-            self.total[k] = self.total.get(k, 0.0) + v

@@ -4,6 +4,8 @@
     JAX_PLATFORMS=cpu python3 benchmark/rehearse.py            # every cell, tiny
     JAX_PLATFORMS=cpu python3 benchmark/rehearse.py --compile  # real size, for the v5e
     python3 benchmark/rehearse.py --starve                     # on the chip: the guard trips
+    JAX_PLATFORMS=cpu python3 benchmark/rehearse.py --drain-only  # tiny: the producer against the program's bare ingest
+    python3 benchmark/rehearse.py --drain-only                 # on the chip's host, real size: the same
 
 The first runs every cell of ``BENCHMARK.json`` end to end on the CPU at
 a tiny size (20 trees, 61,001 slots with 45,000 keys resident, batch
@@ -25,6 +27,18 @@ The third is a chip run: every saturated cell at its real size for 10 s
 with the producer held to ``STARVED_RECORDS_PER_S``, well under what
 the pipeline drains. It has to end ``correct: false`` with the log's
 lead named as the fault, and exits non-zero if it does not.
+
+The fourth needs no device: each cell's producer (``lib/loadgen.py``,
+the cell's own traffic file) against a consumer that does nothing but
+drain: the cell's ``KafkaBlockSource`` behind the program's prefetch
+sidecar, its blocks counted and dropped. No pipeline fed through that
+source can take the log faster, so where the log's lead over this
+consumer never falls under ``least_backlog_allowed`` (by the harness's
+account and the producer's, as in a run), no window of the cell can
+"measure the producer". Three seeds, ``run_seconds`` each at real size;
+one seed of 2 s at the tiny size under ``JAX_PLATFORMS=cpu``. Every
+offset has to arrive once, in order, and the head of every block has to
+be the stream's. Exits non-zero where the lead is not held.
 """
 
 import argparse
@@ -116,6 +130,98 @@ def starve_on_chip() -> None:
           flush=True)
 
 
+def drain_only(tiny: bool) -> None:
+    import threading
+    import time
+
+    import numpy as np
+
+    import run
+    from flink_jpmml_tpu.runtime import prefetch
+    from flink_jpmml_tpu.runtime.kafka import KafkaBlockSource
+    from flink_jpmml_tpu.utils.metrics import MetricsRegistry
+    from lib.stream import Stream
+
+    with open(os.path.join(run.ROOT, "BENCHMARK.json")) as fh:
+        seconds = 2.0 if tiny else float(json.load(fh)["run_seconds"])
+    seeds = (2**31 + 11,) if tiny else (2**31 + 11, 2**31 + 77, 2**31 + 1234)
+    for cell in cells():
+        for seed in seeds:
+            args = argparse.Namespace(
+                workload=cell, seed=seed, seconds=seconds, trace=0)
+            _, cfg, traffic, _, _, _ = run.load_cell(
+                args, TINY if tiny else None)
+            F = int(cfg["model"]["n_features"])
+            stream = Stream(seed, F, cfg["key_domain"], traffic["key_mix"],
+                            traffic["pool_rows"])
+            child = run.Child()
+            drained = {"hi": 0, "bad": []}
+            stop = threading.Event()
+            source = None
+            try:
+                child.send(cmd="init", seed=seed, topic="bench", n_features=F,
+                           key_domain=cfg["key_domain"],
+                           key_mix=traffic["key_mix"],
+                           pool_rows=traffic["pool_rows"])
+                addr = child.read()
+                source = prefetch.maybe_wrap_block(
+                    KafkaBlockSource(
+                        addr["host"], addr["port"], "bench", n_cols=F,
+                        max_wait_ms=int(cfg["pipeline"]["max_wait_ms"]),
+                        metrics=MetricsRegistry(),
+                    ), enable=True)
+
+                def drain():
+                    while not stop.is_set():
+                        item = source.poll()
+                        if item is None:
+                            continue
+                        first, rows = item
+                        if first != drained["hi"]:
+                            drained["bad"].append(
+                                f"block at {first}, expected {drained['hi']}")
+                        elif not np.array_equal(
+                                rows[:64], stream.rows(first, first + min(
+                                    64, rows.shape[0]))):
+                            drained["bad"].append(f"rows at {first} differ")
+                        drained["hi"] = first + rows.shape[0]
+
+                consumer = threading.Thread(target=drain, daemon=True)
+                consumer.start()
+                started = child.ask(cmd="start", traffic=traffic, delivered=0)
+                watch = run.LeadWatch(child, lambda: drained["hi"])
+                time.sleep(max(0.0, float(started["t0"]) + float(
+                    traffic["settle_s"]) - time.monotonic()))
+                w0, n0 = time.monotonic(), drained["hi"]
+                time.sleep(seconds)
+                w1, n1 = time.monotonic(), drained["hi"]
+                watch.stop()
+                gen = child.ask(cmd="stop")
+                stop.set()
+                consumer.join(timeout=10.0)
+            finally:
+                stop.set()
+                if source is not None:
+                    source.close()
+                child.close()
+            lead = watch.least(w0, w1)
+            allowed = int(traffic["least_backlog_allowed"])
+            res = {
+                "cell": cell, "seed": seed, "window_s": w1 - w0,
+                "drained_records_per_s": (n1 - n0) / (w1 - w0),
+                "least_lead_harness": lead, "generator": gen,
+                "allowed": allowed, "faults": drained["bad"][:5],
+            }
+            print(json.dumps(res), flush=True)
+            least = (lead["least"], gen.get("least_backlog_records"))
+            if drained["bad"] or any(v is None or v < allowed for v in least):
+                sys.exit(f"{cell}: the producer did not hold the log's lead "
+                         f"over a consumer that only drains: {least}, "
+                         f"{allowed} allowed")
+    print("drain-only: the producer held the log's lead over the program's "
+          "bare ingest in every cell", flush=True)
+
+
 def compile_for_v5e() -> None:
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     import jax
@@ -179,9 +285,12 @@ if __name__ == "__main__":
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--compile", action="store_true")
     ap.add_argument("--starve", action="store_true")
+    ap.add_argument("--drain-only", action="store_true")
     a = ap.parse_args()
     if a.starve:
         starve_on_chip()
+    elif a.drain_only:
+        drain_only(tiny=os.environ.get("JAX_PLATFORMS") == "cpu")
     elif os.environ.get("JAX_PLATFORMS") != "cpu":
         sys.exit("rehearse.py runs under JAX_PLATFORMS=cpu")
     else:

@@ -6,10 +6,13 @@
 Finds the cell in ``BENCHMARK.json``, loads its configuration
 (``configs/<config>.json``), its traffic mix (``traffic/<mix>.json``,
 with ``traffic/<mix>.<config>.json`` laid over it where a cell has
-parameters of its own), its path builder (``paths/<path>.py``, named by
-the configuration) and one reader per metric the cell reports
-(``end_to_end/<metric>.py``, ``layer_metrics/<metric>.py``). Nothing in
-this file knows a cell, a configuration, a mix or a metric by name.
+parameters of its own), what the configuration names (its path builder
+``paths/<path>.py``, its model kind ``models/<model_kind>.py``, its
+warm-up check ``warmup_checks/<warmup_check>.py``, the counters that
+``must_stay_zero``), the mix's key mix (``lib/keymix/<kind>.py``) and
+one reader per metric the cell reports (``end_to_end/<metric>.py``,
+``layer_metrics/<metric>.py``). Nothing in this file knows a cell, a
+configuration, a model, a mix or a metric by name.
 
 One process holds the chip; the broker and the producer live in a
 jax-free child (``lib/loadgen.py``). Everything before the window is
@@ -17,9 +20,13 @@ set-up: model from the seed, parse, compile (or cache load), the state
 table filled to the deployment's resident keys (``lib/prefill.py``),
 every dispatch shape the window can use, a warm-up stream through the
 real pipeline, and the check of that stream against the plain
-reference. The last line of stdout is the
-result; without a TPU, or with fewer chips than the cell asks for, the
-run exits non-zero and prints none.
+reference. Once the window has closed, a sample of the scores it
+delivered, drawn from the seed, is held to the reference too. Every
+number that decides ``correct`` is printed beside its limit, as the
+last lines of stderr and under ``compared``, the last key of the
+result. The last line of stdout is the result; without a TPU, or with
+fewer chips than the cell asks for, the run exits non-zero and prints
+none.
 """
 
 import time
@@ -44,16 +51,10 @@ ROOT = os.path.dirname(HERE)
 sys.path.insert(0, HERE)   # lib, reference, paths
 sys.path.insert(1, ROOT)   # the program under test
 
-# tolerance of a delivered score against the float64 tree walk: the rank
-# wire carries each leaf as a bf16 hi+lo pair (2**-17 relative,
-# compile/qtrees.py _split_bf16) and adds 500 of them in float32, which
-# leaves ~2e-5 absolute on sums of magnitude 2-9 (measured 2.2e-5). The
-# program's own tests hold the kernel to rtol 1e-4 / atol 1e-5 against
-# its XLA twin, which shares that quantisation
-# (tests/test_qtrees_pallas.py:41); against an exact walk the absolute
-# floor is 5e-5. A bf16-only sum would miss by ~4e-3.
-SCORE_RTOL, SCORE_ATOL = 1e-4, 5e-5
 STALL_S = 8.0  # a dispatch takes under a second
+# the window's scores held to the reference: runs of consecutive
+# offsets at places drawn from the seed (a run costs one block of keys)
+WINDOW_SAMPLE_RUNS, WINDOW_SAMPLE_RUN = 64, 64
 
 
 def log(msg: str) -> None:
@@ -71,15 +72,19 @@ def load_json(*parts: str) -> dict:
 
 
 def load_module(kind: str, name: str):
-    path = os.path.join(HERE, kind, f"{name}.py")
-    if not os.path.isfile(path):
-        die(f"no {kind}/{name}.py")
-    spec = importlib.util.spec_from_file_location(
-        f"bench_{kind}_{name.replace('.', '_')}", path
-    )
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
+    from lib import byname
+
+    try:
+        return byname.load(kind, name)
+    except LookupError as e:
+        die(str(e))
+
+
+def named(cfg: dict, key: str):
+    """What the configuration names under ``key``."""
+    if key not in cfg:
+        die(f"configuration {cfg.get('name')!r} names no {key!r}")
+    return cfg[key]
 
 
 class Child:
@@ -124,6 +129,38 @@ class Child:
         self.proc.wait()
 
 
+class LeadWatch:
+    """The producer follows the sink through this thread, and the
+    harness takes the log's real lead from it: what the broker holds
+    (the child's reply) less what the sink has by then, every 10 ms. A
+    thread of its own, because starting and stopping a trace blocks
+    the main one for seconds."""
+
+    def __init__(self, child: Child, delivered_hi):
+        self.leads = []  # (time, records the log is ahead of the sink)
+        self._child, self._delivered_hi = child, delivered_hi
+        self._done = threading.Event()
+        self._thread = threading.Thread(target=self._run, daemon=True)
+        self._thread.start()
+
+    def _run(self) -> None:
+        while not self._done.is_set():
+            hi = self._delivered_hi()
+            produced = self._child.ask(cmd="delivered", n=hi)["produced"]
+            self.leads.append((time.monotonic(), produced - hi))
+            time.sleep(0.01)
+
+    def stop(self) -> None:
+        self._done.set()
+        self._thread.join(timeout=10.0)
+
+    def least(self, w0: float, w1: float) -> dict:
+        """The least lead sampled inside [w0, w1], and when."""
+        in_w = [(a, t - w0) for t, a in self.leads if w0 <= t <= w1]
+        return dict(zip(("least", "at_s"), min(in_w, default=(None, None))),
+                    samples=len(in_w))
+
+
 class Sink:
     """What reached the sink: per delivery its range of offsets, the
     time, and whether the scores were finite. Appends are atomic under
@@ -132,6 +169,8 @@ class Sink:
     def __init__(self):
         self.deliveries = []  # (first_offset, n, t_done)
         self.scores = None    # offset → score while the warm-up is kept
+        self.kept = None      # (first_offset, scores) of every delivery
+        self.keep_until = float("inf")  # ... that is done by then
         self.nonfinite = 0
         self.delivered_hi = 0
         self.count = 0
@@ -145,6 +184,9 @@ class Sink:
         if self.scores is not None and first < self.scores.shape[0]:
             keep = min(n, self.scores.shape[0] - first)
             self.scores[first:first + keep] = scores[:keep]
+        kept = self.kept  # the harness takes the list away: read it once
+        if kept is not None and t_done <= self.keep_until:
+            kept.append((first, scores))
         self.deliveries.append((first, n, t_done))
         self.delivered_hi = max(self.delivered_hi, first + n)
         self.count += n
@@ -177,80 +219,69 @@ def require_device(chips: int, on_chip: bool):
     return jax, devices, pk
 
 
-def check_warmup(stream, model_arrays, sink, path, n_warm: int,
-                 first_rows):
-    """The delivered warm-up stream (offsets below ``n_warm``) against
-    the plain reference → faults. Scores: a sample of delivered scores
-    against the float64 tree walk. State: after the stream, each key's
-    table row (count, score sum) against the row its slot started with
-    (``first_rows(slots)``) plus a dict tally of what the sink
-    received; left out are keys of the stream that share the table's
-    uint32 hash with another key of the stream."""
-    import jax.numpy as jnp
-    from flink_jpmml_tpu.runtime.state import COL_COUNT, COL_SUM
-    from reference import gbm_ref
+def score_miss(model, handle, X, got):
+    """→ (largest miss of a delivered score, as a share of what the
+    model kind allows: ``|got - ref| / (atol + rtol |ref|)``; largest
+    absolute difference)."""
+    ref = model.reference_scores(handle, X)
+    diff = np.abs(np.asarray(got, np.float64) - ref)
+    allowed = model.SCORE_ATOL + model.SCORE_RTOL * np.abs(ref)
+    return float((diff / allowed).max()), float(diff.max())
 
-    faults = []
+
+def check_warmup_scores(stream, model, handle, sink, n_warm: int):
+    """A sample of the delivered warm-up scores (offsets below
+    ``n_warm``) against the model kind's plain reference → (faults,
+    compared)."""
     got = sink.scores[:n_warm]
-    if np.isnan(got).any():
-        return [f"{int(np.isnan(got).sum())} warm-up records never delivered"]
+    missing = int(np.isnan(got).sum())
+    if missing:
+        return ([f"{missing} warm-up records never delivered"],
+                [("warmup_records_missing", missing, 0)])
     sample = np.arange(0, n_warm, max(1, n_warm // 4096))
-    X = stream.rows(0, n_warm)[sample]
-    ref = gbm_ref.scores(model_arrays, X)
-    bad = ~np.isclose(got[sample], ref, rtol=SCORE_RTOL, atol=SCORE_ATOL)
-    log(f"reference: {sample.size} sampled scores, max |diff| "
-        f"{float(np.abs(got[sample] - ref).max()):.3e}, {int(bad.sum())} "
-        f"beyond rtol {SCORE_RTOL} atol {SCORE_ATOL}")
-    if bad.any():
-        faults.append(f"{int(bad.sum())} scores differ from the reference")
-
-    table = path.table
-    ids = stream.ids(0, n_warm)
-    tally = gbm_ref.KeyTally()
-    tally.fold(ids, got)
-    uniq = np.fromiter(tally.count.keys(), np.int64, len(tally.count))
-    khash = table.hash_keys(uniq)
-    _, inv, cnt = np.unique(khash, return_inverse=True, return_counts=True)
-    shared = cnt[inv] > 1
-    log(f"state: {uniq.size} distinct keys in the warm-up stream, "
-        f"{int(shared.sum())} share a uint32 hash: left out")
-    if shared.sum() > max(4, uniq.size // 1000):
-        faults.append(f"{int(shared.sum())} keys share a hash")
-    uniq, khash = uniq[~shared], khash[~shared]
-    c0 = table.metrics.struct_snapshot()["counters"]
-    # the table's own routing, as a lookup: every key is resident, so
-    # nothing is inserted (checked) and only LRU stamps move
-    slots, reset, _, _ = table.assign_slots(
-        khash, np.zeros(uniq.size, np.int64)
-    )
-    c1 = table.metrics.struct_snapshot()["counters"]
-    if c1["state_inserts"] != c0["state_inserts"] or reset.any() or (
-            slots == table.scratch).any():
-        faults.append("warm-up keys were not all resident in the table")
-    rows = np.asarray(table.values[jnp.asarray(slots)])
-    first = np.asarray(first_rows(slots), np.float64)
-    want_n = first[:, COL_COUNT] + np.array(
-        [tally.count[k] for k in uniq.tolist()], np.float64)
-    want_s = first[:, COL_SUM] + np.array(
-        [tally.total[k] for k in uniq.tolist()], np.float64)
-    bad_n = rows[:, COL_COUNT] != want_n
-    # float32 running sums: one rounding per record folded
-    tol = 1e-6 * want_n * np.maximum(np.abs(want_s), 1.0) + 1e-4
-    bad_s = np.abs(rows[:, COL_SUM] - want_s) > tol
-    log(f"state: {int(bad_n.sum())} counts and {int(bad_s.sum())} score "
-        f"sums differ from the tally over {uniq.size} keys (largest count "
-        f"{int(want_n.max())})")
-    if bad_n.any() or bad_s.any():
-        faults.append("table rows differ from the reference tally")
-    return faults
+    miss, diff = score_miss(
+        model, handle, stream.rows(0, n_warm)[sample], got[sample])
+    log(f"reference: {sample.size} sampled warm-up scores, max |diff| "
+        f"{diff:.3e}, largest miss {miss:.3f} of rtol {model.SCORE_RTOL} "
+        f"atol {model.SCORE_ATOL}")
+    faults = [] if miss <= 1.0 else [
+        "warm-up scores differ from the reference"]
+    return faults, [("warmup_score_miss_over_tol", miss, 1.0)]
 
 
-ZERO_COUNTERS = (
-    "fallback_records", "redispatch_records", "oom_shrinks",
-    "state_bypass_records", "state_rollbacks", "state_evictions",
-    "state_overflow",
-)
-ZERO_PREFIXES = ("device_fault_total", "dlq_records")
+def check_window_scores(seed, stream, model, handle, kept, span):
+    """Scores the window delivered against the plain reference, once it
+    has closed: ``WINDOW_SAMPLE_RUNS`` runs of ``WINDOW_SAMPLE_RUN``
+    consecutive offsets, placed by the seed among the offsets delivered
+    in ``span`` (first, one past last) → (faults, compared)."""
+    lo, hi = span
+    if hi - lo < WINDOW_SAMPLE_RUN:
+        return ["no delivery inside the window to hold to the reference"], []
+    rng = np.random.default_rng([seed, 2])
+    starts = np.unique(rng.integers(
+        lo, hi - WINDOW_SAMPLE_RUN + 1, size=WINDOW_SAMPLE_RUNS))
+    firsts = np.array([f for f, _ in kept], np.int64)
+    got = np.full((starts.size, WINDOW_SAMPLE_RUN), np.nan)
+    for i, a in enumerate(starts.tolist()):
+        for off in range(a, a + WINDOW_SAMPLE_RUN):
+            # deliveries are in offset order: the last that starts at
+            # or before the offset holds it, or nothing does
+            j = int(np.searchsorted(firsts, off, side="right")) - 1
+            first, scores = kept[j]
+            if j >= 0 and off - first < scores.shape[0]:
+                got[i, off - a] = scores[off - first]
+    missing = int(np.isnan(got).sum())
+    if missing:
+        return ([f"{missing} sampled offsets of the window have no score"],
+                [("window_sample_missing", missing, 0)])
+    X = np.concatenate([
+        stream.rows(a, a + WINDOW_SAMPLE_RUN) for a in starts.tolist()])
+    miss, diff = score_miss(model, handle, X, got.reshape(-1))
+    log(f"reference: {got.size} scores of the window, max |diff| "
+        f"{diff:.3e}, largest miss {miss:.3f} of the tolerance")
+    faults = [] if miss <= 1.0 else [
+        "scores delivered in the window differ from the reference"]
+    return faults, [("window_score_miss_over_tol", miss, 1.0)]
 
 
 def load_cell(args, overrides):
@@ -299,10 +330,13 @@ def wait_for_warmup(path, sink, n_warm: int) -> None:
 
 class TraceStretch:
     """A profiler trace of ``seconds`` starting ``after`` seconds into
-    the window, under the host annotation ``bench.window``."""
+    the window, under the host annotation ``bench.window``; its idle
+    gaps are named for the program's own host spans."""
 
-    def __init__(self, jax, w0: float, after: float, seconds: float):
+    def __init__(self, jax, w0: float, after: float, seconds: float,
+                 span_prefix: str):
         self._jax = jax
+        self._spans = span_prefix  # the host spans idle gaps are named for
         self._start, self._stop = w0 + after, w0 + after + seconds
         self._dir = tempfile.TemporaryDirectory(prefix="bench-trace-")
         self._span = None
@@ -334,7 +368,8 @@ class TraceStretch:
             xp = xtrace.find_xplane(self._dir.name)
             if xp is None:
                 return {"devices": 0}
-            return xtrace.reduce_trace(xp, window_name="bench.window")
+            return xtrace.reduce_trace(
+                xp, window_name="bench.window", span_prefix=self._spans)
         finally:
             self._dir.cleanup()
 
@@ -349,6 +384,19 @@ def run_cell(args, overrides=None, on_chip: bool = True) -> dict:
         die("the program under test (flink_jpmml_tpu) is not in this checkout")
     if traffic["loop"] != "closed_backlog":
         die(f"lib/loadgen.py has no producer for loop {traffic['loop']!r}")
+    from lib.stream import Stream
+
+    model = load_module("models", named(cfg, "model_kind"))
+    warmup_check = load_module("warmup_checks", named(cfg, "warmup_check"))
+    zero_names = [str(n) for n in named(cfg, "must_stay_zero")]
+    m = cfg["model"]
+    try:
+        stream = Stream(
+            args.seed, m["n_features"], cfg["key_domain"], traffic["key_mix"],
+            traffic["pool_rows"],
+        )
+    except LookupError as e:  # the mix names a key mix there is no file for
+        die(str(e))
     child = Child()
     path = None
     planner = concurrent.futures.ThreadPoolExecutor(max_workers=1)
@@ -369,8 +417,8 @@ def run_cell(args, overrides=None, on_chip: bool = True) -> dict:
         )
         jax, devices, peaks_row = require_device(cell["chips"], on_chip)
 
-        from lib import gbm, xtrace
-        from lib.stream import Stream
+        from lib import readers as readers_lib
+        from lib import xtrace
 
         compiles = []
         jax.monitoring.register_event_duration_secs_listener(
@@ -381,20 +429,12 @@ def run_cell(args, overrides=None, on_chip: bool = True) -> dict:
         from flink_jpmml_tpu.pmml import parse_pmml_file
 
         # -- set-up ----------------------------------------------------
-        m = cfg["model"]
-        arrays = gbm.gen_arrays(
-            args.seed, m["n_trees"], m["depth"], m["n_features"],
-            m["hist_bins"], m["base_score"],
-        )
+        handle = model.generate(args.seed, m)
         with tempfile.TemporaryDirectory(prefix="bench-model-") as d:
-            doc = parse_pmml_file(gbm.write_pmml(arrays, d))
+            doc = parse_pmml_file(model.write_pmml(handle, d))
         compiled = compile_pmml(doc, batch_size=int(cfg["compile_batch"]))
         log(f"model parsed and lowered at {time.monotonic() - _T_PROCESS:.1f}s")
         addr = dict(child.read(), topic="bench")
-        stream = Stream(
-            args.seed, m["n_features"], cfg["key_domain"], traffic["key_mix"],
-            traffic["pool_rows"],
-        )
         sink = Sink()
         n_warm = int(cfg["warmup_records"])
         sink.keep_scores(n_warm)
@@ -422,44 +462,56 @@ def run_cell(args, overrides=None, on_chip: bool = True) -> dict:
         child.ask(cmd="produce", n=n_warm)
         wait_for_warmup(path, sink, n_warm)
         beside -= state_records()
-        faults = check_warmup(
-            stream, arrays, sink, path, n_warm,
-            lambda slots: prefill.initial_rows(args.seed, slots),
-        )
+        faults, compared = check_warmup_scores(
+            stream, model, handle, sink, n_warm)
+        if not faults:
+            state_faults, state_compared = warmup_check.check({
+                "seed": args.seed, "stream": stream, "n_warm": n_warm,
+                "scores": sink.scores[:n_warm], "path": path, "cfg": cfg,
+                "log": log,
+            })
+            faults += state_faults
+            compared += state_compared
         beside += state_records()
         sink.scores = None
+        sink.kept = []
         log(f"warm-up checked at {time.monotonic() - _T_PROCESS:.1f}s")
 
         # -- the window ------------------------------------------------
         started = child.ask(cmd="start", traffic=traffic, delivered=n_warm)
-        # The producer follows the sink through this thread, and the
-        # harness takes the log's real lead from it: what the broker
-        # holds (its reply) less what the sink has by then. A thread of
-        # its own, because starting and stopping a trace blocks the main
-        # one for seconds.
-        leads = []  # (time, records the log is ahead of the sink)
-        feeding = threading.Event()
-
-        def feed():
-            while not feeding.is_set():
-                produced = child.ask(
-                    cmd="delivered", n=sink.delivered_hi)["produced"]
-                leads.append(
-                    (time.monotonic(), produced - sink.delivered_hi))
-                time.sleep(0.01)
-
-        feedback = threading.Thread(target=feed, daemon=True)
-        feedback.start()
+        watch = LeadWatch(child, lambda: sink.delivered_hi)
         time.sleep(max(0.0, float(started["t0"]) + float(traffic["settle_s"])
                        - time.monotonic()))
         w0 = time.monotonic()
         w1 = w0 + float(args.seconds)
+        sink.keep_until = w1 + 2 * STALL_S
         setup_s = w0 - _T_PROCESS
         snap0 = path.metrics.struct_snapshot()
         n_compiles0 = len(compiles)
+        # The window's second snapshot, at its end and on a thread of
+        # its own: stopping a trace holds the main loop far past w1
+        # (tens of seconds on a long stretch), and a snapshot taken
+        # when it gets out would book that time's stages to the window.
+        at_end = {}
+
+        def snap_at_end():
+            time.sleep(max(0.0, w1 - time.monotonic()))
+            at_end["snap1"] = path.metrics.struct_snapshot()
+            at_end["compiles"] = len(compiles)
+
+        closer = threading.Thread(target=snap_at_end, daemon=True)
+        closer.start()
+        # The traced stretch lies at the window's end: stopping a trace
+        # turns millions of events into a file for tens of seconds, on
+        # the host's cores, and inside the window that work would be
+        # booked to the pipeline's host stages. It ends 1.25 s before
+        # the window does: a span that began before the trace is not in
+        # it, and at 0.25 s the cell's stretch began inside a renorm's
+        # hold in every run, its idle gaps under a span nobody saw.
+        stretch_s = min(float(traffic["trace_seconds"]), 0.6 * args.seconds)
         stretch = TraceStretch(
-            jax, w0, min(1.0, 0.1 * args.seconds),
-            min(float(traffic["trace_seconds"]), 0.6 * args.seconds),
+            jax, w0, max(0.0, args.seconds - stretch_s - 1.25), stretch_s,
+            readers_lib.SPAN_PREFIX,
         ) if args.trace else None
         seen, since, stalled = sink.count, w0, False
         while (now := time.monotonic()) < w1:
@@ -475,8 +527,11 @@ def run_cell(args, overrides=None, on_chip: bool = True) -> dict:
                 faulthandler.dump_traceback(file=sys.stderr)
                 faults.append(f"no delivery for {STALL_S:.0f} s")
             time.sleep(0.005)
-        snap1 = path.metrics.struct_snapshot()
-        n_compiles = len(compiles) - n_compiles0
+        closer.join(timeout=30.0)
+        if closer.is_alive():
+            die("the snapshot at the window's end was never taken")
+        snap1 = at_end["snap1"]
+        n_compiles = at_end["compiles"] - n_compiles0
         if stretch is not None:
             stretch.finish()
         mem_peak = max(
@@ -490,15 +545,13 @@ def run_cell(args, overrides=None, on_chip: bool = True) -> dict:
             path.check_alive()
             time.sleep(0.005)
         deliveries = list(sink.deliveries)
+        kept, sink.kept = sink.kept, None
         hi = sink.delivered_hi
-        feeding.set()
-        feedback.join(timeout=10.0)
+        watch.stop()
         gen = child.ask(cmd="stop")
         path.stop()
         log(f"generator: {json.dumps(gen)}")
-        in_w = [(a, t - w0) for t, a in leads if w0 <= t <= w1]
-        lead = dict(zip(("least", "at_s"), min(in_w, default=(None, None))),
-                    samples=len(in_w))
+        lead = watch.least(w0, w1)
         log(f"log's lead over the sink, from the harness: {json.dumps(lead)}")
 
         # -- what the window held ---------------------------------------
@@ -506,9 +559,23 @@ def run_cell(args, overrides=None, on_chip: bool = True) -> dict:
         lost, dup = int((counts == 0).sum()), int((counts > 1).sum())
         in_window = [(t, n) for _, n, t in deliveries if w0 <= t <= w1]
         attempted = int(sum(n for _, n in in_window))
+        firsts = [f for f, _, t in deliveries if w0 <= t <= w1]
+        window_faults, window_compared = check_window_scores(
+            args.seed, stream, model, handle, kept,
+            (min(firsts), min(firsts) + attempted) if firsts else (0, 0),
+        )
+        del kept
+        faults += window_faults
+        compared += window_compared + [
+            ("offsets_lost", lost, 0), ("offsets_duplicated", dup, 0),
+            ("scores_nonfinite", sink.nonfinite, 0),
+            ("compilations_in_window", n_compiles, 0),
+        ]
         allowed = int(traffic["least_backlog_allowed"])
         for who, least in (("harness", lead["least"]),
                            ("producer", gen.get("least_backlog_records"))):
+            compared.append((f"least_lead_records.{who}", least, allowed,
+                             least is not None and least >= allowed))
             if least is None or least < allowed:
                 faults.append(
                     "the log's lead over the sink " + (
@@ -518,8 +585,13 @@ def run_cell(args, overrides=None, on_chip: bool = True) -> dict:
                     "window measured the producer"
                 )
         final = path.metrics.struct_snapshot()["counters"]
-        for name, v in final.items():
-            if v and (name in ZERO_COUNTERS or name.startswith(ZERO_PREFIXES)):
+        exact = {n for n in zero_names if not n.endswith("*")}
+        prefixes = tuple(n[:-1] for n in zero_names if n.endswith("*"))
+        for name in sorted(exact | {
+                n for n in final if prefixes and n.startswith(prefixes)}):
+            v = final.get(name, 0)
+            compared.append((f"counter.{name}", v, 0))
+            if v:
                 faults.append(f"{name} = {v}")
         # every delivered record was folded once; what was dispatched
         # and not delivered when the pipeline stopped is folded besides
@@ -527,6 +599,8 @@ def run_cell(args, overrides=None, on_chip: bool = True) -> dict:
         slack = (int(cfg["pipeline"]["in_flight"]) + 1) * int(
             cfg["pipeline"].get("max_dispatch_chunks", 1)
         ) * int(cfg["compile_batch"])
+        compared.append(("state_records_beyond_delivered",
+                         folded - sink.count, slack))
         if not (sink.count <= folded <= sink.count + slack):
             faults.append(
                 f"state_records {folded} against {sink.count} delivered"
@@ -581,6 +655,17 @@ def run_cell(args, overrides=None, on_chip: bool = True) -> dict:
             metrics = {}
         result["metrics"] = metrics
         result["device"] = device
+        # each number that decided ``correct``, beside its limit: the
+        # last key of the line, and the last lines of stderr
+        result["compared"] = {}
+        for name, value, limit, *holds in compared:
+            # a limit is the most a number may be, unless its entry
+            # says itself whether it holds (a lead is a least)
+            ok = bool(holds[0]) if holds else value <= limit
+            result["compared"][name] = {
+                "value": value, "limit": limit, "holds": ok}
+            print(f"compared: {name} {value} limit {limit} "
+                  f"{'holds' if ok else 'BROKEN'}", file=sys.stderr, flush=True)
         return result
     finally:
         planner.shutdown(wait=True, cancel_futures=True)
