@@ -37,9 +37,6 @@ ORACLE_ROWS = 48
 SEED = 21
 # the tolerances tests/test_qtrees_pallas.py and tests/test_qtrees.py use
 RTOL, ATOL = 1e-4, 1e-5
-# the mesh scores on the f32 lowering, which adds the 500 leaf values in
-# another order than the rank-wire kernel: one f32 rounding per tree
-MESH_ATOL = TREES * 1.1920929e-07
 STREAM_TIMEOUT_S = 600.0
 
 
@@ -263,14 +260,20 @@ def main(argv=None) -> None:
         )
         check(owned == list(range(n_parts)),
               f"partition ownership {owned}")
+        # the mesh runs the one-chip rank-wire kernel on each chip's rows
+        # (QuantizedScorer.on_mesh): held to the one-chip bound, and the
+        # largest difference is printed (the CPU tests hold it to 0)
         np.testing.assert_allclose(
-            many["scores"], one["scores"], rtol=RTOL, atol=MESH_ATOL
+            many["scores"], one["scores"], rtol=RTOL, atol=ATOL
         )
         facts["mesh"] = {
             "chips": args.mesh,
             "partitions": n_parts,
             "pipeline_backend": many["backend"],
             "chip_records": {k: v["records"] for k, v in chips.items()},
+            "max_abs_diff_to_one_chip": float(
+                np.abs(many["scores"] - one["scores"]).max()
+            ),
         }
 
     facts["elapsed_s"] = round(time.monotonic() - t_start, 1)

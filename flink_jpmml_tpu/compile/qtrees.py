@@ -338,6 +338,76 @@ class QuantizedScorer:
     # tables pinned — the pack reads the live ``params``); None on the
     # Pallas backend, whose program bakes its own grid.
     _pack_info: object = None
+    # the mesh this scorer spans (``on_mesh``; None: one device), and
+    # the twins built from this scorer, one a mesh
+    mesh: object = None
+    _mesh_twins: dict = field(default_factory=dict)
+
+    # -- a scorer over a mesh -----------------------------------------------
+
+    def on_mesh(self, mesh):
+        """This scorer over ``mesh``: forest parameters replicated, the
+        wire batch sharded on the data axis, each chip running the
+        unchanged kernel on its own rows under ``shard_map``. Scores
+        are this scorer's, bit for bit (a record's score does not
+        depend on its batch). One twin a mesh, built once."""
+        if mesh is None or mesh == self.mesh:
+            return self
+        if mesh in self._mesh_twins:
+            return self._mesh_twins[mesh]
+        import dataclasses
+
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        twin = dataclasses.replace(
+            self,
+            params=jax.device_put(self.params, NamedSharding(mesh, P())),
+            mesh=mesh, _multi_fns={}, _donate_fn=None, _mesh_twins={},
+        )
+        self._mesh_twins[mesh] = twin
+        return twin
+
+    @property
+    def data_width(self) -> int:
+        """Chips a batch is spread over (1 without a mesh)."""
+        if self.mesh is None:
+            return 1
+        from flink_jpmml_tpu.parallel.mesh import DATA_AXIS
+
+        return int(self.mesh.shape.get(DATA_AXIS, 1))
+
+    def shardings(self):
+        """→ (replicated, sharded on the leading axis over the data
+        axis) on this scorer's mesh."""
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        from flink_jpmml_tpu.parallel.mesh import DATA_AXIS
+
+        return (NamedSharding(self.mesh, P()),
+                NamedSharding(self.mesh, P(DATA_AXIS)))
+
+    def spmd(self, fn, n_sharded: int = 1):
+        """``fn(params, *operands)`` as each chip of the mesh runs it:
+        params whole, every other operand and every output its own
+        rows. Without a mesh, ``fn`` itself."""
+        if self.mesh is None:
+            return fn
+        from jax.sharding import PartitionSpec as P
+
+        from flink_jpmml_tpu.parallel.mesh import DATA_AXIS
+
+        data = P(DATA_AXIS)
+        return jax.shard_map(
+            fn, mesh=self.mesh, in_specs=(P(),) + (data,) * n_sharded,
+            out_specs=data, check_vma=False,
+        )
+
+    def stage(self, payload):
+        """The aligned batch onto the device(s), asynchronously: each
+        chip of a mesh receives its own rows."""
+        if self.mesh is None:
+            return jax.device_put(payload)
+        return jax.device_put(payload, self.shardings()[1])
 
     @property
     def is_classification(self) -> bool:
@@ -359,6 +429,12 @@ class QuantizedScorer:
             return float(self._wire_pack.bytes_per_record)
         return float(self.wire.bytes_per_record)
 
+    def pack_wire(self, Xq):
+        """The adopted wire packing alone (identity without one): for a
+        caller that lays the rows out itself (a keyed mesh dispatch:
+        runtime/shuffle.py ``ShardPlan.place``)."""
+        return Xq if self._wire_pack is None else self._wire_pack.pack(Xq)
+
     def pad_wire(self, Xq):
         """Host-side batch alignment → ``(Xq_padded, K)``.
 
@@ -377,10 +453,13 @@ class QuantizedScorer:
         padding — zero pad rows are packed zero bytes either way), so
         every caller's staged payload and bytes accounting see the
         packed wire without code changes."""
-        if self._wire_pack is not None:
-            Xq = self._wire_pack.pack(Xq)
+        Xq = self.pack_wire(Xq)
         n = Xq.shape[0]
         bs = self.batch_size
+        if self.mesh is not None:
+            # every chip takes the same whole number of chunks (K
+            # counts a chip's), in arrival order
+            bs = (bs or 1) * self.data_width
         if bs is None or n == bs:
             return Xq, 1
         pad = (-n) % bs
@@ -423,7 +502,7 @@ class QuantizedScorer:
         batch argument.  Donating twins are separate compiles of the
         same program (built lazily — callers that never donate never
         pay them)."""
-        if K == 1:
+        if K == 1 and self.mesh is None:
             if not donate:
                 return self._jit_fn
             if self._donate_fn is None:
@@ -457,14 +536,14 @@ class QuantizedScorer:
         """Jitted scan over K fixed-size chunks. Built once per distinct
         (K, donate); callers bound the K set (the block pipeline
         aggregates to powers of two)."""
-        if K == 1:
+        if K == 1 and self.mesh is None:
             return self._entry(1, donate)  # already compiled; no wrapper
         key = (K, donate)
         fn = self._multi_fns.get(key)
         if fn is None:
             inner = getattr(self._jit_fn, "__wrapped__", self._jit_fn)
             fn = jax.jit(
-                self._scan_over(inner, K),
+                self.spmd(inner if K == 1 else self._scan_over(inner, K)),
                 donate_argnums=(1,) if donate else (),
             )
             self._multi_fns[key] = fn
@@ -479,6 +558,8 @@ class QuantizedScorer:
         X = np.ascontiguousarray(X, np.float32)
         n = X.shape[0]
         bs = self.batch_size
+        if self.mesh is not None:
+            bs = (bs or 1) * self.data_width  # as pad_wire
         if bs is None or n == bs:
             return X, 1
         pad = (-n) % bs
@@ -504,7 +585,9 @@ class QuantizedScorer:
                 if K == 1
                 else self._scan_over(self._fused_inner, K)
             )
-            fn = jax.jit(inner, donate_argnums=(1,) if donate else ())
+            fn = jax.jit(
+                self.spmd(inner), donate_argnums=(1,) if donate else ()
+            )
             self._multi_fns[key] = fn
         return fn
 
@@ -537,7 +620,7 @@ class QuantizedScorer:
         from flink_jpmml_tpu.compile import statekernel
 
         fn = statekernel.entry_for(
-            self, "wire", K, donate, table.spec.decay, table.scratch
+            self, "wire", K, donate, table.spec.decay, table.local_scratch
         )
         return fn(self.params, Xq, table.values, slots, rel, w, reset)
 
@@ -548,7 +631,7 @@ class QuantizedScorer:
         from flink_jpmml_tpu.compile import statekernel
 
         fn = statekernel.entry_for(
-            self, "fused", K, donate, table.spec.decay, table.scratch
+            self, "fused", K, donate, table.spec.decay, table.local_scratch
         )
         return fn(self.params, X, table.values, slots, rel, w, reset)
 
@@ -574,6 +657,7 @@ class QuantizedScorer:
         self._jit_fn = jit_fn
         self._fused_inner = fused_inner
         self._multi_fns.clear()
+        self._mesh_twins.clear()
         self._donate_fn = None
 
     def build_variant(self, layout: str = "ref"):

@@ -52,6 +52,17 @@ rows) arrive with ``slot == scratch`` and weight 0: they read the
 scratch row (zeros → derived zeros) and their scatter contributions
 land on the scratch row, which the program zeroes before returning —
 by construction they cannot mutate any key's state.
+
+Over a mesh (a scorer from ``QuantizedScorer.on_mesh``) the same
+``state_fn`` runs under ``shard_map`` on the data axis: forest
+parameters replicated, the wire batch, the table and the four routing
+operands sharded on their leading axis. A chip runs the forest and
+``_state_step`` on its own piece of the table and its own records, with
+LOCAL rows and a scratch row of its own (``KeyedStateTable.locate``;
+the host sorts a dispatch by owner first, runtime/shuffle.py). No
+collective is in the program and no chip sees another chip's rows;
+donation and the in-place whole-row scatters hold shard by shard
+(tests/test_v5e_compile.py).
 """
 
 from __future__ import annotations
@@ -59,6 +70,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from flink_jpmml_tpu.compile import common
 from flink_jpmml_tpu.runtime.state import (
     COL_COUNT,
     COL_DCOUNT,
@@ -168,7 +180,10 @@ def entry_for(q, kind: str, K: int, donate: bool,
     do — "wire" wraps the host-encoded kernel, "fused" the
     encode+score program — and ``K`` scan-chunks it for the Pallas
     fixed grid. Cached in the scorer's ``_multi_fns`` beside its
-    stateless twins (``adopt_backend`` clears them together)."""
+    stateless twins (``adopt_backend`` clears them together). For a
+    scorer on a mesh, ``K`` counts a chip's chunks, ``scratch`` is the
+    chip's own (``KeyedStateTable.local_scratch``) and the entry is
+    the mesh form (module docstring)."""
     key = ("state", kind, int(K), bool(donate),
            int(scratch), float(decay))
     fn = q._multi_fns.get(key)
@@ -193,8 +208,16 @@ def entry_for(q, kind: str, K: int, donate: bool,
         )
         return out, derived, S2
 
+    shardings = {}
+    if getattr(q, "mesh", None) is not None:
+        # shard_map keeps the function's name, so the module is still
+        # jit_state_fn: device traces are read by it
+        state_fn, (repl, data) = q.spmd(state_fn, 6), q.shardings()
+        shardings = {
+            "in_shardings": (repl,) + (data,) * 6, "out_shardings": data,
+        }
     fn = jax.jit(
-        state_fn, donate_argnums=(1, 2) if donate else ()
+        state_fn, donate_argnums=(1, 2) if donate else (), **shardings
     )
     q._multi_fns[key] = fn
     return fn
@@ -234,15 +257,36 @@ def packed_entry(pack, donate: bool, decay: float, scratch: int,
     return fn
 
 
-_renorm_fn = None
+_renorm_fns = {}
+
+
+def renorm_program(in_place: bool):
+    """The jitted sweep ``S · mul + add`` over rows; ``in_place``
+    donates the table (the output takes its buffer)."""
+    fn = _renorm_fns.get(in_place)
+    if fn is None:
+        fn = _renorm_fns[in_place] = jax.jit(
+            lambda s, m, a: s * m[None, :] + a[None, :],
+            donate_argnums=(0,) if in_place else (),
+        )
+    return fn
 
 
 def renorm(S, mul, add):
     """Epoch renormalization: ``S · mul + add`` broadcast over rows
-    (one rare O(capacity) column op — see KeyedStateTable.maybe_renorm)."""
-    global _renorm_fn
-    if _renorm_fn is None:
-        _renorm_fn = jax.jit(
-            lambda s, m, a: s * m[None, :] + a[None, :]
-        )
-    return _renorm_fn(S, jnp.asarray(mul), jnp.asarray(add))
+    (one rare O(capacity) column op — see KeyedStateTable.maybe_renorm).
+
+    A table in pieces over a mesh is swept IN PLACE (donated, wherever
+    the backend honours donation): a sweep into a new buffer moves
+    every chip's piece, the allocator puts it somewhere else each time,
+    and the fold's per-record loop runs 1–1.4% slower on some addresses
+    than on others, so the chips' pace wandered from run to run and
+    inside a run, stepping at every renorm (PERF.md §6, PR 27). The
+    one-chip table's sweep is left as it was (its two buffers alternate
+    between the same two places; ROADMAP S6)."""
+    in_place = (
+        isinstance(S, jax.Array)
+        and len(S.sharding.device_set) > 1
+        and not common.backend_is_cpu()
+    )
+    return renorm_program(in_place)(S, jnp.asarray(mul), jnp.asarray(add))

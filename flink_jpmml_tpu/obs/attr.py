@@ -50,6 +50,12 @@ Stage semantics (who observes what):
 - ``route``     — keyed state's host routing on a state-armed
                   dispatch: key hashing, ``maybe_renorm``,
                   ``assign_slots``, the pad rows;
+- ``shard``     — the keyed shuffle of a state table over a mesh
+                  (runtime/shuffle.py): owner and rank of each held
+                  record, the cut where a chip's bucket fills
+                  (``cut``), the bucketed operands, the tail moved on,
+                  and in ``dispatch_quantized`` the codes put in bucket
+                  order (two intervals a dispatch);
 - ``h2d``       — host-side staging + async dispatch issue (on the
                   trace its two halves are the child annotations
                   ``fjt.h2d.put`` and ``fjt.h2d.launch``: a long
@@ -61,6 +67,9 @@ Stage semantics (who observes what):
                   sampled distribution, not every batch);
 - ``readback``  — host blocked fetching results (``finish_oldest`` /
                   ``wait``);
+- ``unshard``   — a mesh dispatch's scores fetched and put back in
+                  offset order before the sink (``ShardPlan.unshard``;
+                  the D2H copy a one-chip pipeline's sink pays is here);
 - ``sink``      — sink delivery (block pipelines' ``_complete``);
 - ``commit``    — the checkpoint tick after delivery
                   (``CheckpointManager.maybe_save``);
@@ -106,8 +115,9 @@ from flink_jpmml_tpu.obs import trace as trace_mod
 from flink_jpmml_tpu.utils.metrics import Histogram, MetricsRegistry
 
 STAGES = (
-    "fetch", "decode", "prefetch_wait", "drain", "encode", "route", "h2d",
-    "queue_wait", "device", "readback", "sink", "commit", "prof_sample",
+    "fetch", "decode", "prefetch_wait", "drain", "encode", "route", "shard",
+    "h2d", "queue_wait", "device", "readback", "unshard", "sink", "commit",
+    "prof_sample",
 )
 
 # which thread each stage is observed on — rendered as the fjt-top
@@ -123,10 +133,12 @@ STAGE_THREADS = {
     "drain": "score",
     "encode": "score",
     "route": "score",
+    "shard": "score",
     "h2d": "score",
     "queue_wait": "score",
     "device": "device",
     "readback": "score",
+    "unshard": "score",
     "sink": "score",
     "commit": "score",
     "prof_sample": "score",

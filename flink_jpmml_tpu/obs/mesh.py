@@ -9,8 +9,16 @@ merge rules in utils/metrics.py.
 
 Series (chip = the data-row id from parallel/assignment.ChipAssignment):
 
-- ``mesh_chip_records{chip="*"}`` counter — records scored by the chip
-  (a data-parallel dispatch splits the batch evenly across rows);
+- ``mesh_chip_records{chip="*"}`` counter — records FOLDED ON the chip:
+  under keyed state a record goes to the chip that owns its key's row
+  (runtime/shuffle.py), so the shares follow the keys' skew and
+  ``note_folded`` books each chip's own count; a stateless
+  data-parallel dispatch splits the batch evenly across rows
+  (``note_batch``);
+- ``mesh_bucket_slots`` / ``mesh_bucket_pad_records`` /
+  ``mesh_dispatch_cuts`` counters (runtime/shuffle.py) — bucket rows a
+  keyed dispatch offered the chips (``D·C``), those of them that were
+  padding, and dispatches cut short because one chip's bucket filled;
 - ``mesh_chip_inflight{chip="*"}`` gauge — the in-flight window depth
   the chip is riding (fleet SUM: total outstanding work);
 - ``mesh_chip_state{chip="*"}`` gauge — 0 healthy / 2 lost (fleet
@@ -95,6 +103,14 @@ class MeshTelemetry:
         share = n / width
         for chip in self._live:
             self._rec_counters[chip].inc(share)
+            self._inflight_gauges[chip].set(float(inflight))
+
+    def note_folded(self, counts, inflight: int) -> None:
+        """A keyed dispatch: ``counts[i]`` records were folded on the
+        ``i``-th live chip (data-row order, as the table's ``locate``
+        numbers them)."""
+        for chip, k in zip(self._live, counts):
+            self._rec_counters[chip].inc(int(k))
             self._inflight_gauges[chip].set(float(inflight))
 
     # -- rebuild path ------------------------------------------------------

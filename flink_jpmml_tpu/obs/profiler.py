@@ -584,7 +584,13 @@ _PROFILERS_MU = threading.Lock()
 
 def profiler_for(
     metrics: Optional[MetricsRegistry],
+    interval_s: Optional[float] = None,
 ) -> Optional[DeviceProfiler]:
+    """The registry's one profiler. ``interval_s`` is the sampling
+    interval of a profiler THIS call creates (a deployment's own
+    setting, stated before its pipeline is built: a sample drains the
+    in-flight window, 0.3–0.4 s on four chips); None takes the
+    environment's. A profiler that exists keeps its interval."""
     if metrics is None:
         return None
     prof = _PROFILERS.get(metrics)
@@ -593,6 +599,7 @@ def profiler_for(
             prof = _PROFILERS.get(metrics)
             if prof is None:
                 prof = _PROFILERS[metrics] = DeviceProfiler(
-                    metrics, cost_ledger=_COST_LEDGER
+                    metrics, interval_s=interval_s,
+                    cost_ledger=_COST_LEDGER,
                 )
     return prof

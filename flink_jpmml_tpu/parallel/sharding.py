@@ -124,10 +124,15 @@ class ShardedModel:
         return self.decode(self.predict(X, M), n)
 
     def quantized_scorer(self):
-        """The rank-wire fast path is single-device only for now: a
-        sharded serving plane scores on the f32 path (None here keeps
-        the BlockPipeline fallback contract)."""
-        return None
+        """The base model's rank-wire scorer over this mesh
+        (``QuantizedScorer.on_mesh``): forest parameters replicated,
+        the wire batch sharded on the data axis, each chip running the
+        one-chip kernel on its rows, so scores are the one-chip
+        scorer's bit for bit. None where the base model has none (the
+        BlockPipeline fallback contract: it then scores on the f32
+        path)."""
+        q = self.base.quantized_scorer()
+        return None if q is None else q.on_mesh(self.mesh)
 
     @property
     def field_space(self):

@@ -441,6 +441,11 @@ class BlockPipelineBase:
         # (stashed by _dispatch_checked for the state stage; the score
         # loop is single-threaded by the ring contract)
         self._cur_offsets = None
+        # the current dispatch's shard plan, stashed the same way, and
+        # the keyed shuffle of a state table over a mesh
+        # (runtime/shuffle.py; the subclass arms it)
+        self._cur_plan = None
+        self._shuffle = None
         # -- device-fault resilience (runtime/devfault.py +
         #    serving/failover.py) ------------------------------------------
         # The recovery ladder (redispatch → OOM batch bisection →
@@ -762,6 +767,28 @@ class BlockPipelineBase:
         offsets = np.concatenate(off_parts)
         return X, offsets, total
 
+    def _drain_into_shuffle(self, sh, deadline_us: int, idle_us: int) -> int:
+        """The drain of a pipeline whose state table lies over a mesh:
+        blocks go from the ring into the keyed shuffle
+        (runtime/shuffle.py), which holds them until a dispatch takes
+        them. The first drain waits (fill or deadline) only where the
+        shuffle holds nothing; after it, provably full batches follow
+        while the shuffle has room for one. → records drained."""
+        bs, got, first = self._batch_size, 0, True
+        while True:
+            if first and not sh.pending:
+                X, offsets = self._ring.drain(deadline_us, idle_us)
+            elif sh.room >= bs and (first or len(self._ring) >= bs):
+                X, offsets = self._ring.drain(0, 0)
+            else:
+                break
+            first = False
+            sh.feed(X, offsets)
+            got += X.shape[0]
+            if X.shape[0] < bs:
+                break
+        return got
+
     def _resolve_donate(self) -> bool:
         """Donation default: on unless the backend is CPU. Resolved
         once, lazily — backend identity needs jax initialized.
@@ -821,6 +848,7 @@ class BlockPipelineBase:
                 # the state stage's decay clock + replay guard, and the
                 # dispatch's first_off on its encode/route/h2d spans
                 offsets=self._cur_offsets,
+                plan=self._cur_plan if st is not None else None,
             )
         if self._state is not None and not self._state_bypass:
             raise InputValidationException(
@@ -859,14 +887,16 @@ class BlockPipelineBase:
 
     # -- poison isolation (runtime/dlq.py) ---------------------------------
 
-    def _dispatch_checked(self, handle, X, n, offsets):
+    def _dispatch_checked(self, handle, X, n, offsets, plan=None):
         """The one dispatch entry carrying the batch's offsets past the
         fault harness: ``poison_record`` / offset-targeted
         ``worker_crash`` faults match against exactly the range being
         scored, so bisection isolates an injected poison the same way
-        it isolates a real one."""
+        it isolates a real one. ``plan`` is the dispatch's shard plan
+        where the keyed shuffle made one."""
         faults.fire("score_batch", offsets=offsets)
         self._cur_offsets = offsets  # read by _dispatch_bound
+        self._cur_plan = plan
         return self._dispatch(handle, X, n)
 
     def _on_dispatch_error(self, out, meta, error) -> bool:
@@ -1140,6 +1170,9 @@ class BlockPipelineBase:
         BoundScorer's decode closure follows ``handle.model``, so the
         sink path needs no rebind)."""
         handle.model = rebuilt
+        if getattr(handle, "q", None) is not None:
+            # the rank-wire scorer spans the mesh it was built on
+            handle.q = rebuilt.quantized_scorer()
         if self._state is not None:
             # chip loss moves state WITH its keys: slot = hash %
             # capacity is mesh-independent, so re-placing the value
@@ -1571,6 +1604,14 @@ class BlockPipelineBase:
                 # plane under the model's "#state" label (state
                 # corruption surfaces as feature drift)
                 out, derived = state_mod.split_output(out)
+            plan = meta[8] if len(meta) > 8 else None
+            if plan is not None:
+                # a mesh dispatch comes back sorted by owning chip:
+                # back into offset order, on the host, before the sink
+                with ledger.span("unshard", first_off=first_off, n=n):
+                    out = plan.unshard(out)
+                    if dplane is not None and derived is not None:
+                        derived = plan.unshard(derived)
             # the completing batch's OWN context wraps the sink: its
             # span (and any exemplar the sink stage captures) must
             # carry THIS journey's ids, not whichever batch the score
@@ -1612,7 +1653,10 @@ class BlockPipelineBase:
                 # per-chip accounting (obs/mesh.py): one call per BATCH
                 # — a data-parallel dispatch spans every chip equally,
                 # so the split is arithmetic, not a per-record loop
-                self._mesh_obs.note_batch(n, len(disp))
+                if plan is not None:
+                    self._mesh_obs.note_folded(plan.counts, len(disp))
+                else:
+                    self._mesh_obs.note_batch(n, len(disp))
             if self._failover is not None:
                 # green completion: clears strike streaks / counts a
                 # half-open probe (a dict miss while no breaker exists)
@@ -1679,26 +1723,37 @@ class BlockPipelineBase:
                     )
                 # drain ends before the batch's offsets are known: its
                 # span carries n alone
+                sh, plan = self._shuffle, None
                 with ledger.span("drain") as sp:
-                    if self._carry_drain:
+                    if sh is not None:
+                        n = self._drain_into_shuffle(
+                            sh, batch_cfg.deadline_us, idle_us
+                        )
+                    elif self._carry_drain:
                         X, offsets = self._carry_drain.pop(0)
                     else:
                         X, offsets = self._ring.drain(
                             batch_cfg.deadline_us, idle_us
                         )
-                    n = X.shape[0]
+                    if sh is None:
+                        n = X.shape[0]
                     # ring fill fraction AFTER the drain: the
                     # producer-side saturation input to the pressure
                     # score (1.0 = the ingest thread is blocked pushing)
                     ring_occ.set(min(len(self._ring) / ring_cap, 1.0))
                     if (
-                        n == self._batch_size  # drain limit = model batch
+                        sh is None
+                        and n == self._batch_size  # drain limit = model batch
                         and self._max_dispatch_chunks > 1
                     ):
                         X, offsets, n = self._aggregate_full_batches(
                             X, offsets, self._batch_size
                         )
                     sp.note(n=n)
+                if sh is not None:
+                    # route what was drained, and take the longest
+                    # prefix of what is held that the buckets can take
+                    X, offsets, n, plan = sh.take(ledger)
                 if n == 0:
                     if self._ring.closed:
                         break
@@ -1738,6 +1793,8 @@ class BlockPipelineBase:
                         # entry is UNACCOUNTED (no device work — it
                         # must not dilute the dispatch counters the
                         # pressure score divides by)
+                        if sh is not None:
+                            sh.abandon(plan)  # routed, never folded
                         if jstore is not None and n:
                             # the shed decision IS the journey's point:
                             # terminal hop, always kept
@@ -1787,6 +1844,8 @@ class BlockPipelineBase:
                     # Flush first: the marker protocol and the FIFO
                     # commit contract both need nothing else in flight.
                     disp.flush()
+                    if sh is not None:
+                        sh.abandon(plan)  # the scan scores statelessly
                     self._suspect_scan(
                         handle, X, offsets, error=None, persist=True,
                         ctx=(
@@ -1810,6 +1869,8 @@ class BlockPipelineBase:
                     # serves synchronously on the host fallback tier —
                     # degraded, not down
                     disp.flush()
+                    if sh is not None:
+                        sh.abandon(plan)  # the host tier folds nothing
                     self._serve_fallback(
                         handle, X, offsets,
                         jctx=(
@@ -1862,15 +1923,15 @@ class BlockPipelineBase:
                 try:
                     with trace_mod.use(jctx):
                         disp.launch(
-                            lambda h=handle, X=X, n=n, o=offsets: (
-                                self._dispatch_checked(h, X, n, o)
+                            lambda h=handle, X=X, n=n, o=offsets, p=plan: (
+                                self._dispatch_checked(h, X, n, o, plan=p)
                             ),
                             meta=(
                                 n, first_off, t_start, False,
                                 handle,
                                 X if self._retain_batches else None,
                                 offsets if self._retain_batches else None,
-                                jctx,
+                                jctx, plan,
                             ),
                             ident={"first_off": first_off, "n": n},
                             # opts this launch into the sampled
@@ -1992,8 +2053,12 @@ class BlockPipeline(BlockPipelineBase):
             # mesh-aware in-flight window: deep enough to cover the
             # data rows (parallel/assignment.mesh_in_flight), recorded
             # as carried dispatch state so a degraded-mesh rebuild
-            # keeps the window geometry without re-derivation
-            in_flight = model.in_flight_depth(in_flight)
+            # keeps the window geometry without re-derivation. A
+            # state-armed pipeline keeps the depth it was given: its
+            # dispatches chain on the one donated table, one at a time
+            # on every chip, so a deeper window buys no overlap
+            if state is None:
+                in_flight = model.in_flight_depth(in_flight)
             model.with_dispatch_state(in_flight=in_flight)
             if getattr(model, "assignment", None) is None:
                 from flink_jpmml_tpu.parallel.assignment import (
@@ -2003,6 +2068,16 @@ class BlockPipeline(BlockPipelineBase):
                 model.assignment = assignment_for(
                     model.mesh, getattr(source, "partitions", ()) or ()
                 )
+        if (
+            isinstance(state, state_mod.StateSpec)
+            and getattr(model, "mesh", None) is not None
+        ):
+            # born on the chips: zeros allocated shard by shard, no
+            # table-sized host array on the way (runtime/state.py)
+            metrics = metrics if metrics is not None else MetricsRegistry()
+            state = state_mod.KeyedStateTable(
+                state, metrics=metrics, mesh=model.mesh
+            )
         super().__init__(
             source=source,
             sink=sink,
@@ -2036,13 +2111,35 @@ class BlockPipeline(BlockPipelineBase):
                 )
             model_mesh = getattr(model, "mesh", None)
             if model_mesh is not None:
-                # shard the table over the mesh data axis alongside
-                # the model it rides with
+                # the table over the mesh data axis alongside the model
+                # it rides with (nothing to do for one built with
+                # ``mesh=``: it was born there)
                 self._state.shard(model_mesh)
+            if self._state.n_shards > 1:
+                # the keyed shuffle: a chip's bucket is a power of two
+                # of the model's chunks, all buckets of a dispatch
+                # together at most max_dispatch_chunks of them
+                from flink_jpmml_tpu.runtime.shuffle import KeyShuffle
+
+                most = max(1, max_dispatch_chunks // self._state.n_shards)
+                self._shuffle = KeyShuffle(
+                    self._state, model.field_space.arity, model.batch_size,
+                    [1 << i for i in range(most.bit_length())],
+                    max(1, max_dispatch_chunks) * model.batch_size,
+                    self.metrics,
+                )
         if hasattr(model, "batch_divisor"):
             from flink_jpmml_tpu.obs import mesh as mesh_obs
 
             self._mesh_obs = mesh_obs.telemetry_for(self.metrics, model)
+
+    @property
+    def bucket_chunks(self) -> tuple:
+        """The chunk counts a chip's bucket of a keyed mesh dispatch
+        may have (runtime/shuffle.py), ascending: with the model's
+        batch size, every device shape the state-armed program runs
+        at. Empty where the state table lies on one chip."""
+        return () if self._shuffle is None else self._shuffle.sizes
 
     def decode(self, out, n: int):
         """Sink-received raw output → ``Prediction`` list (host-side).

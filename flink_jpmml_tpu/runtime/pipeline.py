@@ -168,6 +168,7 @@ def dispatch_quantized(
     state=None,
     state_keys=None,
     offsets=None,
+    plan=None,
 ):
     """Featurize + stage + async-dispatch one raw f32 batch through a
     :class:`~flink_jpmml_tpu.compile.qtrees.QuantizedScorer` — the ONE
@@ -205,7 +206,21 @@ def dispatch_quantized(
     column of ``X``); ``offsets`` are the records' ring offsets —
     the decay clock and the exactly-once replay guard, and, state or
     none, the ``first_off`` this dispatch's ``encode``/``route``/``h2d``
-    spans carry (obs/attr.py). Unarmed cost is one ``is None`` check."""
+    spans carry (obs/attr.py). Unarmed cost is one ``is None`` check.
+
+    A table over a mesh (``state.n_shards > 1``) is folded by the mesh
+    form of the program: the records go out sorted by owning chip
+    (runtime/shuffle.py; span ``shard``). A pipeline routes and cuts
+    its dispatch beforehand and hands the ``plan``
+    (``KeyShuffle.take``): scores and derived rows then come back in
+    bucket order, for ``plan.unshard`` at readback. Without one, all of
+    ``X`` is routed and bucketed here, in as many chunks a chip as the
+    fullest needs, and what is returned is in offset order, ``n``
+    long."""
+    if state is not None and q.mesh is not state.mesh:
+        # a caller that holds the one-chip scorer (a pipeline binds the
+        # twin that spans the table's mesh when it is built)
+        q = q.on_mesh(state.mesh)
     enc, h2d = (
         _wire_counters(metrics) if metrics is not None else (None, None)
     )
@@ -228,6 +243,9 @@ def dispatch_quantized(
     if dplane is not None:
         dplane.record_features(q, X, M)
     fused = getattr(q, "encode_mode", "host") == "fused" and q.supports_fused
+    # a planned dispatch's rows are laid out by the plan (bucket order,
+    # pad rows and all): no alignment padding before it
+    planned = plan is not None
     # encode covers featurize+align
     with ledger.span(
         "encode", fused=fused, layout=getattr(q, "layout", "ref"), **ident
@@ -237,8 +255,11 @@ def dispatch_quantized(
             if M is not None and np.asarray(M).any():
                 X = np.where(M, np.nan, np.asarray(X, np.float32))
                 owned = True
-            payload, K = q.pad_f32(X)
-            if payload is X and not owned:
+            payload, K = (
+                (np.ascontiguousarray(X, np.float32), 1) if planned
+                else q.pad_f32(X)
+            )
+            if payload is X and not owned and not planned:
                 # an unpadded f32-contiguous batch passes through
                 # pad_f32 unchanged, and the caller's array may alias a
                 # REUSED ring drain buffer — which jax's CPU backend can
@@ -256,40 +277,64 @@ def dispatch_quantized(
             # WirePack) when the kernel search chose one, so the staged
             # payload, h2d_bytes, and the donation accounting all see
             # the packed wire without any per-call-site knowledge
-            payload, K = q.pad_wire(q.wire.encode(X, M))
+            Xq = q.wire.encode(X, M)
+            payload, K = (
+                (q.pack_wire(Xq), 1) if planned else q.pad_wire(Xq)
+            )
             predict = q.predict_padded
     if enc is not None:
         enc.inc(sp.seconds)
-    if h2d is not None:
-        h2d.inc(payload.nbytes)
     st_args = None
+    sharded = unplanned = False
     if state is not None:
-        # keyed state routing (host-side slot assignment; the state
-        # gather/update itself is traced into the dispatch below) —
-        # one vectorized pass per batch, zero per-record host work
-        with ledger.span("route", **ident):
-            khash = (
-                np.asarray(state_keys, np.uint32)
-                if state_keys is not None
-                else state.hash_keys(state.extract_keys(X))
-            )
-            state.maybe_renorm(ident.get("first_off", state.applied_hi))
-            slots, reset, rel, w = state.assign_slots(khash, offs)
-            pad = payload.shape[0] - ident["n"]
-            if pad > 0:
-                # alignment rows ride the scratch slot with zero weight
-                # — by construction they cannot touch any key's state
-                slots = np.concatenate(
-                    [slots, np.full(pad, state.scratch, np.int32)]
+        sharded, unplanned = state.n_shards > 1, plan is None
+        if unplanned:
+            # keyed state routing (host-side slot assignment; the state
+            # gather/update itself is traced into the dispatch below) —
+            # one vectorized pass per batch, zero per-record host work
+            with ledger.span("route", **ident):
+                khash = (
+                    np.asarray(state_keys, np.uint32)
+                    if state_keys is not None
+                    else state.hash_keys(state.extract_keys(X))
                 )
-                reset = np.concatenate([reset, np.zeros(pad, bool)])
-                rel = np.concatenate([rel, np.zeros(pad, np.float32)])
-                w = np.concatenate([w, np.zeros(pad, np.float32)])
-            st_args = (slots, rel, w, reset)
+                state.maybe_renorm(ident.get("first_off", state.applied_hi))
+                slots, reset, rel, w = state.assign_slots(khash, offs)
+                pad = payload.shape[0] - ident["n"]
+                if pad > 0 and not sharded:
+                    # alignment rows ride the scratch slot with zero
+                    # weight — by construction they cannot touch any
+                    # key's state
+                    slots = np.concatenate(
+                        [slots, np.full(pad, state.scratch, np.int32)]
+                    )
+                    reset = np.concatenate([reset, np.zeros(pad, bool)])
+                    rel = np.concatenate([rel, np.zeros(pad, np.float32)])
+                    w = np.concatenate([w, np.zeros(pad, np.float32)])
+                st_args = (slots, rel, w, reset)
+        if sharded:
+            # the table lies over a mesh: records, codes and routing
+            # operands go out sorted by owning chip (runtime/shuffle.py)
+            with ledger.span("shard", cut=bool(plan and plan.cut), **ident):
+                if unplanned:
+                    from flink_jpmml_tpu.runtime import shuffle
+
+                    plan = shuffle.make_plan(
+                        state, slots, reset, rel, w, q.batch_size or 256,
+                        first_off=ident.get("first_off", 0),
+                    )
+                elif plan.applied_hi is not None:
+                    state.mark_applied(plan.applied_hi)
+                # the codes in bucket order; K counts a chip's chunks
+                payload = plan.place(payload)
+                K = plan.chunks if q.backend == "pallas" else 1
+                st_args = (plan.slots, plan.rel, plan.w, plan.reset)
         predict_state = (
             q.predict_fused_padded_state if fused
             else q.predict_padded_state
         )
+    if h2d is not None:
+        h2d.inc(payload.nbytes)
     # h2d: the host-side staging + async dispatch issue, and nothing
     # else; its two halves are children on the profiler's clock only
     staged = None
@@ -311,8 +356,8 @@ def dispatch_quantized(
                         rf"uint(?:8|16)\[\d+,{payload.shape[1]}\]"
                     )
             with attr_mod.trace_only("h2d.put"):
-                # async H2D staging copy
-                payload = staged = jax.device_put(payload)
+                # async H2D staging copy (a mesh: each chip its rows)
+                payload = staged = q.stage(payload)
         kw = {"donate": True} if donate else {}
         with attr_mod.trace_only("h2d.launch"):
             if st_args is None:
@@ -322,6 +367,11 @@ def dispatch_quantized(
                     payload, K, state, *st_args, **kw
                 )
                 state.commit(S2)
+                if sharded and unplanned:
+                    # nobody downstream holds the plan: offset order here
+                    out, derived = jax.tree_util.tree_map(
+                        lambda a: a[plan.dest], (out, derived)
+                    )
                 out = (out, derived)
     if staged is not None and donation_hits is not None:
         deleted = getattr(staged, "is_deleted", None)

@@ -64,10 +64,25 @@ state buffer poisons the buffer, and ``rollback()`` restores the
 snapshot (bounded, counted loss — ``state_rollbacks``) so the ladder
 can keep serving statelessly.
 
-Sharding: rows are padded to a multiple of 256 and the buffer shards
-over the mesh data axis (``NamedSharding``). Slot = hash % capacity
-never changes, so a degraded-mesh rebuild (``migrate``) only re-places
-rows across the survivors — every key keeps its slot and its state.
+Sharding: over a mesh the table is ONE table in ``D`` pieces, ``D``
+the width of the mesh's data axis. A key's slot is what it always was
+(probing from ``hash % capacity`` over the GLOBAL capacity), and chip
+``d`` owns the global slots ``[d·R, (d+1)·R)``, ``R = ⌈capacity/D⌉``.
+Each chip's piece of the buffer is ``shard_rows`` rows: its ``R`` slots,
+then a scratch row of its own (local row ``R``: the pad rows and the
+bypassed records of whatever the chip is handed land there), then zero
+rows up to a multiple of 256. ``locate`` is that rule, global slot →
+``(chip, local row)``, and the one place it is written down; with
+``D = 1`` it is the identity and the buffer is the one-chip buffer. The
+fold of a mesh runs under ``shard_map`` (compile/statekernel.py): a
+chip sees its own piece and the local rows of its own records, which
+the host sorts by owner before the dispatch (runtime/shuffle.py). A
+table built with ``mesh=`` is born on the chips, zeros allocated per
+shard and an empty rollback point: no table-sized host array on the
+way. A degraded-mesh rebuild (``migrate``) changes ``D`` and therefore
+the pieces, never a key's slot: rows move with their keys. Snapshots
+hold the values slot-major, as a one-chip table lays them out, so one
+taken at any ``D`` restores at any other.
 """
 
 from __future__ import annotations
@@ -107,9 +122,8 @@ DERIVED_FIELDS = (
     "state_decayed_mean", "state_gap", "state_min", "state_max",
 )
 
-# sharding-friendly row padding: rows % 256 == 0 keeps the buffer
-# divisible by any data-axis width the meshes use (and any degraded
-# rebuild of them), so migrate() never has to reshape
+# row padding of the buffer, and of each chip's piece of it: whole
+# (8, 128) tiles of the chip's layout, whatever the capacity
 _ROW_PAD = 256
 
 _SNAPSHOT_VERSION = 1
@@ -193,11 +207,13 @@ class KeyedStateTable:
     single-threaded by the same contract as the ring."""
 
     def __init__(self, spec: StateSpec,
-                 metrics: Optional[MetricsRegistry] = None):
+                 metrics: Optional[MetricsRegistry] = None,
+                 mesh=None):
         self.spec = spec
         self.capacity = int(spec.capacity)
-        self.rows = -(-(self.capacity + 1) // _ROW_PAD) * _ROW_PAD
         self.scratch = self.capacity  # the bypass/padding slot
+        self._mesh = None
+        self._set_layout(1)
         # renorm trigger: keep λ^rel comfortably inside f32 —
         # exp(30) ≈ 1e13 of headroom against ~1e38
         self._renorm_every = max(
@@ -226,14 +242,82 @@ class KeyedStateTable:
         self.applied_hi = 0     # exactly-once high-water (offsets)
         self.skip_until = 0     # restore sets: replayed offsets below
         # bypass the table (their updates already applied pre-crash)
-        # device values (numpy until first dispatch / shard())
-        self.values = np.zeros((self.rows, STATE_WIDTH), np.float32)
-        self._mesh = None
+        # bumped whenever routing done earlier stops being true of the
+        # table (rollback, restore, a new layout): runtime/shuffle.py
+        # re-routes what it holds
+        self.generation = 0
+        # slots claimed for records routed and not yet dispatched
+        # (runtime/shuffle.py): a snapshot leaves them unclaimed
+        self._unsettled = None
         self._bypass_depth = 0
-        # in-memory rollback point (init = empty table)
-        self._snap: Dict[str, Any] = self._host_snapshot()
+        if mesh is not None and _data_width(mesh) > 1:
+            # born on the chips: zeros allocated per shard, and the
+            # rollback point is "empty" (None), not a copy
+            self._mesh = mesh
+            self._set_layout(_data_width(mesh))
+            self.values = self._zeros_on_mesh()
+            self._snap: Optional[Dict[str, Any]] = None
+        else:
+            # device values (numpy until first dispatch / shard())
+            self.values = np.zeros((self.rows, STATE_WIDTH), np.float32)
+            # in-memory rollback point (init = empty table)
+            self._snap = self._host_snapshot()
+            self.shard(mesh)
         # drift shims per model label (one handle set per model+table)
         self._shims: Dict[str, Any] = {}
+
+    # -- layout ------------------------------------------------------------
+
+    def _set_layout(self, n_shards: int) -> None:
+        """One table in ``n_shards`` pieces (module docstring)."""
+        self.n_shards = int(n_shards)
+        self.shard_slots = -(-self.capacity // self.n_shards)
+        self.shard_rows = _padded_rows(self.shard_slots)
+        # a chip's own scratch row, as the fold on that chip knows it
+        self.local_scratch = self.shard_slots
+        self.rows = self.n_shards * self.shard_rows
+
+    @property
+    def mesh(self):
+        """The mesh the value buffer is placed on (None: one device)."""
+        return self._mesh
+
+    def locate(self, slots):
+        """Global slot → ``(chip, local row)``: chip ``d`` owns the
+        slots ``[d·R, (d+1)·R)``, ``R = shard_slots``. The scratch slot
+        belongs to no chip (whoever is handed the record uses its own):
+        it comes back as chip 0, local row ``local_scratch``. With one
+        shard this is the identity."""
+        s = np.asarray(slots, np.int64)
+        chip = s // self.shard_slots
+        row = s - chip * self.shard_slots
+        pad = s >= self.capacity
+        if pad.any():
+            chip = np.where(pad, 0, chip)
+            row = np.where(pad, self.local_scratch, row)
+        return chip.astype(np.int32), row.astype(np.int32)
+
+    def read_rows(self, slots) -> np.ndarray:
+        """The value rows of global ``slots`` on the host, through
+        ``locate`` (blocks on in-flight updates)."""
+        return self.read_local(*self.locate(slots))
+
+    def read_local(self, chip, row) -> np.ndarray:
+        """The value rows at ``(chip, local row)`` on the host, each
+        read out of that chip's own piece of the buffer: no chip is
+        asked for another's rows, and nothing table-sized moves."""
+        chip, row = np.asarray(chip), np.asarray(row)
+        if self.n_shards == 1:
+            return np.asarray(self.values[row])
+        out = np.zeros((chip.shape[0], STATE_WIDTH), np.float32)
+        seen = set()
+        for piece in self.values.addressable_shards:
+            d = (piece.index[0].start or 0) // self.shard_rows
+            mine = np.flatnonzero(chip == d)
+            if d not in seen and mine.size:  # model-axis replicas agree
+                out[mine] = np.asarray(piece.data[row[mine]])
+            seen.add(d)
+        return out
 
     # -- bypass ------------------------------------------------------------
 
@@ -283,24 +367,67 @@ class KeyedStateTable:
         vectorized — the only per-batch routing cost).
 
         → ``(slots int32[B], reset bool[B], rel f32[B], w f32[B])``:
-        ``slots`` are value-buffer rows (``scratch`` for bypassed
-        records), ``reset`` marks slots whose key is fresh this batch
-        (the kernel re-initializes them before the gather), ``rel`` is
-        the record's decay stride relative to the epoch and ``w`` its
-        product-form weight λ^-rel. Replayed offsets below
-        ``skip_until`` bypass (exactly-once state)."""
+        ``slots`` are GLOBAL slots (``locate`` says where one lives;
+        ``scratch`` for bypassed records), ``reset`` marks slots whose
+        key is fresh this batch (the kernel re-initializes them before
+        the gather), ``rel`` is the record's decay stride relative to
+        the epoch and ``w`` its product-form weight λ^-rel. Replayed
+        offsets below ``skip_until`` bypass (exactly-once state).
+
+        Its three steps are public one by one for a caller that routes
+        records before it knows which dispatch will fold them
+        (runtime/shuffle.py): ``route``, ``mark_applied``,
+        ``decay_operands``."""
+        if offsets is None:
+            B = np.asarray(khash).shape[0]
+            offsets = np.arange(self.applied_hi, self.applied_hi + B,
+                                dtype=np.int64)
+        slots, reset, apply = self.route(khash, offsets)
+        if apply.any():
+            self.mark_applied(int(np.asarray(offsets)[apply].max()) + 1)
+        rel, w = self.decay_operands(offsets, apply)
+        return slots, reset, rel, w
+
+    def mark_applied(self, hi: int) -> None:
+        """Offsets below ``hi`` are folded (or riding a dispatch): the
+        exactly-once high-water a snapshot records."""
+        if hi > self.applied_hi:
+            self.applied_hi = int(hi)
+
+    def decay_operands(self, offsets, apply):
+        """→ ``(rel f32[B], w f32[B])`` of records at ``offsets``
+        against the epoch as it stands: the decay stride relative to it
+        and the product-form weight λ^-rel, both 0 where ``apply`` is
+        False."""
+        offs = np.asarray(offsets, np.int64)
+        rel_t = (offs // self.spec.stride) - self.epoch
+        rel = np.where(apply, rel_t, 0).astype(np.float32)
+        w = np.power(
+            np.float32(self.spec.decay), -rel, dtype=np.float32
+        )
+        w = np.where(apply, w, np.float32(0.0)).astype(np.float32)
+        return rel, w
+
+    def route(self, khash: np.ndarray, offsets, held=None):
+        """The slot resolution of ``assign_slots`` alone → ``(slots,
+        reset, apply)``; ``apply`` is False for replayed offsets, which
+        bypass. Claims and evicts in the host mirror and counts, and
+        leaves ``applied_hi`` and the decay clock alone.
+
+        ``held`` are the slots of records an earlier call routed and no
+        dispatch has taken yet: they count as touched by this call, so
+        no eviction of it hands one to another key (the held record
+        would fold into that key's fresh row)."""
         khash = np.asarray(khash, np.uint32)
         B = khash.shape[0]
         self._seq += 1
         seq = self._seq
-        if offsets is None:
-            offs = np.arange(self.applied_hi, self.applied_hi + B,
-                             dtype=np.int64)
-        else:
-            offs = np.asarray(offsets, np.int64)
+        if held is not None and len(held):
+            hs = np.asarray(held, np.int64)
+            self._touch[hs[hs < self.capacity]] = seq
+        offs = np.asarray(offsets, np.int64)
         apply = offs >= self.skip_until
         n_bypass = int(B - apply.sum())
-        rel_t = (offs // self.spec.stride) - self.epoch
         slots = np.full(B, self.scratch, np.int32)
         reset = np.zeros(B, bool)
         if apply.any():
@@ -377,9 +504,6 @@ class KeyedStateTable:
             )
             self._c_hits.inc(hits)
             self._c_collisions.inc(collided)
-            hi = int(offs[apply].max()) + 1
-            if hi > self.applied_hi:
-                self.applied_hi = hi
         self._c_records.inc(B)
         if n_bypass:
             self._c_bypass.inc(n_bypass)
@@ -389,12 +513,31 @@ class KeyedStateTable:
         self._g_hit_ratio.set(
             self._c_hits.value / rec if rec else 0.0
         )
-        rel = np.where(apply, rel_t, 0).astype(np.float32)
-        w = np.power(
-            np.float32(self.spec.decay), -rel, dtype=np.float32
+        return slots, reset, apply
+
+    def unclaim(self, slots) -> None:
+        """Give back slots claimed by ``route`` for records that will
+        never be folded (shed, served on a fallback tier): the reset
+        that rode with them is lost, so the next record of such a key
+        must claim again. A row nobody owns is re-initialized by
+        whoever claims it next, so what was folded into it meanwhile
+        cannot reach a key."""
+        s = np.unique(np.asarray(slots, np.int64))
+        s = s[s < self.capacity]
+        s = s[self._occ[s]]
+        self._occ[s] = False
+        self.resident -= int(s.size)
+        self._g_resident.set(float(self.resident))
+        self._g_occupancy.set(self.resident / float(self.capacity))
+
+    def hold_claims(self, slots) -> None:
+        """Slots claimed for records routed but not yet dispatched
+        (None: there are none). A snapshot taken meanwhile leaves them
+        unclaimed: their rows are not reset yet."""
+        self._unsettled = (
+            None if slots is None or not len(slots)
+            else np.unique(np.asarray(slots, np.int64))
         )
-        w = np.where(apply, w, np.float32(0.0)).astype(np.float32)
-        return slots, reset, rel, w
 
     def maybe_renorm(self, first_off: int) -> None:
         """Advance the decay epoch when the product-form exponents
@@ -433,16 +576,24 @@ class KeyedStateTable:
         subsequent records re-enter cleanly)."""
         self._c_rollbacks.inc()
         snap = self._snap
-        self._keys = snap["keys"].copy()
-        self._occ = snap["occ"].copy()
-        self._touch = snap["touch"].copy()
-        self.resident = int(snap["resident"])
-        self.epoch = int(snap["epoch"])
-        self.applied_hi = int(snap["applied_hi"])
+        self.generation += 1
+        self._unsettled = None
+        if snap is None:
+            # a table born on its mesh and never snapshotted: empty
+            self._keys.fill(0)
+            self._occ.fill(False)
+            self._touch.fill(0)
+            self.resident = self.epoch = self.applied_hi = 0
+            self.values = self._zeros_on_mesh()
+        else:
+            self._keys = snap["keys"].copy()
+            self._occ = snap["occ"].copy()
+            self._touch = snap["touch"].copy()
+            self.resident = int(snap["resident"])
+            self.epoch = int(snap["epoch"])
+            self.applied_hi = int(snap["applied_hi"])
+            self._place(snap["values"])
         self.skip_until = max(self.skip_until, self.applied_hi)
-        self.values = snap["values"].copy()
-        if self._mesh is not None:
-            self.shard(self._mesh)
         flight.record(
             "state_rollback", applied_hi=self.applied_hi,
             resident=self.resident,
@@ -451,60 +602,115 @@ class KeyedStateTable:
     # -- sharding / migration ---------------------------------------------
 
     def shard(self, mesh) -> None:
-        """Place the value buffer sharded over the mesh data axis (rows
-        are padded to a multiple of 256, so any data width divides)."""
+        """Place the table over ``mesh``: ``D`` pieces, ``D`` the width
+        of its data axis (module docstring). Nothing to do where it
+        lies there already; else the rows go through the host, which a
+        table born on its mesh (``mesh=``) never pays."""
         if mesh is None:
             return
-        import jax
-        from jax.sharding import NamedSharding, PartitionSpec as P
-
-        from flink_jpmml_tpu.parallel.mesh import DATA_AXIS
-
+        if mesh == self._mesh and not isinstance(self.values, np.ndarray):
+            return
+        host = self._slot_major()
         self._mesh = mesh
-        self.values = jax.device_put(
-            np.asarray(self.values),
-            NamedSharding(mesh, P(DATA_AXIS, None)),
-        )
+        if _data_width(mesh) != self.n_shards:
+            self._set_layout(_data_width(mesh))
+            self.generation += 1
+        self._place(host)
 
     def migrate(self, new_mesh) -> None:
         """Degraded-rebuild hook: re-place every row across the
         surviving chips. Slot = hash % capacity is mesh-independent,
         so chip loss moves state WITH its keys — no key loses its
-        state vector (pinned in tests)."""
+        state vector (pinned in tests); which chip owns a slot follows
+        the new width (``locate``)."""
         if new_mesh is None:
             return
-        host = np.asarray(self.values)
         self.shard(new_mesh)
-        # force the re-placement from the host copy (shard() re-placed
-        # self.values, which may still reference lost devices)
+        flight.record(
+            "state_migrate",
+            data=_data_width(new_mesh),
+            resident=self.resident,
+        )
+
+    def _zeros_on_mesh(self):
+        """An empty value buffer, allocated on the mesh shard by
+        shard."""
+        import jax
+        import jax.numpy as jnp
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        from flink_jpmml_tpu.parallel.mesh import DATA_AXIS
+
+        return jax.jit(
+            lambda: jnp.zeros((self.rows, STATE_WIDTH), jnp.float32),
+            out_shardings=NamedSharding(self._mesh, P(DATA_AXIS, None)),
+        )()
+
+    def _slot_major(self) -> np.ndarray:
+        """The values on the host as a one-chip table lays them out:
+        slot ``s`` in row ``s``, zeros from the scratch row on."""
+        v = np.asarray(self.values)
+        if self.n_shards == 1:
+            return v
+        D, R = self.n_shards, self.shard_slots
+        out = np.zeros(
+            (_padded_rows(self.capacity), STATE_WIDTH), np.float32
+        )
+        out[: self.capacity] = v.reshape(D, self.shard_rows, STATE_WIDTH)[
+            :, :R
+        ].reshape(D * R, STATE_WIDTH)[: self.capacity]
+        return out
+
+    def _place(self, host: np.ndarray) -> None:
+        """Adopt slot-major host values (``_slot_major``'s form, any
+        row padding) under the layout and the mesh the table has."""
+        if self._mesh is None:
+            self.values = np.array(host, np.float32)
+            return
         import jax
         from jax.sharding import NamedSharding, PartitionSpec as P
 
         from flink_jpmml_tpu.parallel.mesh import DATA_AXIS
 
-        self.values = jax.device_put(
-            host, NamedSharding(new_mesh, P(DATA_AXIS, None))
-        )
-        flight.record(
-            "state_migrate",
-            data=int(new_mesh.shape.get(DATA_AXIS, 1)),
-            resident=self.resident,
+        sharding = NamedSharding(self._mesh, P(DATA_AXIS, None))
+        R, Rl = self.shard_slots, self.shard_rows
+        cap = min(self.capacity, host.shape[0])
+
+        def piece(idx):
+            d = (idx[0].start or 0) // Rl
+            out = np.zeros((Rl, STATE_WIDTH), np.float32)
+            lo, hi = d * R, min((d + 1) * R, cap)
+            if hi > lo:
+                out[: hi - lo] = host[lo:hi]
+            return out[:, idx[1]]
+
+        self.values = jax.make_array_from_callback(
+            (self.rows, STATE_WIDTH), sharding, piece
         )
 
     # -- snapshots ---------------------------------------------------------
 
     def _host_snapshot(self) -> Dict[str, Any]:
+        occ, resident = self._occ.copy(), self.resident
+        if self._unsettled is not None:
+            # claimed for records no dispatch has folded yet: their
+            # rows still hold what was there before (hold_claims)
+            held = self._unsettled[occ[self._unsettled]]
+            occ[held] = False
+            resident -= int(held.size)
+        values = self._slot_major()
         return {
             "version": _SNAPSHOT_VERSION,
             "capacity": self.capacity,
             "keys": self._keys.copy(),
-            "occ": self._occ.copy(),
+            "occ": occ,
             "touch": self._touch.copy(),
-            "resident": self.resident,
+            "resident": resident,
             "epoch": self.epoch,
             "applied_hi": self.applied_hi,
             "seq": self._seq,
-            "values": np.asarray(self.values).copy(),
+            # slot-major, whatever the layout: restores at any width
+            "values": values.copy() if self.n_shards == 1 else values,
         }
 
     def snapshot(self) -> Dict[str, Any]:
@@ -618,18 +824,20 @@ class KeyedStateTable:
         # exactly-once: replayed offsets below the snapshot's
         # high-water were already folded in — bypass them
         self.skip_until = self.applied_hi
-        self.values = snap["values"].astype(np.float32)
-        if self.values.shape != (self.rows, STATE_WIDTH):
+        self.generation += 1
+        self._unsettled = None
+        values = snap["values"].astype(np.float32)
+        rows1 = _padded_rows(self.capacity)
+        if values.shape != (rows1, STATE_WIDTH):
             # snapshot from a different row padding: re-pad
-            v = np.zeros((self.rows, STATE_WIDTH), np.float32)
-            n = min(self.values.shape[0], self.rows)
-            v[:n] = self.values[:n]
-            self.values = v
+            v = np.zeros((rows1, STATE_WIDTH), np.float32)
+            n = min(values.shape[0], rows1)
+            v[:n] = values[:n]
+            values = v
+        self._place(values)
         self._snap = self._host_snapshot()
         self._g_resident.set(float(self.resident))
         self._g_occupancy.set(self.resident / float(self.capacity))
-        if self._mesh is not None:
-            self.shard(self._mesh)
         flight.record(
             "state_restore", applied_hi=self.applied_hi,
             resident=self.resident,
@@ -666,6 +874,18 @@ class _DriftShim:
     def __init__(self, label: str):
         self.model_hash = label
         self.wire = _DerivedWire()
+
+
+def _padded_rows(slots: int) -> int:
+    """Rows of a buffer (or of one chip's piece of it) that holds
+    ``slots`` slots: a scratch row more, up to a multiple of 256."""
+    return -(-(slots + 1) // _ROW_PAD) * _ROW_PAD
+
+
+def _data_width(mesh) -> int:
+    from flink_jpmml_tpu.parallel.mesh import DATA_AXIS
+
+    return int(mesh.shape.get(DATA_AXIS, 1))
 
 
 def _npz_payload(snap: Dict[str, Any]) -> Dict[str, np.ndarray]:

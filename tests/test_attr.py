@@ -330,6 +330,21 @@ class TestDeviceProfilerRateLimiter:
         assert not prof.enabled
         assert not prof.should_sample()
 
+    def test_a_deployment_states_its_interval_before_the_pipeline(
+            self, monkeypatch):
+        """``profiler_for(metrics, interval_s=)`` sets the interval of
+        the registry's profiler where it creates it (a deployment on
+        four chips samples every two minutes: benchmark/paths/mesh.py);
+        the pipeline's later ``profiler_for(metrics)`` gets that one."""
+        monkeypatch.delenv("FJT_PROF_SAMPLE", raising=False)
+        m = MetricsRegistry()
+        prof = profiler.profiler_for(m, interval_s=120.0)
+        assert prof._interval == 120.0
+        assert profiler.profiler_for(m) is prof
+        assert profiler.profiler_for(m, interval_s=1.0)._interval == 120.0
+        assert prof.should_sample() and not prof.should_sample()
+        assert profiler.profiler_for(MetricsRegistry())._interval == 1.0
+
     def test_sample_feeds_gauges_and_device_stage(self, tmp_path):
         clk = FakeClock(10.0)
         m, prof = self._prof(tmp_path, clk)

@@ -1015,11 +1015,15 @@ def check_journey_trace() -> None:
     )
     m_gate = MetricsRegistry()
     assert trace_mod.store_for(m_gate) is None
-    n = 200_000
-    t0 = time.perf_counter()
-    for _ in range(n):
-        trace_mod.store_for(m_gate)
-    per_call = (time.perf_counter() - t0) / n
+    # best of five: a shared host's scheduling doubles a single loop's
+    # reading; the gate's own cost is the floor
+    n = 40_000
+    per_call = float("inf")
+    for _ in range(5):
+        t0 = time.perf_counter()
+        for _ in range(n):
+            trace_mod.store_for(m_gate)
+        per_call = min(per_call, (time.perf_counter() - t0) / n)
     assert per_call <= 2e-6, (
         f"unarmed journey gate costs {per_call * 1e6:.2f}µs/dispatch > 2µs"
     )
