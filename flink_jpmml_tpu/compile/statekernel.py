@@ -27,12 +27,13 @@ lane of an (8, 128) tile. What the TPU compiler makes of a table write
   dispatch, 9.6 GB of temporaries, ~430 of a dispatch's 665 ms. Those
   three are now two whole-row scatters whose other columns carry the
   operation's identity (−inf for max, +inf for min);
-- a scatter into a SLICE of columns (the add of columns 0..4 below) is
-  expanded to a ``while`` of one iteration a record, ~3.5 µs each:
-  ~230 ms for 65,536 records. The same mechanism cures it
-  (``S.at[slots].add`` of rows padded with 0: ~46 ms a dispatch all
-  told), and it is held back only because the benchmark's load
-  generator cannot yet out-run the pipeline that results (PERF.md §7).
+- a scatter into a SLICE of columns (what the add of the five
+  accumulator columns was until PR 28) is expanded to a ``while`` of
+  one iteration a record, ~3.5 µs each: ~230 ms for 65,536 records.
+  The add is now a whole-row scatter too, its other three columns
+  carrying −0.0 (``x + (−0.0)`` is ``x`` for every float32, −0.0 and
+  ±inf included; +0.0 would turn a stored −0.0 into +0.0): no
+  ``while`` is left in the program.
 
 Batch-consistent read semantics: every record's DERIVED features
 reflect the table as of the BATCH start (one gather before the
@@ -147,13 +148,12 @@ def _state_step(S, score, slots, rel, w, reset, scratch, decay):
         # scatters, native and in place on the TPU (module docstring):
         # a column an operation does not touch carries that operation's
         # identity — max(x, -inf) and min(x, +inf) are exact, also on a
-        # fresh row's ±inf. The five accumulator columns still ride one
-        # column-sliced scatter-add, a loop over the records there
-        adds = jnp.stack(
-            [jnp.ones_like(score), score, score * score, w, w * score],
-            axis=1,
-        )
-        S = S.at[slots, COL_COUNT:COL_DSUM + 1].add(adds)
+        # fresh row's ±inf. The add's identity is -0.0, not 0.0:
+        # x + (-0.0) is x bit for bit, a stored -0.0 minimum included
+        S = S.at[slots].add(_rows(score, -0.0, {
+            COL_COUNT: jnp.ones_like(score), COL_SUM: score,
+            COL_SQSUM: score * score, COL_DCOUNT: w, COL_DSUM: w * score,
+        }))
         S = S.at[slots].max(_rows(
             score, -jnp.inf, {COL_LAST_T: rel, COL_MAX: score}
         ))
@@ -279,11 +279,12 @@ def renorm(S, mul, add):
     A table in pieces over a mesh is swept IN PLACE (donated, wherever
     the backend honours donation): a sweep into a new buffer moves
     every chip's piece, the allocator puts it somewhere else each time,
-    and the fold's per-record loop runs 1–1.4% slower on some addresses
-    than on others, so the chips' pace wandered from run to run and
-    inside a run, stepping at every renorm (PERF.md §6, PR 27). The
-    one-chip table's sweep is left as it was (its two buffers alternate
-    between the same two places; ROADMAP S6)."""
+    and the fold's per-record loop of the time (gone since PR 28) ran
+    1–1.4% slower on some addresses than on others, so the chips' pace
+    wandered from run to run and inside a run, stepping at every renorm
+    (PERF.md §6, PR 27). The one-chip table's sweep is left as it was
+    (its two buffers alternate between the same two places; ROADMAP
+    S6)."""
     in_place = (
         isinstance(S, jax.Array)
         and len(S.sharding.device_set) > 1

@@ -365,6 +365,20 @@ def _numpy_fold(S, score, slots, rel, w, reset):
     return derived, S, (S[:, COL_COUNT] + 2.0)[:, None] * mass
 
 
+def _dispatch(case, rng, B):
+    """One dispatch's operands for a ``case`` above →
+    ``(score, slots, rel, w, reset)`` as ``_state_step`` takes them,
+    and the bypass marks."""
+    slots, reset, bypass = case(rng, B)
+    slots = np.where(bypass, _SCRATCH, slots).astype(np.int32)
+    score = rng.normal(0.0, 3.0, size=B).astype(np.float32)
+    rel = rng.integers(3, 9, size=B).astype(np.float32)
+    w = np.where(
+        bypass, 0.0, np.power(_DECAY, -rel.astype(np.float64))
+    ).astype(np.float32)
+    return (score, slots, rel, w, reset), bypass
+
+
 def _jit_step():
     import jax
 
@@ -383,14 +397,7 @@ class TestStateStep:
     )
     def test_matches_float64_numpy_fold(self, case):
         rng = np.random.default_rng(25)
-        B = 512
-        slots, reset, bypass = case(rng, B)
-        slots = np.where(bypass, _SCRATCH, slots).astype(np.int32)
-        score = rng.normal(0.0, 3.0, size=B).astype(np.float32)
-        rel = rng.integers(3, 9, size=B).astype(np.float32)
-        w = np.where(
-            bypass, 0.0, np.power(_DECAY, -rel.astype(np.float64))
-        ).astype(np.float32)
+        (score, slots, rel, w, reset), bypass = _dispatch(case, rng, 512)
         S0 = _prior_table(rng)
         derived, S1 = (np.asarray(a) for a in _jit_step()(
             S0, score, slots, rel, w, reset
@@ -421,15 +428,68 @@ class TestStateStep:
         )
         assert not derived[bypass].any()
 
+    @pytest.mark.parametrize(
+        "last_t, lo, hi, sign",
+        [
+            (np.inf, -np.inf, np.inf, 1.0),
+            (-0.0, -0.0, np.inf, 1.0),  # min(-0.0, score > 0) holds
+            (-0.0, -np.inf, -0.0, -1.0),  # max(-0.0, score < 0) holds
+        ],
+        ids=["inf", "neg_zero_min", "neg_zero_max"],
+    )
+    def test_add_returns_the_columns_it_does_not_own(
+            self, last_t, lo, hi, sign):
+        """The add scatters whole rows, so ``COL_LAST_T``, ``COL_MIN``
+        and ``COL_MAX`` ride it with the add's identity, −0.0: where no
+        extremum of the batch moves them they come back byte for byte,
+        ±inf and a stored −0.0 included (``−0.0 + 0.0`` is ``+0.0``: an
+        identity of +0.0 would flip that sign bit)."""
+        rng = np.random.default_rng(28)
+        B, rows = 96, np.arange(20, 28)
+        S0 = _prior_table(rng)
+        S0[rows, COL_LAST_T], S0[rows, COL_MIN], S0[rows, COL_MAX] = (
+            last_t, lo, hi)
+        slots = rng.choice(rows, size=B).astype(np.int32)
+        score = (sign * rng.uniform(0.5, 3.0, size=B)).astype(np.float32)
+        rel = np.full(B, -1.0, np.float32)  # never above a -0.0 last_t
+        w = np.power(_DECAY, -rel.astype(np.float64)).astype(np.float32)
+        _, S1 = _jit_step()(S0, score, slots, rel, w, np.zeros(B, bool))
+        S1 = np.asarray(S1)
+        kept = [COL_LAST_T, COL_MIN, COL_MAX]
+        assert S1[:, kept].tobytes() == S0[:, kept].tobytes()
+        assert np.array_equal(
+            S1[:, COL_COUNT] - S0[:, COL_COUNT],
+            np.bincount(slots, minlength=_ROWS),
+        )
+        assert (S1[rows, COL_SUM] != S0[rows, COL_SUM]).all()
+
+    @pytest.mark.parametrize(
+        "case",
+        [_heavy_duplicates, _fresh_slots, _bypassed_rows, _scratch_only],
+        ids=lambda f: f.__name__.strip("_"),
+    )
+    def test_same_batch_twice_gives_the_same_bytes(self, case):
+        """Replay-exact: a native scatter may sum a slot's duplicates
+        in another order than a loop over the records would, but the
+        same dispatch folded into the same table sums them in the SAME
+        order every time (here on the CPU; PERF.md §6, PR 28 has the
+        chip's reading)."""
+        rng = np.random.default_rng(28)
+        operands, _ = _dispatch(case, rng, 4096)
+        S0, step = _prior_table(rng), _jit_step()
+        once, twice = (
+            [np.asarray(a).tobytes() for a in step(S0, *operands)]
+            for _ in range(2)
+        )
+        assert once == twice
+
     def test_every_table_scatter_writes_whole_rows(self):
         """Tripwire: on the TPU the table is column-major, tiled
         (8, 128), and a scatter into part of a row is lowered to a flat
         copy of the whole table (one column) or a loop over the records
-        (a slice of columns). Every scatter of the fold whose operand
-        is the table has to carry an update window of all 8 columns;
-        the one exception on record is the add of the five accumulator
-        columns (PERF.md §7: it goes whole-row, and this list empty,
-        once the benchmark's producer can feed what results)."""
+        (a slice of columns: the add of the five accumulator columns
+        until PR 28). Every scatter of the fold whose operand is the
+        table has to carry an update window of all 8 columns."""
         import re
 
         import jax
@@ -465,8 +525,8 @@ class TestStateStep:
 
         walk(module.operation)
         # reset, add, max, min, and the scratch row's zeroing
-        assert len(windows) >= 4, windows
-        assert [wd for wd in windows if wd != 8] == [5], windows
+        assert len(windows) >= 5, windows
+        assert all(wd == 8 for wd in windows), windows
 
 
 class TestNamedScopes:

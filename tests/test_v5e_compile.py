@@ -46,13 +46,13 @@ def no_compile_cache():
 @pytest.mark.parametrize("batch", [16384, 65536])
 def test_state_fold_scatters_in_place(one_chip, no_compile_cache, batch):
     """The fold of ``benchmark/configs/gbm500_keyed.json`` (200,000,000
-    slots, 6.4 GB, donated): the reset and the extrema are native
-    scatters in place. The chip keeps the table column-major, tiled
-    (8, 128); a scatter into one column made the compiler flatten the
-    table a column at a time (``f32[1600002048]``, 9.6 GB of
-    temporaries), and one into a slice of columns makes it loop over
-    the records: the one ``while`` left is the five-column add
-    (PERF.md §7; with it a whole-row scatter too, none is)."""
+    slots, 6.4 GB, donated): the reset, the add and the extrema are
+    native scatters of whole rows, in place. The chip keeps the table
+    column-major, tiled (8, 128); a scatter into one column made the
+    compiler flatten the table a column at a time
+    (``f32[1600002048]``, 9.6 GB of temporaries), and one into a slice
+    of columns made it loop over the records (the five-column add
+    until PR 28: 3.57 µs a record). No ``while`` is in the program."""
     import jax
     import jax.numpy as jnp
 
@@ -78,9 +78,9 @@ def test_state_fold_scatters_in_place(one_chip, no_compile_cache, batch):
     assert mem.alias_size_in_bytes >= table_bytes
     assert mem.temp_size_in_bytes < 10_000_000, mem.temp_size_in_bytes
     assert f"f32[{rows * 8}]" not in text
-    assert len(re.findall(r"\bwhile\(", text)) <= 1
+    assert not re.findall(r"\bwhile\(", text)
     scatters = re.findall(r"= (\S+) scatter\(", text)
-    assert len(scatters) >= 3, scatters  # reset, max, min
+    assert len(scatters) >= 4, scatters  # reset, add, max, min
     assert all(s.startswith(f"f32[{rows},8]") for s in scatters), scatters
 
 
@@ -109,11 +109,12 @@ def test_mesh_state_program_keeps_the_table_on_its_chip(
     table of ``benchmark/configs/gbm500_keyed_mesh4.json`` (560,000,000
     slots: 140,000,256 rows, 4.48 GB a chip): every chip folds its own
     piece in place. No collective is in the program (so none touches
-    the table), it loops no more than the one-chip program does, the
-    donated table is aliased shard by shard and the temporaries stay
-    under 1% of a shard. The forest here is the XLA rank-wire twin of a
-    small model (the Pallas kernel is built only where a TPU is
-    attached); the fold is the deployment's."""
+    the table), no ``while`` is in it nor in its one-chip twin (every
+    table write is a native scatter: a loop would walk a bucket's pad
+    rows as it walks records), the donated table is aliased shard by
+    shard and the temporaries stay under 1% of a shard. The forest here
+    is the XLA rank-wire twin of a small model (the Pallas kernel is
+    built only where a TPU is attached); the fold is the deployment's."""
     import dataclasses
 
     import jax
@@ -168,8 +169,8 @@ def test_mesh_state_program_keeps_the_table_on_its_chip(
     for collective in ("all-gather", "all-reduce", "all-to-all",
                        "collective-permute", "reduce-scatter"):
         assert collective not in text, collective
-    loops = len(re.findall(r"\bwhile\(", text))
-    assert loops == len(re.findall(r"\bwhile\(", one_text)) <= 1
+    assert not re.findall(r"\bwhile\(", text)
+    assert not re.findall(r"\bwhile\(", one_text)
     mem, shard_bytes = mesh_prog.memory_analysis(), layout.shard_rows * 8 * 4
     assert mem.alias_size_in_bytes >= shard_bytes
     assert mem.temp_size_in_bytes < shard_bytes // 100, mem.temp_size_in_bytes
