@@ -393,20 +393,23 @@ void fjt_bucketize_u16(const float* X, uint64_t n, uint32_t f,
 
 namespace {
 
-struct Crc32cTable {
+// byte-wise table of a reflected CRC-32 polynomial: 0x82F63B78 is CRC32C
+// (Kafka's batches), 0xEDB88320 zlib's CRC32 (the state table's key hash)
+template <uint32_t Poly>
+struct CrcTable {
     uint32_t t[256];
-    Crc32cTable() {
+    CrcTable() {
         for (uint32_t i = 0; i < 256; ++i) {
             uint32_t c = i;
             for (int k = 0; k < 8; ++k)
-                c = (c >> 1) ^ (0x82F63B78u & (~(c & 1u) + 1u));
+                c = (c >> 1) ^ (Poly & (~(c & 1u) + 1u));
             t[i] = c;
         }
     }
 };
 
 inline uint32_t crc32c_buf(const uint8_t* p, int64_t n) {
-    static const Crc32cTable table;
+    static const CrcTable<0x82F63B78u> table;
     uint32_t c = 0xFFFFFFFFu;
     for (int64_t i = 0; i < n; ++i)
         c = (c >> 8) ^ table.t[(c ^ p[i]) & 0xFFu];
@@ -572,6 +575,119 @@ int64_t fjt_kafka_decode_fixed(const uint8_t* buf, int64_t len,
         pos = end;
     }
     return count;
+}
+
+}  // extern "C"
+
+// ---------------------------------------------------------------------------
+// Keyed-state routing (runtime/state.py KeyedStateTable.route's fast path).
+//
+// The host mirror of the state table is three flat arrays (uint32 key
+// hashes, occupancy bytes, int64 LRU stamps), hundreds of millions of
+// entries in a deployment, and every record of every batch has to find its
+// key's slot in them. Two passes, one call each, both bit-exact twins of the
+// numpy code they stand in for (which stays as the fallback and the tests'
+// oracle):
+//
+//  - fjt_state_hash_f32: the key column of a raw f32 block → the uint32
+//    stable hash, parallel/partitioner.py stable_hash_vec of the column cast
+//    to int64: CRC32 (zlib's) over b"i" + the key's low
+//    abs(key).bit_length()//8 + 1 bytes, little-endian two's complement;
+//  - fjt_state_resolve: per record, in arrival order, walk the probe window
+//    from hash % capacity and stop at the first slot that matches (a hit:
+//    write the slot, stamp it) or is empty, or at the window's end (both:
+//    the record is left pending for the caller's claim/evict rounds). It
+//    writes neither keys nor occupancy, so what it resolves no claim or
+//    eviction of the same call can change.
+//
+// Every probe is a cache miss in a table of that size; the walk prefetches
+// the home slots of the records a few places ahead.
+// ---------------------------------------------------------------------------
+
+namespace {
+
+// numpy's f32 → int64 cast as this platform does it (cvttss2si): NaN, ±inf
+// and |x| >= 2^63 all come out INT64_MIN. Written out, since the plain C
+// cast of such a value is undefined.
+inline int64_t f32_to_i64(float f) {
+    if (!(f >= -9223372036854775808.0f && f < 9223372036854775808.0f))
+        return INT64_MIN;
+    return static_cast<int64_t>(f);
+}
+
+constexpr uint64_t kRouteAhead = 16;  // records prefetched ahead of the walk
+
+// hash % capacity, as a 32-bit division wherever the capacity allows one
+inline uint64_t home_slot(uint32_t h, uint64_t capacity) {
+    return capacity >> 32 ? h : h % static_cast<uint32_t>(capacity);
+}
+
+}  // namespace
+
+extern "C" {
+
+// col: the first record's key (an f32), row_stride: bytes from one record's
+// key to the next. out [n] uint32.
+void fjt_state_hash_f32(const uint8_t* col, uint64_t n, int64_t row_stride,
+                        uint32_t* out) {
+    static const CrcTable<0xEDB88320u> table;
+    const uint32_t* t = table.t;
+    const uint32_t seed = t[(0xFFFFFFFFu ^ uint32_t('i')) & 0xFFu] ^
+                          (0xFFFFFFFFu >> 8);
+    for (uint64_t i = 0; i < n; ++i) {
+        float f;
+        std::memcpy(&f, col + int64_t(i) * row_stride, sizeof f);
+        const int64_t k = f32_to_i64(f);
+        const uint64_t u = static_cast<uint64_t>(k);
+        const uint64_t mag = k < 0 ? ~u + 1 : u;
+        const int bits = mag ? 64 - __builtin_clzll(mag) : 0;
+        const int nbytes = bits / 8 + 1;  // 9 for -2^63 alone
+        uint32_t c = seed;
+        for (int b = 0; b < (nbytes < 8 ? nbytes : 8); ++b)
+            c = t[(c ^ uint32_t(u >> (8 * b))) & 0xFFu] ^ (c >> 8);
+        if (nbytes == 9)  // its sign-extension byte
+            c = t[(c ^ 0xFFu) & 0xFFu] ^ (c >> 8);
+        out[i] = c ^ 0xFFFFFFFFu;
+    }
+}
+
+// khash [n]; apply [n] (0: the record bypasses the table and is skipped);
+// keys/occ/touch [capacity]: the mirror; probe: the window; seq: this
+// call's stamp. Writes slots[i] and touch for a hit, pending[i] = 1 for a
+// record left to the caller (slots[i] untouched), 0 otherwise. → the hits
+// found past their home slot, counted once a key: a key's first hit of a
+// call is the one that finds its slot not yet stamped seq.
+uint64_t fjt_state_resolve(const uint32_t* khash, const uint8_t* apply,
+                           uint64_t n, const uint32_t* keys,
+                           const uint8_t* occ, int64_t* touch,
+                           uint64_t capacity, uint32_t probe, int64_t seq,
+                           int32_t* slots, uint8_t* pending) {
+    uint64_t collided = 0;
+    for (uint64_t i = 0; i < n; ++i) {
+        if (i + kRouteAhead < n) {
+            const uint64_t a = home_slot(khash[i + kRouteAhead], capacity);
+            __builtin_prefetch(occ + a);
+            __builtin_prefetch(keys + a);
+            __builtin_prefetch(touch + a, 1);
+        }
+        pending[i] = 0;
+        if (!apply[i]) continue;
+        const uint32_t h = khash[i];
+        uint64_t c = home_slot(h, capacity);
+        uint32_t p = 0;
+        for (; p < probe && occ[c] && keys[c] != h; ++p)
+            if (++c == capacity) c = 0;
+        if (p < probe && occ[c]) {
+            slots[i] = static_cast<int32_t>(c);
+            if (touch[c] != seq) {
+                touch[c] = seq;
+                collided += p != 0;
+            }
+        } else {
+            pending[i] = 1;
+        }
+    }
+    return collided;
 }
 
 }  // extern "C"

@@ -176,6 +176,27 @@ def _load() -> Optional[ctypes.CDLL]:
             ctypes.c_int64,                   # out capacity (records)
             ctypes.POINTER(ctypes.c_int64),   # out offsets [cap]
         ]
+        lib.fjt_state_hash_f32.restype = None
+        lib.fjt_state_hash_f32.argtypes = [
+            ctypes.c_void_p,                  # first record's key (f32)
+            ctypes.c_uint64,                  # n
+            ctypes.c_int64,                   # row stride (bytes)
+            ctypes.POINTER(ctypes.c_uint32),  # out hashes [n]
+        ]
+        lib.fjt_state_resolve.restype = ctypes.c_uint64  # collided
+        lib.fjt_state_resolve.argtypes = [
+            ctypes.POINTER(ctypes.c_uint32),  # khash [n]
+            ctypes.POINTER(ctypes.c_uint8),   # apply [n]
+            ctypes.c_uint64,                  # n
+            ctypes.POINTER(ctypes.c_uint32),  # mirror keys [capacity]
+            ctypes.POINTER(ctypes.c_uint8),   # mirror occupancy
+            ctypes.POINTER(ctypes.c_int64),   # mirror touch (stamped)
+            ctypes.c_uint64,                  # capacity
+            ctypes.c_uint32,                  # probe
+            ctypes.c_int64,                   # seq
+            ctypes.POINTER(ctypes.c_int32),   # slots [n] (hits written)
+            ctypes.POINTER(ctypes.c_uint8),   # out pending [n]
+        ]
         _lib = lib
         return _lib
 
@@ -422,3 +443,50 @@ def bucketize_pow2(
         n_threads,
     )
     return out
+
+
+def _ptr(a: np.ndarray, ctype):
+    return a.ctypes.data_as(ctypes.POINTER(ctype))
+
+
+def state_hash_f32(X: np.ndarray, key_col: int) -> Optional[np.ndarray]:
+    """Column ``key_col`` of a raw ``[n, arity]`` float32 block → uint32
+    stable hashes, read where the block lies (any strides): equal to
+    ``stable_hash_vec(X[:, key_col].astype(np.int64))`` on every value,
+    the ones numpy casts to ``INT64_MIN`` included. None when ``X`` is
+    no such block (the caller hashes with numpy). Callers check
+    :func:`available` first."""
+    if (not isinstance(X, np.ndarray) or X.ndim != 2
+            or X.dtype != np.float32):
+        return None
+    n = X.shape[0]
+    out = np.empty(n, np.uint32)
+    if n:
+        _load().fjt_state_hash_f32(
+            X.ctypes.data + key_col * X.strides[1], n, X.strides[0],
+            _ptr(out, ctypes.c_uint32),
+        )
+    return out
+
+
+def state_resolve(
+    khash: np.ndarray, apply: np.ndarray, keys: np.ndarray,
+    occ: np.ndarray, touch: np.ndarray, probe: int, seq: int,
+    slots: np.ndarray,
+) -> Tuple[np.ndarray, int]:
+    """One pass of ``KeyedStateTable.route`` over a batch: every applied
+    record whose key is found in its probe window, before any empty
+    slot, gets its slot written into ``slots`` and stamped ``seq`` in
+    ``touch``. → ``(pending bool[n], collided)``: the records left to
+    the caller's rounds, and the keys found past their home slot. The
+    arrays are the table's own (contiguous uint32 / bool / int64);
+    callers check :func:`available` first."""
+    n = khash.shape[0]
+    pending = np.empty(n, bool)
+    collided = _load().fjt_state_resolve(
+        _ptr(khash, ctypes.c_uint32), _ptr(apply, ctypes.c_uint8), n,
+        _ptr(keys, ctypes.c_uint32), _ptr(occ, ctypes.c_uint8),
+        _ptr(touch, ctypes.c_int64), keys.shape[0], probe, seq,
+        _ptr(slots, ctypes.c_int32), _ptr(pending, ctypes.c_uint8),
+    )
+    return pending, int(collided)

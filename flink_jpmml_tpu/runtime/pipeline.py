@@ -291,12 +291,13 @@ def dispatch_quantized(
         if unplanned:
             # keyed state routing (host-side slot assignment; the state
             # gather/update itself is traced into the dispatch below) —
-            # one vectorized pass per batch, zero per-record host work
+            # one native pass per batch for the keys that are resident,
+            # numpy rounds for the rest; no per-record Python
             with ledger.span("route", **ident):
                 khash = (
                     np.asarray(state_keys, np.uint32)
                     if state_keys is not None
-                    else state.hash_keys(state.extract_keys(X))
+                    else state.hash_block(X)
                 )
                 state.maybe_renorm(ident.get("first_off", state.applied_hi))
                 slots, reset, rel, w = state.assign_slots(khash, offs)

@@ -200,7 +200,7 @@ class KeyShuffle:
             if lo < n:
                 # the tail of a cut dispatch keeps the slots it has
                 h.slots[lo:n], h.reset[lo:n], h.apply[lo:n] = t.route(
-                    t.hash_keys(t.extract_keys(h.X[lo:n])), h.offs[lo:n],
+                    t.hash_block(h.X[lo:n]), h.offs[lo:n],
                     held=h.slots[:lo],
                 )
                 self._routed = n

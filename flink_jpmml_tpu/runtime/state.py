@@ -18,10 +18,16 @@ Division of labor — host routes, device accumulates:
   state routing agree on every key by construction. The key → slot
   map (hashes, occupancy, LRU touch) lives in host numpy — it is
   metadata exactly like the ring's offsets — and ``assign_slots``
-  resolves a whole batch with vectorized probing: dedupe the batch's
-  keys, probe a bounded linear window, claim empties, evict the
-  least-recently-touched slot when the window is full. No device
-  round trip is involved in routing.
+  resolves a whole batch in two steps (``route``). A record whose key
+  is resident, found in its bounded linear probe window before any
+  empty slot, is resolved by one native pass over the batch
+  (``_native/fjt_native.cpp``, which also hashes the block's key
+  column); the records it leaves go through vectorized rounds over
+  their deduped keys: probe the window, claim empties, evict the
+  least-recently-touched slot when the window is full. Which step
+  resolves a record follows from the mirror alone, and the rounds
+  alone (the library missing) give the same answer. No device round
+  trip is involved in routing.
 - **Device values.** The table's VALUES — one fixed-width f32 vector
   per slot (counts, sums, decayed counters in product form, last-seen
   stride, min/max) — live in a single ``[rows, STATE_WIDTH]`` device
@@ -99,6 +105,7 @@ import numpy as np
 
 from flink_jpmml_tpu.obs import recorder as flight
 from flink_jpmml_tpu.parallel.partitioner import stable_hash, stable_hash_vec
+from flink_jpmml_tpu.runtime import native
 from flink_jpmml_tpu.utils.exceptions import InputValidationException
 from flink_jpmml_tpu.utils.metrics import MetricsRegistry
 
@@ -228,6 +235,9 @@ class KeyedStateTable:
         self._c_collisions = m.counter("state_collisions")
         self._c_overflow = m.counter("state_overflow")
         self._c_bypass = m.counter("state_bypass_records")
+        # records ``route`` left to its claim rounds (0 on a stream of
+        # resident keys: the native pass resolved every one)
+        self._c_pending = m.counter("state_route_pending")
         self._c_rollbacks = m.counter("state_rollbacks")
         self._g_resident = m.gauge("state_resident_keys")
         self._g_occupancy = m.gauge("state_occupancy_frac")
@@ -362,9 +372,23 @@ class KeyedStateTable:
         col = np.asarray(X)[:, self.spec.key_col]
         return col.astype(np.int64)
 
+    def hash_block(self, X: np.ndarray) -> np.ndarray:
+        """Block-path key column of a raw f32 batch → uint32 stable
+        hashes, ``hash_keys(extract_keys(X))`` bit for bit: one native
+        pass over the column where it lies, where the library is built
+        and ``X`` is a float32 block."""
+        h = (
+            native.state_hash_f32(X, self.spec.key_col)
+            if native.available() else None
+        )
+        return self.hash_keys(self.extract_keys(X)) if h is None else h
+
     def assign_slots(self, khash: np.ndarray, offsets=None):
-        """Resolve one batch of key hashes to table slots (host-side,
-        vectorized — the only per-batch routing cost).
+        """Resolve one batch of key hashes to table slots on the host:
+        ``route`` (one native pass over the batch for the keys that are
+        resident, vectorized numpy rounds for the rest), the
+        exactly-once high-water and the decay operands — the per-batch
+        routing cost beside the key hash.
 
         → ``(slots int32[B], reset bool[B], rel f32[B], w f32[B])``:
         ``slots`` are GLOBAL slots (``locate`` says where one lives;
@@ -414,91 +438,46 @@ class KeyedStateTable:
         bypass. Claims and evicts in the host mirror and counts, and
         leaves ``applied_hi`` and the decay clock alone.
 
+        A record whose key is resident (found in its probe window before
+        any empty slot) is resolved by one native pass over the batch;
+        what that pass leaves (fresh keys, a hole before the key's row,
+        an exhausted window) goes through ``_claim_rounds``, as every
+        record does where the native library cannot be built.
+        ``state_route_pending`` counts the records left to the rounds.
+
         ``held`` are the slots of records an earlier call routed and no
         dispatch has taken yet: they count as touched by this call, so
         no eviction of it hands one to another key (the held record
         would fold into that key's fresh row)."""
-        khash = np.asarray(khash, np.uint32)
+        khash = np.ascontiguousarray(khash, np.uint32)
         B = khash.shape[0]
         self._seq += 1
         seq = self._seq
-        if held is not None and len(held):
-            hs = np.asarray(held, np.int64)
-            self._touch[hs[hs < self.capacity]] = seq
         offs = np.asarray(offsets, np.int64)
         apply = offs >= self.skip_until
         n_bypass = int(B - apply.sum())
         slots = np.full(B, self.scratch, np.int32)
         reset = np.zeros(B, bool)
-        if apply.any():
-            uk, inv = np.unique(khash[apply], return_inverse=True)
-            nu = uk.shape[0]
-            base = uk.astype(np.int64) % self.capacity
-            slot_u = np.full(nu, -1, np.int64)
-            reset_u = np.zeros(nu, bool)
-            keys_h, occ, touch = self._keys, self._occ, self._touch
-            collided = 0
-            for p in range(self.spec.probe):
-                pending = slot_u < 0
-                if not pending.any():
-                    break
-                cand = (base + p) % self.capacity
-                hit = pending & occ[cand] & (keys_h[cand] == uk)
-                slot_u[hit] = cand[hit]
-                # stamp at hit/claim time, not batch end: the evict
-                # round must see THIS batch's slots as untouchable
-                touch[cand[hit]] = seq
-                pending &= ~hit
-                empty = pending & ~occ[cand]
-                idx = np.flatnonzero(empty)
-                if idx.size:
-                    # one claimant per empty slot per round (np.unique
-                    # keeps the first); losers keep probing
-                    _, first = np.unique(cand[idx], return_index=True)
-                    win = idx[first]
-                    c = cand[win]
-                    slot_u[win] = c
-                    occ[c] = True
-                    keys_h[c] = uk[win]
-                    touch[c] = seq
-                    reset_u[win] = True
-                    self.resident += win.size
-                    self._c_inserts.inc(win.size)
-                if p == 0:
-                    # catalogue semantic: home slot held by a DIFFERENT
-                    # key — a fresh key claiming its empty home slot is
-                    # not a collision, so count after the claim round
-                    collided = int((slot_u < 0).sum())
-            pend = np.flatnonzero(slot_u < 0)
-            if pend.size:
-                # probe window exhausted: evict the least-recently-
-                # touched slot in each key's window — but never one
-                # touched THIS batch (another key just landed there);
-                # keys that lose the eviction race overflow to scratch
-                W = (base[pend, None]
-                     + np.arange(self.spec.probe)[None, :]) % self.capacity
-                t = touch[W]
-                vic = W[np.arange(pend.size), np.argmin(t, axis=1)]
-                fresh_vic = touch[vic] < seq
-                _, first = np.unique(vic, return_index=True)
-                winner = np.zeros(pend.size, bool)
-                winner[first] = True
-                winner &= fresh_vic
-                win = pend[winner]
-                c = vic[winner]
-                if win.size:
-                    keys_h[c] = uk[win]
-                    touch[c] = seq
-                    slot_u[win] = c
-                    reset_u[win] = True
-                    self._c_evictions.inc(win.size)
-                lost = int(pend.size - win.size)
-                if lost:
-                    self._c_overflow.inc(lost)
-            assigned = slot_u >= 0
-            slot_r = np.where(assigned, slot_u, np.int64(self.scratch))
-            slots[apply] = slot_r[inv].astype(np.int32)
-            reset[apply] = reset_u[inv]
+        todo, collided = apply, 0
+        if B and native.available():
+            # a hit changes neither ``_occ`` nor ``_keys`` and every
+            # slot before it is occupied, so no claim or eviction below
+            # can change what this resolved. It tells a key's first
+            # record by a slot not yet stamped ``seq``: ``held`` is
+            # stamped after it
+            todo, collided = native.state_resolve(
+                khash, apply, self._keys, self._occ, self._touch,
+                self.spec.probe, seq, slots,
+            )
+        if held is not None and len(held):
+            hs = np.asarray(held, np.int64)
+            self._touch[hs[hs < self.capacity]] = seq
+        n_todo = int(todo.sum())
+        if n_todo:
+            slots[todo], reset[todo], c = self._claim_rounds(khash[todo], seq)
+            collided += c
+            self._c_pending.inc(n_todo)
+        if n_bypass < B:
             hits = int(
                 (apply & (slots != self.scratch) & ~reset).sum()
             )
@@ -514,6 +493,80 @@ class KeyedStateTable:
             self._c_hits.value / rec if rec else 0.0
         )
         return slots, reset, apply
+
+    def _claim_rounds(self, khash: np.ndarray, seq: int):
+        """Resolve records by vectorized rounds over their unique keys:
+        probe a bounded linear window a slot a round, take a match,
+        claim an empty, and evict the least-recently-touched slot of a
+        window that is full → ``(slots int32, reset bool, collided)``,
+        one of each a record, ``collided`` the unique keys not resolved
+        at their home slot."""
+        uk, inv = np.unique(khash, return_inverse=True)
+        nu = uk.shape[0]
+        base = uk.astype(np.int64) % self.capacity
+        slot_u = np.full(nu, -1, np.int64)
+        reset_u = np.zeros(nu, bool)
+        keys_h, occ, touch = self._keys, self._occ, self._touch
+        collided = 0
+        for p in range(self.spec.probe):
+            pending = slot_u < 0
+            if not pending.any():
+                break
+            cand = (base + p) % self.capacity
+            hit = pending & occ[cand] & (keys_h[cand] == uk)
+            slot_u[hit] = cand[hit]
+            # stamp at hit/claim time, not batch end: the evict
+            # round must see THIS batch's slots as untouchable
+            touch[cand[hit]] = seq
+            pending &= ~hit
+            empty = pending & ~occ[cand]
+            idx = np.flatnonzero(empty)
+            if idx.size:
+                # one claimant per empty slot per round (np.unique
+                # keeps the first); losers keep probing
+                _, first = np.unique(cand[idx], return_index=True)
+                win = idx[first]
+                c = cand[win]
+                slot_u[win] = c
+                occ[c] = True
+                keys_h[c] = uk[win]
+                touch[c] = seq
+                reset_u[win] = True
+                self.resident += win.size
+                self._c_inserts.inc(win.size)
+            if p == 0:
+                # catalogue semantic: home slot held by a DIFFERENT
+                # key — a fresh key claiming its empty home slot is
+                # not a collision, so count after the claim round
+                collided = int((slot_u < 0).sum())
+        pend = np.flatnonzero(slot_u < 0)
+        if pend.size:
+            # probe window exhausted: evict the least-recently-
+            # touched slot in each key's window — but never one
+            # touched THIS batch (another key just landed there);
+            # keys that lose the eviction race overflow to scratch
+            W = (base[pend, None]
+                 + np.arange(self.spec.probe)[None, :]) % self.capacity
+            t = touch[W]
+            vic = W[np.arange(pend.size), np.argmin(t, axis=1)]
+            fresh_vic = touch[vic] < seq
+            _, first = np.unique(vic, return_index=True)
+            winner = np.zeros(pend.size, bool)
+            winner[first] = True
+            winner &= fresh_vic
+            win = pend[winner]
+            c = vic[winner]
+            if win.size:
+                keys_h[c] = uk[win]
+                touch[c] = seq
+                slot_u[win] = c
+                reset_u[win] = True
+                self._c_evictions.inc(win.size)
+            lost = int(pend.size - win.size)
+            if lost:
+                self._c_overflow.inc(lost)
+        slot_r = np.where(slot_u >= 0, slot_u, np.int64(self.scratch))
+        return slot_r[inv].astype(np.int32), reset_u[inv], collided
 
     def unclaim(self, slots) -> None:
         """Give back slots claimed by ``route`` for records that will

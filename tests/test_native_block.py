@@ -98,6 +98,79 @@ class TestNativeRing:
         assert isinstance(r, _PyRing)
 
 
+def _boundaries():
+    """The float32 values at and on either side of every byte-length
+    boundary of the key encoding, 2^(8b-1), both signs; 2^63 itself and
+    what lies beyond are out of int64's range."""
+    out = []
+    for b in range(1, 9):
+        lim = np.float32(2.0 ** (8 * b - 1))
+        out += [np.nextafter(lim, np.float32(0)), lim,
+                np.nextafter(lim, np.float32(np.inf))]
+    out += [float(v) for v in (126, 127, 128, 129, 32767, 32768, 32769)]
+    return np.array(out + [-v for v in out], np.float32)
+
+
+_HASH_COLUMNS = {
+    "zero_and_ones": np.array([0.0, -0.0, 1.0, -1.0, 0.5, -0.5, 1.9, -1.9],
+                              np.float32),
+    "byte_length_boundaries": _boundaries(),
+    # the benchmark's ids: the float32 with bit pattern 0x4B000000 + rank
+    "benchmark_ids": (
+        np.uint32(0x4B000000) + np.concatenate([
+            np.arange(0, 70000), np.arange(2**23 - 9, 2**23 + 9),
+            np.linspace(0, 335544319, 50001).astype(np.int64),
+            np.arange(335544319 - 9, 335544320),
+        ]).astype(np.uint32)).view(np.float32),
+    "negatives": -np.concatenate([
+        np.arange(1, 3000), 2.0 ** np.arange(0, 63), 3.0 * 2.0 ** np.arange(0, 61),
+    ]).astype(np.float32),
+    "int64_min": np.array([-(2.0 ** 63)], np.float32),
+    # numpy casts these to INT64_MIN on this platform, and so does the plane
+    "nan_inf_and_out_of_range": np.array(
+        [np.nan, -np.nan, np.inf, -np.inf, 1e30, -1e30, 2.0 ** 63,
+         -(2.0 ** 64), 3.4e38], np.float32),
+}
+
+
+@needs_native
+class TestStateHash:
+    """``fjt_state_hash_f32`` reads the key column where it lies in a raw
+    f32 block and hashes it as ``stable_hash_vec`` hashes the column cast
+    to int64: the oracle, and what ``hash_keys(extract_keys(X))`` is."""
+
+    @pytest.mark.parametrize("name", sorted(_HASH_COLUMNS))
+    def test_strided_f32_column_equals_stable_hash_vec(self, name):
+        from flink_jpmml_tpu.parallel.partitioner import stable_hash_vec
+
+        col = _HASH_COLUMNS[name]
+        with np.errstate(invalid="ignore"):
+            keys = col.astype(np.int64)
+        if name in ("int64_min", "nan_inf_and_out_of_range"):
+            assert (keys == np.iinfo(np.int64).min).all()
+        want = stable_hash_vec(keys)
+        X = np.full((col.size, 5), 7.0, np.float32)
+        X[:, 2] = col
+        assert np.array_equal(native.state_hash_f32(X, 2), want)
+        # any strides: every other row of a column-major copy
+        F = np.asfortranarray(X)[::2]
+        assert np.array_equal(native.state_hash_f32(F, 2), want[::2])
+        assert native.state_hash_f32(X[:0], 2).shape == (0,)
+
+    def test_hash_block_is_hash_keys_of_extract_keys(self, monkeypatch):
+        from flink_jpmml_tpu.runtime.state import KeyedStateTable, StateSpec
+
+        t = KeyedStateTable(StateSpec(capacity=16, key_col=1))
+        X = np.random.default_rng(3).normal(0, 1e6, (500, 3)).astype(
+            np.float32)
+        want = t.hash_keys(t.extract_keys(X))
+        assert np.array_equal(t.hash_block(X), want)
+        # not a float32 block: numpy hashes it
+        assert np.array_equal(t.hash_block(X.astype(np.float64)), want)
+        monkeypatch.setattr(native, "available", lambda: False)
+        assert np.array_equal(t.hash_block(X), want)
+
+
 class TestBlockPipeline:
     @pytest.fixture()
     def iris_model(self, assets_dir):

@@ -16,6 +16,7 @@ import numpy as np
 import pytest
 
 from flink_jpmml_tpu.parallel.partitioner import stable_hash
+from flink_jpmml_tpu.runtime import native as native_mod
 from flink_jpmml_tpu.runtime import state as state_mod
 from flink_jpmml_tpu.runtime.state import (
     COL_COUNT,
@@ -173,6 +174,124 @@ class TestSlotRouting:
             assert int(t.hash_keys(np.array([k]))[0]) == (
                 stable_hash(k) & 0xFFFFFFFF
             ), k
+
+
+# -- the native pass against the rounds alone (ISSUE 31) -----------------------
+#
+# ``route`` resolves resident keys in one native pass and leaves the rest
+# to its numpy rounds; with the library masked the rounds take every
+# record, which is the code as it was. Two tables get the same calls, one
+# each way, and everything a caller or a snapshot can see has to agree
+# after every call. A case is a list of calls ``(khash, first offset)``
+# with optional ``held`` slots, a ``skip_until`` and an ``unclaim`` before
+# the call. Capacity 16, probe 4: hash ``h`` has its home at ``h % 16``.
+
+_COUNTERS = (
+    "state_records", "state_hits", "state_inserts", "state_evictions",
+    "state_overflow", "state_bypass_records", "state_collisions",
+)
+
+
+def _call(khash, first, **kw):
+    return dict(khash=np.asarray(khash, np.uint32), first=first, **kw)
+
+
+_AT_3 = [3, 19, 35, 51]  # four keys whose home is slot 3: a full window
+_PARITY_CASES = {
+    # every record of the later calls finds its key: nothing is pending
+    "all_hits": ([_call([1, 2, 7, 2], 0), _call([2, 7, 1, 1, 7], 4),
+                  _call([7], 9)], 4),
+    "fresh_keys_into_empty_homes": ([_call([0, 5, 9, 15], 0)], 4),
+    "two_fresh_keys_contend_for_one_empty_slot": (
+        [_call([19, 3, 35], 0), _call([3, 35, 19], 3)], 3),
+    # 19 sits at slot 4 behind 3: one collision a call, however many records
+    "home_held_by_another_key": (
+        [_call([3, 19], 0), _call([19, 19, 3, 19], 2)], 2),
+    "duplicate_fresh_key_resets_every_record": (
+        [_call([5, 5, 5, 6], 0), _call([5, 6], 4)], 4),
+    # 19's slot is given back: 35, behind it, meets the hole before its
+    # own row and claims the hole, as the rounds always did
+    "hole_from_unclaim_before_a_resident_key": (
+        [_call([3, 19, 35], 0), _call([35, 3], 3, unclaim=[4]),
+         _call([35, 19, 3], 5)], 5),
+    "window_wraps_past_capacity": (
+        [_call([15, 31, 47, 14], 0), _call([47, 31, 15, 14, 63], 4)], 5),
+    "exhausted_window_evicts_the_least_recent": (
+        [_call(_AT_3, 0), _call([19, 35, 51], 4), _call([67], 7),
+         _call([3, 67], 8)], 6),
+    # 67 and 83 both want the one slot this call has not touched
+    "eviction_race_lost_overflows_to_scratch": (
+        [_call(_AT_3, 0), _call([3, 19, 35, 67, 83], 4)], 6),
+    "held_slots_are_safe_from_eviction": (
+        [_call(_AT_3, 0), _call([67, 83], 4, held=[3, 4, 5]),
+         _call([99], 6, held=[3, 4, 5, 6, 16])], 7),
+    "offsets_under_skip_until_bypass": (
+        [_call([1, 2, 3], 0), _call([1, 2, 3, 4, 1], 1, skip_until=4),
+         _call([4, 1], 6)], 4),
+    "empty_batch": ([_call([], 0), _call([8], 0), _call([], 1)], 1),
+}
+
+
+def _random_stream():
+    """200 calls of keys from a population larger than the table, slots
+    given back as fast as they fill so that it runs near load 0.9 with
+    probe 8: hits, claims, holes, evictions and overflows all the way."""
+    rng = np.random.default_rng(31)
+    calls, first = [], 0
+    for i in range(200):
+        n = int(rng.integers(1, 48))
+        kw = {"unclaim": rng.integers(0, 64, 12)}
+        if i % 7 == 3:
+            kw["held"] = rng.integers(0, 64, 4)
+        if i % 13 == 6:
+            kw["skip_until"] = first + n // 2
+        calls.append(_call(rng.integers(0, 100, n) * 2654435761 % 2**32,
+                           first, **kw))
+        first += n
+    return calls
+
+
+def _route_both_ways(case, capacity, probe, monkeypatch):
+    """→ the native table's ``state_route_pending`` at the end."""
+    (a, ma), (b, mb) = (_table(capacity, probe) for _ in range(2))
+    for i, call in enumerate(case):
+        kh = call["khash"]
+        offs = np.arange(call["first"], call["first"] + kh.size)
+        out = []
+        for t, masked in ((a, False), (b, True)):
+            if "unclaim" in call:
+                t.unclaim(call["unclaim"])
+            t.skip_until = call.get("skip_until", t.skip_until)
+            with monkeypatch.context() as mp:
+                if masked:
+                    mp.setattr(native_mod, "available", lambda: False)
+                out.append(t.route(kh, offs, held=call.get("held")))
+        for name, x, y in zip(("slots", "reset", "apply"), *out):
+            assert np.array_equal(x, y), (i, name, x, y)
+        for name in ("_keys", "_occ", "_touch"):
+            assert np.array_equal(getattr(a, name), getattr(b, name)), (
+                i, name)
+        assert a.resident == b.resident, i
+        ca, cb = (m.struct_snapshot()["counters"] for m in (ma, mb))
+        for name in _COUNTERS:
+            assert ca[name] == cb[name], (i, name, ca[name], cb[name])
+    # masked, every record that applies is left to the rounds
+    assert cb["state_route_pending"] == (
+        cb["state_records"] - cb["state_bypass_records"])
+    return ca["state_route_pending"]
+
+
+@pytest.mark.skipif(not native_mod.available(), reason="no native library")
+class TestNativeRouteParity:
+    @pytest.mark.parametrize("name", sorted(_PARITY_CASES))
+    def test_native_pass_and_rounds_equal_the_rounds_alone(
+            self, name, monkeypatch):
+        case, pending = _PARITY_CASES[name]
+        assert _route_both_ways(case, 16, 4, monkeypatch) == pending
+
+    def test_random_stream_at_load_09(self, monkeypatch):
+        pending = _route_both_ways(_random_stream(), 64, 8, monkeypatch)
+        assert 0 < pending < 200 * 48
 
 
 @pytest.fixture(scope="module")

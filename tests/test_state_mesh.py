@@ -19,6 +19,7 @@ import numpy as np
 import pytest
 
 from flink_jpmml_tpu.obs import attr
+from flink_jpmml_tpu.runtime import native
 from flink_jpmml_tpu.runtime import shuffle as shuffle_mod
 from flink_jpmml_tpu.runtime.state import (
     COL_COUNT,
@@ -514,6 +515,37 @@ def test_no_eviction_takes_a_row_a_held_record_points_to(
         assert c["state_evictions"] == 0 and c["state_overflow"] == 4
     else:
         assert stolen > 0 and c["state_evictions"] > 0
+
+
+@pytest.mark.skipif(not native.available(), reason="no native library")
+def test_route_pending_counts_what_the_native_pass_leaves_to_the_rounds():
+    """Through the shuffle, cuts and held tails included: a stream whose
+    keys are all resident leaves ``state_route_pending`` where it was
+    (the native pass resolved every record), a churning one moves it."""
+    m = MetricsRegistry()
+    t = KeyedStateTable(StateSpec(capacity=64, probe=8), metrics=m,
+                        mesh=_mesh(2))
+    sh = shuffle_mod.KeyShuffle(t, 4, 4, (1,), 16, m)
+    ledger = attr.ledger_for(m)
+
+    def run(keys, first):
+        X = np.zeros((len(keys), 4), np.float32)
+        X[:, 0] = keys
+        sh.feed(X, np.arange(first, first + len(keys)))
+        cuts = 0
+        while sh.pending:
+            cuts += sh.take(ledger)[3].cut
+        c = m.struct_snapshot()["counters"]
+        return cuts, c["state_route_pending"], c["state_records"]
+
+    keys = [100] * 5 + list(range(101, 108))  # the hot key cuts a dispatch
+    cuts, fresh, _ = run(keys, 0)
+    assert cuts and fresh == 12  # every record's key was new to the table
+    cuts, pending, records = run(keys, 12)
+    assert cuts and pending == fresh and records == 24
+    assert m.struct_snapshot()["counters"]["state_hits"] == 12
+    _, pending, _ = run(list(range(300, 312)), 24)
+    assert pending == fresh + 12
 
 
 def test_a_churning_stream_with_a_cut_tail_folds_on_its_own_rows(
