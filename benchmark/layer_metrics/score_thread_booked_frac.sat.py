@@ -1,14 +1,15 @@
 """Pipeline: share of the score thread's time that some stage of it
-books (``obs/attr.STAGE_THREADS``: drain, encode, route, h2d,
-queue_wait, readback, sink, commit, prof_sample); what is left is host
-time of that thread no span covers. Over the time between the two
+books (``obs/attr.STAGE_THREADS``: drain, encode, route, shard, h2d,
+queue_wait, readback, unshard, sink, commit, prof_sample; ``shard`` and
+``unshard`` fire over a mesh alone); what is left is host time of that
+thread no span covers. Over the time between the two
 snapshots the deltas come from (see ``device_wait_frac.sat``). A stage
 that never fired counts as zero; a program that has no ``drain`` stage
 does not book the whole thread and reports nothing."""
 from lib.readers import stage_delta
 
-SCORE_THREAD = ("drain", "encode", "route", "h2d", "queue_wait",
-                "readback", "sink", "commit", "prof_sample")
+SCORE_THREAD = ("drain", "encode", "route", "shard", "h2d", "queue_wait",
+                "readback", "unshard", "sink", "commit", "prof_sample")
 
 
 def read(ctx):

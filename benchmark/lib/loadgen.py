@@ -14,9 +14,22 @@ One producer, parameterised by the traffic file alone:
 and reports the least it saw on any turn after the backlog was first
 full (the shape of ``bench._measure_kafka_mode``,
 flink_jpmml_tpu/bench.py:491, without its seek back to offset 0:
-offsets are fresh and increasing). ``producer_max_records_per_s``, null
-in every cell, holds it back: ``rehearse.py --starve`` proves with it
-that a producer slower than the pipeline fails the run.
+offsets are fresh and increasing). Every ``delivered`` reply says
+whether the backlog has been full yet (``filled``): the harness opens
+its window no earlier. ``producer_max_records_per_s``, null in every
+cell, holds the producer back, from its start or, with
+``producer_max_from: "filled"``, from the moment the backlog was first
+full: ``rehearse.py --starve`` proves with the second that a producer
+slower than the pipeline fails the run on the log's lead, and with the
+first that one that never fills its backlog gets no window at all.
+
+The child pins itself to the cores ``init`` names (``lib/cores.py``:
+the rule) before it starts a thread, and runs one encoder a core of
+them. The log is trimmed behind the sink: segments wholly under the
+delivered offset less one backlog are dropped (``_BulkBroker.trim``),
+so the child holds two backlogs of records, not the run's 50-60M (7-8
+GB of fresh pages in 30 s, faulted in beside a pipeline that faults in
+its own mirror); no consumer of a closed backlog reads behind its sink.
 
 The producer has to out-run the program's own ingest (one prefetch
 sidecar fetches and decodes 1.86M records/s on the chip's host, PERF.md
@@ -40,6 +53,7 @@ encoded segments only; it relies on the broker's ``_mu``, ``_segs`` and
 
 from __future__ import annotations
 
+import bisect
 import collections
 import concurrent.futures
 import json
@@ -54,10 +68,11 @@ _BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, _BENCH)                   # lib
 sys.path.insert(1, os.path.dirname(_BENCH))  # the program's broker
 
+from lib import cores  # noqa: E402
 from lib.stream import Stream  # noqa: E402
 
 _SEG = 512  # records per stored record batch (MiniKafkaBroker._SEG_RECORDS)
-ENCODERS = 4  # of the host's 13 cores; the pipeline's threads keep theirs
+ENCODERS = 4  # where nothing is pinned; pinned, one a core (lib/cores.py)
 PIECE = 16 * _SEG  # records one encoder draws and encodes at a time
 
 
@@ -89,6 +104,13 @@ def _make_broker(topic: str):
                 self._next[0] = self.produced = segs[-1][1]
                 self._mu.notify_all()
 
+        def trim(self, below: int) -> None:
+            """Drop the segments that lie wholly under offset ``below``."""
+            with self._mu:
+                segs = self._segs[0]
+                del segs[:bisect.bisect_right(
+                    segs, below, key=lambda s: s[1])]
+
         @staticmethod
         def encode(rows: np.ndarray, base: int):
             """float32 rows of offsets ``base``... → segments of ``_SEG``
@@ -118,8 +140,12 @@ class Generator:
             init["key_mix"], init["pool_rows"],
         )
         self.broker = _make_broker(init["topic"])
+        # one a core the child was pinned to (``main``), else ENCODERS
+        self.n_encoders = (cores.encoders_for(len(init["cores"]))
+                           if init.get("cores") else ENCODERS)
         self._encoders = concurrent.futures.ThreadPoolExecutor(
-            max_workers=ENCODERS, thread_name_prefix="encode")
+            max_workers=self.n_encoders, thread_name_prefix="encode")
+        self.filled = False  # the measured producer's backlog has been full
         self._stop = threading.Event()
         self._thread = None
         self._delivered = 0
@@ -156,6 +182,7 @@ class Generator:
 
     def start(self, traffic: dict, delivered: int) -> dict:
         self._delivered = int(delivered)
+        self.filled = False
         target = {"closed_backlog": self._run_closed}[traffic["loop"]]
         t0 = time.monotonic() + 0.05
         self._thread = threading.Thread(
@@ -174,33 +201,42 @@ class Generator:
         chunk = int(traffic["chunk_records"])
         want = int(traffic["backlog_records"])
         cap = traffic.get("producer_max_records_per_s")
+        cap_from_fill = traffic.get("producer_max_from") == "filled"
         first = submitted = self.broker.produced
         pieces = collections.deque()  # handed to the encoders, unpublished
-        least, filled = None, False
+        least, filled_after, trimmed_to, t_start = None, None, 0, t0
         while not self._stop.is_set():
             while pieces and pieces[0].done():
                 self.broker.publish(pieces.popleft().result())
             backlog = self.broker.produced - self._delivered
-            if filled:
+            if self.filled:
                 # every turn counts once the backlog has been full:
                 # before that the producer has not yet had its chance
                 least = backlog if least is None else min(least, backlog)
-            held = cap is not None and (
+            if self._delivered - want >= trimmed_to + chunk:
+                trimmed_to = self._delivered - want
+                self.broker.trim(trimmed_to)
+            held = cap is not None and (self.filled or not cap_from_fill) and (
                 submitted - first >= cap * (time.monotonic() - t0)
             )
             if (submitted - self._delivered < want and not held
-                    and len(pieces) < 2 * ENCODERS):
+                    and len(pieces) < 2 * self.n_encoders):
                 pieces.extend(self._submit(submitted, submitted + chunk))
                 submitted += chunk
                 continue
-            filled = filled or backlog >= want
+            if not self.filled and backlog >= want:
+                self.filled, now = True, time.monotonic()
+                filled_after = now - t_start
+                if cap_from_fill:  # the hold counts from here
+                    first, t0 = submitted, now
             if pieces:
                 concurrent.futures.wait([pieces[0]], timeout=0.002)
             else:
                 time.sleep(0.002)
         for piece in pieces:
             piece.cancel()
-        self._stats = {"least_backlog_records": least}
+        self._stats = {"least_backlog_records": least,
+                       "backlog_full_after_s": filled_after}
 
     def stop(self) -> dict:
         self._stop.set()
@@ -226,8 +262,12 @@ def main() -> None:
             msg = json.loads(line)
             cmd = msg["cmd"]
             if cmd == "init":
+                # before the first thread: each inherits the set
+                pinned = cores.pin(msg.get("cores"))
                 gen = Generator(msg)
-                reply({"host": gen.broker.host, "port": gen.broker.port})
+                reply({"host": gen.broker.host, "port": gen.broker.port,
+                       "pid": os.getpid(), "pinned": pinned,
+                       "encoders": gen.n_encoders})
             elif cmd == "produce":
                 lo = gen.broker.produced
                 gen.append(lo, lo + int(msg["n"]))
@@ -236,7 +276,8 @@ def main() -> None:
                 reply(gen.start(msg["traffic"], msg["delivered"]))
             elif cmd == "delivered":
                 gen.note_delivered(msg["n"])
-                reply({"produced": gen.broker.produced})
+                reply({"produced": gen.broker.produced,
+                       "filled": gen.filled})
             elif cmd == "stop":
                 reply(gen.stop())
             elif cmd == "exit":

@@ -20,7 +20,17 @@ Two halves, as the table itself is split (runtime/state.py):
   it. ``assign_slots`` itself routes 4M keys in seconds and would take
   minutes for 150M; there is no public way to restore a table from
   arrays short of a 6.4 GB ``.npz``, so ``apply_fill`` writes
-  ``_keys``/``_occ``/``resident`` and says so if they are gone. The few
+  ``_keys``/``_occ``/``resident`` and says so if they are gone. It
+  also writes each placed slot's LRU stamp (``_touch``, under a routing
+  sequence number of its own, ``_seq``), as the admission that put the
+  key there would have: a table that traffic had filled has every one
+  of those stamps written, and a mirror whose stamps are still the
+  untouched zero pages ``np.zeros`` hands out pays a first-touch fault
+  a probe inside the window (1.09M of them on the four-chip table: the
+  routing call ran at 347 falling to 54 us a thousand over a window's
+  first 12 s, PERF.md §5). ``back_mirror`` backs the mirror's pages
+  beforehand, beside the plan; every run prints the mirror's resident
+  bytes before and after. The few
   keys that would land past the table's end go through the public
   ``assign_slots``, which wraps. The key set is the domain's ranks, the
   same for every seed, so the placement is too.
@@ -35,11 +45,14 @@ holds it to the table's public ``hash_keys`` on a sample of every run.
 
 from __future__ import annotations
 
+import ctypes
 import json
+import mmap
 import zlib
 
 import numpy as np
 
+from . import cores
 from . import keys as keys_mod
 
 CHUNK = 1 << 21  # small enough that malloc reuses the work arrays
@@ -137,14 +150,60 @@ def plan_fill(n_keys: int, capacity: int) -> dict:
     return {"hash": h, "pos": pos, "distinct": distinct, "stats": stats}
 
 
-def apply_fill(table, plan: dict, log) -> None:
-    """Write a plan into the table's host mirror."""
-    for attr in ("_keys", "_occ", "resident"):
+def resident_bytes(arr: np.ndarray):
+    """Bytes of ``arr`` that a page of memory backs (``mincore``), or
+    None where the host cannot say."""
+    page = mmap.PAGESIZE
+    lo = arr.ctypes.data // page * page
+    n_pages = -(-(arr.ctypes.data + arr.nbytes - lo) // page)
+    vec = (ctypes.c_ubyte * n_pages)()
+    try:
+        libc = ctypes.CDLL(None, use_errno=True)
+        if libc.mincore(ctypes.c_void_p(lo), ctypes.c_size_t(n_pages * page),
+                        vec) != 0:
+            return None
+    except (OSError, AttributeError):
+        return None
+    return int(np.count_nonzero(np.frombuffer(vec, np.uint8) & 1)) * page
+
+
+def mirror_resident(table):
+    """→ resident bytes of the mirror's three arrays, or None."""
+    parts = [resident_bytes(getattr(table, a))
+             for a in ("_keys", "_occ", "_touch")]
+    return None if None in parts else sum(parts)
+
+
+def _needs(table) -> None:
+    for attr in ("_keys", "_occ", "_touch", "_seq", "resident"):
         if not hasattr(table, attr):
             raise RuntimeError(
                 f"KeyedStateTable has no {attr}: the benchmark's bulk "
                 "fill needs a new way in"
             )
+
+
+def back_mirror(table, log) -> None:
+    """Write the empty table's mirror through, zeros over zeros, so
+    that a page of memory backs every part of it before ``apply_fill``
+    scatters into it. The harness calls this while ``plan_fill`` still
+    runs on its thread, where the main thread would only wait: the
+    four-chip mirror's 7.28 GB cost 6 s of ``setup_s`` as first-touch
+    faults under the scatters (my chip runs, PR 33), and nothing
+    here."""
+    _needs(table)
+    if table.resident:
+        raise RuntimeError("back_mirror is for a table that holds no key")
+    rss = cores.rss_bytes()
+    for attr in ("_keys", "_occ", "_touch"):
+        getattr(table, attr)[:] = 0
+    log("table fill: the host mirror written through beside the plan; the "
+        f"process's resident set grew by {(cores.rss_bytes() or 0) - (rss or 0)}")
+
+
+def apply_fill(table, plan: dict, log) -> None:
+    """Write a plan into the table's host mirror."""
+    _needs(table)
     st = plan["stats"]
     log(f"table fill: {json.dumps(st)}, probe window {table.spec.probe}")
     n_keys = st["keys"]
@@ -166,9 +225,21 @@ def apply_fill(table, plan: dict, log) -> None:
         )
     h, pos = plan["hash"], plan["pos"]
     n_fit = int(np.searchsorted(pos, table.capacity))  # pos ascends
+    before = mirror_resident(table)
     table._keys[pos[:n_fit]] = h[:n_fit]
     table._occ[pos[:n_fit]] = True
+    # the stamp an admission leaves: one routing call's sequence number,
+    # under every one the run will take (``route`` counts on from it)
+    table._seq += 1
+    table._touch[pos[:n_fit]] = table._seq
     table.resident += plan["distinct"]
+    nbytes = table._keys.nbytes + table._occ.nbytes + table._touch.nbytes
+    # (``mincore`` counts a page that was only ever read, the shared
+    # zero page, as resident: ``back_mirror``'s line has the process's
+    # own resident set, which tells them apart)
+    log(f"table fill: the host mirror's resident bytes {before} before and "
+        f"{mirror_resident(table)} after, of {nbytes}; slots stamped "
+        f"{table._seq}")
     if n_fit < pos.shape[0]:
         # past the table's end: the table's own routing wraps them
         left = np.unique(h[n_fit:])

@@ -48,6 +48,14 @@ def stage_delta(ctx: dict, stage: str) -> Optional[Tuple[float, int]]:
     return float(h1["sum"]) - float(h0["sum"]), int(h1["n"]) - int(h0["n"])
 
 
+def stage_sums(ctx: dict) -> dict:
+    """``{stage: (self-time in seconds, observations)}`` for every stage
+    the ledger knows at the window's end, over the window."""
+    head = 'stage_seconds{stage="'
+    return {k[len(head):-2]: stage_delta(ctx, k[len(head):-2])
+            for k in ctx["snap1"]["histograms"] if k.startswith(head)}
+
+
 def window_records(ctx: dict) -> int:
     return int(sum(n for _, n in ctx["batches"]))
 

@@ -20,7 +20,8 @@ from . import byname
 from . import keys as keys_mod
 
 BLOCK = 65536
-_BLOCKS_KEPT = 4  # two chunks in the producer's hands span three, and one ahead
+# up to 23 pieces of 8192 in eight encoders' hands span four, and one ahead
+_BLOCKS_KEPT = 6
 
 
 class Stream:

@@ -11,10 +11,13 @@ The first runs every cell of ``BENCHMARK.json`` end to end on the CPU at
 a tiny size (20 trees, 61,001 slots with 45,000 keys resident, batch
 1024, a 2 s window) through ``run.run_cell``, once untraced and once
 traced, and asserts the result line's keys and that a CPU run reports
-no metric; then once more with the producer held to a tenth of what the
-pipeline drains, and asserts that the run is NOT correct and says why.
-On the CPU the rank wire scores on its XLA twin, not on the Pallas
-kernel.
+no metric; then once more with the producer held, from the moment its
+backlog was full, to a tenth of what the pipeline drains, and asserts
+that the run is NOT correct and that ``broken`` names the log's lead;
+and, on the first cell, once with the producer held from its start so
+that the backlog never fills: that run has to end by itself with a
+sentence, and open no window. On the CPU the rank wire scores on its
+XLA twin, not on the Pallas kernel.
 
 The second compiles the table's fill (``lib.prefill.device_table``) and
 the state fold (``statekernel._state_step``) for a described
@@ -23,10 +26,13 @@ size and batch, and prints ``memory_analysis()``: what the 6.4 GB
 buffer, its donation and the scatters cost is known before chip time is
 spent. Nothing runs; it is not a chip run.
 
-The third is a chip run: every saturated cell at its real size for 10 s
-with the producer held to ``STARVED_RECORDS_PER_S``, well under what
-the pipeline drains. It has to end ``correct: false`` with the log's
-lead named as the fault, and exits non-zero if it does not.
+The third is a chip run (four chips: it runs every cell in one
+process): every saturated cell at its real size for 10 s with the
+producer held to ``STARVED_RECORDS_PER_S``, well under what the
+pipeline drains, once its backlog has been full. It has to end
+``correct: false`` with the log's lead under ``broken``, and exits
+non-zero if it does not; then the first cell with the producer held
+from its start, which has to end with the sentence and no window.
 
 The fourth needs no device: each cell's producer (``lib/loadgen.py``,
 the cell's own traffic file) against a consumer that does nothing but
@@ -65,9 +71,17 @@ TINY = {
         "settle_s": 0.5, "trace_seconds": 0.5,
     },
 }
+# held from the moment the backlog was first full: the window opens on a
+# full log and the pipeline drains it
 TINY_STARVED = {
     "cfg": TINY["cfg"],
-    "traffic": dict(TINY["traffic"], producer_max_records_per_s=20000),
+    "traffic": dict(TINY["traffic"], producer_max_records_per_s=20000,
+                    producer_max_from="filled"),
+}
+# held from its start: the backlog never fills and no window opens
+TINY_NEVER_FULL = {
+    "cfg": TINY["cfg"],
+    "traffic": dict(TINY["traffic"], producer_max_records_per_s=2000),
 }
 STARVED_RECORDS_PER_S = 40000
 RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
@@ -86,6 +100,30 @@ def expect_starved(cell: str, res: dict) -> None:
     if res["correct"] or res["failed"]:
         sys.exit(f"{cell}: a starved run has to end correct: false with "
                  f"nothing failed: {line}")
+    if not any(n.startswith("least_lead_records.") for n in res["broken"]):
+        sys.exit(f"{cell}: a starved run's `broken` has to name the log's "
+                 f"lead: {res['broken']}")
+
+
+def expect_no_window(cell: str, seconds: float, overrides: dict,
+                     on_chip: bool) -> None:
+    """The cell under ``overrides`` that hold its producer so that the
+    backlog never fills: the run has to stop by itself (``run.die``),
+    not measure."""
+    import run
+
+    args = argparse.Namespace(
+        workload=cell, seed=2**31 + 11, seconds=seconds, trace=0)
+    try:
+        res = run.run_cell(args, overrides=overrides, on_chip=on_chip)
+    except SystemExit as stopped:
+        if stopped.code == 1:
+            print(f"{cell}: a producer that never filled its backlog ended "
+                  "the run before any window", flush=True)
+            return
+        raise
+    sys.exit(f"{cell}: a producer held under its backlog got a window: "
+             f"{json.dumps(res)[:2000]}")
 
 
 def rehearse_cells() -> None:
@@ -113,8 +151,10 @@ def rehearse_cells() -> None:
         )
         expect_starved(cell, run.run_cell(
             args, overrides=TINY_STARVED, on_chip=False))
-    print("rehearsal: every cell ran end to end on the CPU, and failed "
-          "when its producer was held back", flush=True)
+    expect_no_window(cells()[0], 2.0, TINY_NEVER_FULL, on_chip=False)
+    print("rehearsal: every cell ran end to end on the CPU, failed when its "
+          "producer was held back, and got no window from a producer that "
+          "never filled its backlog", flush=True)
 
 
 def starve_on_chip() -> None:
@@ -125,8 +165,12 @@ def starve_on_chip() -> None:
             workload=cell, seed=2**31 + 11, seconds=10.0, trace=0
         )
         expect_starved(cell, run.run_cell(args, overrides={"traffic": {
-            "producer_max_records_per_s": STARVED_RECORDS_PER_S}}))
-    print("starved: every cell failed when its producer was held back",
+            "producer_max_records_per_s": STARVED_RECORDS_PER_S,
+            "producer_max_from": "filled"}}))
+    expect_no_window(cells()[0], 10.0, {"traffic": {
+        "producer_max_records_per_s": STARVED_RECORDS_PER_S}}, on_chip=True)
+    print("starved: every cell failed when its producer was held back, and "
+          "a producer that never filled its backlog got no window",
           flush=True)
 
 
@@ -140,6 +184,7 @@ def drain_only(tiny: bool) -> None:
     from flink_jpmml_tpu.runtime import prefetch
     from flink_jpmml_tpu.runtime.kafka import KafkaBlockSource
     from flink_jpmml_tpu.utils.metrics import MetricsRegistry
+    from lib import cores
     from lib.stream import Stream
 
     with open(os.path.join(run.ROOT, "BENCHMARK.json")) as fh:
@@ -154,6 +199,7 @@ def drain_only(tiny: bool) -> None:
             F = int(cfg["model"]["n_features"])
             stream = Stream(seed, F, cfg["key_domain"], traffic["key_mix"],
                             traffic["pool_rows"])
+            split = cores.split(cores.allowed())
             child = run.Child()
             drained = {"hi": 0, "bad": []}
             stop = threading.Event()
@@ -162,7 +208,8 @@ def drain_only(tiny: bool) -> None:
                 child.send(cmd="init", seed=seed, topic="bench", n_features=F,
                            key_domain=cfg["key_domain"],
                            key_mix=traffic["key_mix"],
-                           pool_rows=traffic["pool_rows"])
+                           pool_rows=traffic["pool_rows"],
+                           cores=split["producer"])
                 addr = child.read()
                 source = prefetch.maybe_wrap_block(
                     KafkaBlockSource(
@@ -190,8 +237,8 @@ def drain_only(tiny: bool) -> None:
                 consumer.start()
                 started = child.ask(cmd="start", traffic=traffic, delivered=0)
                 watch = run.LeadWatch(child, lambda: drained["hi"])
-                time.sleep(max(0.0, float(started["t0"]) + float(
-                    traffic["settle_s"]) - time.monotonic()))
+                full_after = watch.wait_for_backlog(
+                    started, traffic, lambda: None)
                 w0, n0 = time.monotonic(), drained["hi"]
                 time.sleep(seconds)
                 w1, n1 = time.monotonic(), drained["hi"]
@@ -208,6 +255,8 @@ def drain_only(tiny: bool) -> None:
             allowed = int(traffic["least_backlog_allowed"])
             res = {
                 "cell": cell, "seed": seed, "window_s": w1 - w0,
+                "producer_cores": len(split["producer"]),
+                "backlog_full_after_s": full_after,
                 "drained_records_per_s": (n1 - n0) / (w1 - w0),
                 "least_lead_harness": lead, "generator": gen,
                 "allowed": allowed, "faults": drained["bad"][:5],

@@ -21,12 +21,25 @@ table filled to the deployment's resident keys (``lib/prefill.py``),
 every dispatch shape the window can use, a warm-up stream through the
 real pipeline, and the check of that stream against the plain
 reference. Once the window has closed, a sample of the scores it
-delivered, drawn from the seed, is held to the reference too. Every
-number that decides ``correct`` is printed beside its limit, as the
-last lines of stderr and under ``compared``, the last key of the
-result. The last line of stdout is the result; without a TPU, or with
-fewer chips than the cell asks for, the run exits non-zero and prints
-none.
+delivered, drawn from the seed, is held to the reference too.
+
+The producer's child and this process pin themselves to cores of their
+own (``lib/cores.py``), and the window opens at the later of
+``settle_s`` after the producer's start and the producer's first word
+that its backlog has been full; a producer that cannot fill it in
+``BACKLOG_WAIT_S`` gets no window, and the run ends with a sentence.
+
+Every number that decides ``correct`` is printed beside its limit, as
+the last lines of stderr and under ``compared``, the last key of the
+result: the entries that do not hold first (their names alone under
+``broken``, the key before), then the leads and the counters, then the
+rest; on stderr the same, the ``BROKEN`` lines last. Nothing makes a
+run not correct without an entry there. ``window``, the key before
+those, is what the window was made of: the program's stage sums, the
+delivery intervals, the producer's rates, the lead at the opening and
+both processes' resident sets. The last line of stdout is the result;
+without a TPU, or with fewer chips than the cell asks for, the run
+exits non-zero and prints none.
 """
 
 import time
@@ -52,6 +65,13 @@ sys.path.insert(0, HERE)   # lib, reference, paths
 sys.path.insert(1, ROOT)   # the program under test
 
 STALL_S = 8.0  # a dispatch takes under a second
+# a producer that cannot fill its backlog beside the pipeline in this
+# long is a finding, not a window
+BACKLOG_WAIT_S = 20.0
+# ``compared`` after the entries that do not hold: what decides a hard
+# run, then the rest in the order the checks ran
+COMPARED_HEAD = ("least_lead_records.", "backlog_full_after_s",
+                 "longest_delivery_gap_s", "counter.")
 # the window's scores held to the reference: runs of consecutive
 # offsets at places drawn from the seed (a run costs one block of keys)
 WINDOW_SAMPLE_RUNS, WINDOW_SAMPLE_RUN = 64, 64
@@ -137,7 +157,9 @@ class LeadWatch:
     the main one for seconds."""
 
     def __init__(self, child: Child, delivered_hi):
-        self.leads = []  # (time, records the log is ahead of the sink)
+        # (time, records the log is ahead of the sink, the log's head)
+        self.leads = []
+        self.filled_at = None  # the first reply that said ``filled``
         self._child, self._delivered_hi = child, delivered_hi
         self._done = threading.Event()
         self._thread = threading.Thread(target=self._run, daemon=True)
@@ -146,8 +168,14 @@ class LeadWatch:
     def _run(self) -> None:
         while not self._done.is_set():
             hi = self._delivered_hi()
-            produced = self._child.ask(cmd="delivered", n=hi)["produced"]
-            self.leads.append((time.monotonic(), produced - hi))
+            try:
+                reply = self._child.ask(cmd="delivered", n=hi)
+            except (OSError, ValueError, RuntimeError):
+                return  # the child is gone: the run is on its way out
+            now = time.monotonic()
+            self.leads.append((now, reply["produced"] - hi, reply["produced"]))
+            if self.filled_at is None and reply.get("filled"):
+                self.filled_at = now
             time.sleep(0.01)
 
     def stop(self) -> None:
@@ -156,9 +184,36 @@ class LeadWatch:
 
     def least(self, w0: float, w1: float) -> dict:
         """The least lead sampled inside [w0, w1], and when."""
-        in_w = [(a, t - w0) for t, a in self.leads if w0 <= t <= w1]
+        in_w = [(a, t - w0) for t, a, _ in self.leads if w0 <= t <= w1]
         return dict(zip(("least", "at_s"), min(in_w, default=(None, None))),
                     samples=len(in_w))
+
+    def produced_at(self, *times: float):
+        """The log's head at those instants, between the samples."""
+        t, _, head = zip(*self.leads)
+        return np.interp(times, t, head).tolist()
+
+    def wait_for_backlog(self, started: dict, traffic: dict, alive) -> float:
+        """Sleeps to the later of ``settle_s`` after the producer's
+        start and the first reply that says its backlog has been full →
+        the seconds from its start to that reply. The window opens
+        there. Past ``BACKLOG_WAIT_S`` the run ends with a sentence."""
+        t0 = float(started["t0"])
+        while self.filled_at is None:
+            alive()
+            waited = time.monotonic() - t0
+            if waited > BACKLOG_WAIT_S:
+                _, lead, head = self.leads[-1] if self.leads else (0, 0, 0)
+                made = max(0, head - int(started["first_offset"]))
+                die(f"the producer did not fill its backlog of "
+                    f"{traffic['backlog_records']} records beside the "
+                    f"pipeline: after {waited:.1f} s the log's lead over the "
+                    f"sink is {lead} records and the producer has made "
+                    f"{made / waited:.0f} records/s; no window was opened")
+            time.sleep(0.005)
+        time.sleep(max(
+            0.0, t0 + float(traffic["settle_s"]) - time.monotonic()))
+        return self.filled_at - t0
 
 
 class Sink:
@@ -256,7 +311,9 @@ def check_window_scores(seed, stream, model, handle, kept, span):
     in ``span`` (first, one past last) → (faults, compared)."""
     lo, hi = span
     if hi - lo < WINDOW_SAMPLE_RUN:
-        return ["no delivery inside the window to hold to the reference"], []
+        return (["no delivery inside the window to hold to the reference"],
+                [("window_records_to_sample", hi - lo, WINDOW_SAMPLE_RUN,
+                  False)])
     rng = np.random.default_rng([seed, 2])
     starts = np.unique(rng.integers(
         lo, hi - WINDOW_SAMPLE_RUN + 1, size=WINDOW_SAMPLE_RUNS))
@@ -282,6 +339,63 @@ def check_window_scores(seed, stream, model, handle, kept, span):
     faults = [] if miss <= 1.0 else [
         "scores delivered in the window differ from the reference"]
     return faults, [("window_score_miss_over_tol", miss, 1.0)]
+
+
+def describe_window(w0, w1, deliveries, watch, started, full_after, snaps,
+                    rss, split) -> dict:
+    """What the window was made of, for the reader of a slow run: the
+    program's stage sums between the two snapshots (seconds, count),
+    the intervals between deliveries (the window's edges close the
+    first and the last), the producer's rate while it filled its
+    backlog and over the window, the log's lead at the opening, both
+    processes' resident sets at the two edges, the cores."""
+    from lib import readers
+
+    t = np.array([w0] + [d[2] for d in deliveries if w0 <= d[2] <= w1] + [w1])
+    gaps = np.diff(t)
+    longest = int(gaps.argmax())
+    between = gaps[1:-1] if gaps.size > 2 else gaps
+    t0, first = float(started["t0"]), int(started["first_offset"])
+    at_full, at_w0, at_w1 = watch.produced_at(t0 + full_after, w0, w1)
+    lead0 = next((a for ts, a, _ in watch.leads if ts >= w0), None)
+    return {
+        "stage_s": {k: [round(v[0], 4), v[1]] for k, v in
+                    readers.stage_sums({"snap0": snaps[0], "snap1": snaps[1]}
+                                       ).items() if v[1]},
+        "delivery_ms": {
+            "n": int(gaps.size - 1),
+            "median": round(1e3 * float(np.median(between)), 3),
+            "p99": round(1e3 * float(np.quantile(between, 0.99)), 3),
+            "longest": round(1e3 * float(gaps[longest]), 3),
+            "longest_began_at_s": round(float(t[longest] - w0), 3),
+        },
+        "producer_records_per_s": {
+            "filling": round((at_full - first) / max(full_after, 1e-9)),
+            "window": round((at_w1 - at_w0) / (w1 - w0)),
+        },
+        "backlog_full_after_s": round(full_after, 3),
+        "lead_at_open": lead0,
+        "rss_bytes": rss,
+        "cores": {k: len(split[k]) for k in ("pipeline", "producer")},
+    }
+
+
+def settle_compared(compared, faults):
+    """→ the entries of ``compared`` as ``(name, value, limit, holds)``
+    in the order the result gives them: those that do not hold, then
+    the leads and the counters, then the rest. A limit is the most a
+    number may be, unless its entry says itself whether it holds (a
+    lead is a least). A fault that left no entry that does not hold
+    gets one, so that a run is never not correct without a number."""
+    entries = [(n, v, lim, bool(h[0]) if h else v <= lim)
+               for n, v, lim, *h in compared]
+    if faults and all(e[3] for e in entries):
+        entries.append(("faults_without_a_number", len(faults), 0, False))
+
+    def rank(e):
+        return 0 if not e[3] else 1 if e[0].startswith(COMPARED_HEAD) else 2
+
+    return sorted(entries, key=rank)  # stable: the checks' order within a rank
 
 
 def load_cell(args, overrides):
@@ -397,8 +511,17 @@ def run_cell(args, overrides=None, on_chip: bool = True) -> dict:
         )
     except LookupError as e:  # the mix names a key mix there is no file for
         die(str(e))
+    from lib import cores
+
+    # before JAX, the planner and the pipeline start a thread: each
+    # inherits this thread's set
+    may_run_on = cores.allowed()
+    split = cores.split(may_run_on)
+    cores.pin(split["pipeline"])
+    log(f"cores: {split['why']}: pipeline {split['pipeline']}, "
+        f"producer {split['producer']}")
     child = Child()
-    path = None
+    path = watch = None
     planner = concurrent.futures.ThreadPoolExecutor(max_workers=1)
     try:
         child.send(
@@ -406,6 +529,7 @@ def run_cell(args, overrides=None, on_chip: bool = True) -> dict:
             n_features=cfg["model"]["n_features"],
             key_domain=cfg["key_domain"], key_mix=traffic["key_mix"],
             pool_rows=traffic["pool_rows"],
+            cores=split["producer"],  # it pins itself before its threads
         )
         from lib import prefill
 
@@ -435,6 +559,8 @@ def run_cell(args, overrides=None, on_chip: bool = True) -> dict:
         compiled = compile_pmml(doc, batch_size=int(cfg["compile_batch"]))
         log(f"model parsed and lowered at {time.monotonic() - _T_PROCESS:.1f}s")
         addr = dict(child.read(), topic="bench")
+        log(f"load generator: pid {addr['pid']}, pinned {addr['pinned']}, "
+            f"{addr['encoders']} encoders")
         sink = Sink()
         n_warm = int(cfg["warmup_records"])
         sink.keep_scores(n_warm)
@@ -447,6 +573,7 @@ def run_cell(args, overrides=None, on_chip: bool = True) -> dict:
                     die(f"{k} is {facts.get(k)!r}, the configuration "
                         f"states {want!r}")
         log(f"table built at {time.monotonic() - _T_PROCESS:.1f}s")
+        prefill.back_mirror(path.table, log)  # while the plan is made
         path.fill_table(args.seed, plan.result(), log)
         log(f"table filled at {time.monotonic() - _T_PROCESS:.1f}s")
         path.warm_shapes()
@@ -480,14 +607,15 @@ def run_cell(args, overrides=None, on_chip: bool = True) -> dict:
         # -- the window ------------------------------------------------
         started = child.ask(cmd="start", traffic=traffic, delivered=n_warm)
         watch = LeadWatch(child, lambda: sink.delivered_hi)
-        time.sleep(max(0.0, float(started["t0"]) + float(traffic["settle_s"])
-                       - time.monotonic()))
+        full_after = watch.wait_for_backlog(started, traffic, path.check_alive)
         w0 = time.monotonic()
         w1 = w0 + float(args.seconds)
         sink.keep_until = w1 + 2 * STALL_S
         setup_s = w0 - _T_PROCESS
         snap0 = path.metrics.struct_snapshot()
         n_compiles0 = len(compiles)
+        rss = {"parent": [cores.rss_bytes()],
+               "child": [cores.rss_bytes(addr["pid"])]}
         # The window's second snapshot, at its end and on a thread of
         # its own: stopping a trace holds the main loop far past w1
         # (tens of seconds on a long stretch), and a snapshot taken
@@ -498,6 +626,8 @@ def run_cell(args, overrides=None, on_chip: bool = True) -> dict:
             time.sleep(max(0.0, w1 - time.monotonic()))
             at_end["snap1"] = path.metrics.struct_snapshot()
             at_end["compiles"] = len(compiles)
+            rss["parent"].append(cores.rss_bytes())
+            rss["child"].append(cores.rss_bytes(addr["pid"]))
 
         closer = threading.Thread(target=snap_at_end, daemon=True)
         closer.start()
@@ -525,7 +655,6 @@ def run_cell(args, overrides=None, on_chip: bool = True) -> dict:
                 # dispatch takes: say where every thread stands
                 stalled = True
                 faulthandler.dump_traceback(file=sys.stderr)
-                faults.append(f"no delivery for {STALL_S:.0f} s")
             time.sleep(0.005)
         closer.join(timeout=30.0)
         if closer.is_alive():
@@ -566,10 +695,19 @@ def run_cell(args, overrides=None, on_chip: bool = True) -> dict:
         )
         del kept
         faults += window_faults
+        described = describe_window(
+            w0, w1, deliveries, watch, started, full_after, (snap0, snap1),
+            rss, split)
+        log(f"window: {json.dumps(described)}")
+        gap_s = described["delivery_ms"]["longest"] / 1e3
+        if gap_s > STALL_S:
+            faults.append(f"no delivery for {gap_s:.1f} s")
         compared += window_compared + [
             ("offsets_lost", lost, 0), ("offsets_duplicated", dup, 0),
             ("scores_nonfinite", sink.nonfinite, 0),
             ("compilations_in_window", n_compiles, 0),
+            ("longest_delivery_gap_s", gap_s, STALL_S),
+            ("backlog_full_after_s", full_after, BACKLOG_WAIT_S),
         ]
         allowed = int(traffic["least_backlog_allowed"])
         for who, least in (("harness", lead["least"]),
@@ -632,11 +770,14 @@ def run_cell(args, overrides=None, on_chip: bool = True) -> dict:
                     ],
                     "idle_gaps": xtrace.attribute_gaps(trace_red),
                 }
-            elif on_chip:
-                faults.append("no operation ran on the device in the trace")
+            if on_chip:
+                n_ops = len(trace_red.get("device_ops", ()))
+                compared.append(("device_ops_in_trace", n_ops, 1, n_ops >= 1))
+                if not n_ops:
+                    faults.append(
+                        "no operation ran on the device in the trace")
         for f in faults:
             log(f"FAULT: {f}")
-        result["correct"] = not faults and failed == 0
         ctx = {
             "window": (w0, w1), "window_s": float(args.seconds),
             "deliveries": [(t, n) for _, n, t in deliveries],
@@ -656,14 +797,17 @@ def run_cell(args, overrides=None, on_chip: bool = True) -> dict:
         result["metrics"] = metrics
         result["device"] = device
         # each number that decided ``correct``, beside its limit: the
-        # last key of the line, and the last lines of stderr
-        result["compared"] = {}
-        for name, value, limit, *holds in compared:
-            # a limit is the most a number may be, unless its entry
-            # says itself whether it holds (a lead is a least)
-            ok = bool(holds[0]) if holds else value <= limit
-            result["compared"][name] = {
-                "value": value, "limit": limit, "holds": ok}
+        # last key of the line, what does not hold first; and the last
+        # lines of stderr, what does not hold last
+        entries = settle_compared(compared, faults)
+        print(f"window: {json.dumps(described)}", file=sys.stderr, flush=True)
+        result["correct"] = all(e[3] for e in entries) and failed == 0
+        result["window"] = described
+        result["broken"] = [e[0] for e in entries if not e[3]]
+        result["compared"] = {
+            name: {"value": value, "limit": limit, "holds": ok}
+            for name, value, limit, ok in entries}
+        for name, value, limit, ok in sorted(entries, key=lambda e: not e[3]):
             print(f"compared: {name} {value} limit {limit} "
                   f"{'holds' if ok else 'BROKEN'}", file=sys.stderr, flush=True)
         return result
@@ -674,7 +818,10 @@ def run_cell(args, overrides=None, on_chip: bool = True) -> dict:
                 path.stop()
             except Exception as e:  # the first error is the one to report
                 log(f"stop: {type(e).__name__}: {e}")
+        if watch is not None:
+            watch.stop()
         child.close()
+        cores.pin(may_run_on)  # a caller that goes on (a test) has its own back
 
 
 def main(argv=None) -> None:

@@ -47,13 +47,29 @@ PINNED = {
 }
 
 
-def decode_log(broker, n_features):
+def decode_segs(segs, n_features):
     from flink_jpmml_tpu.runtime.kafka import decode_record_batches_rows
 
-    segs = list(broker._segs[0])
     assert [a for a, _, _ in segs[1:]] == [b for _, b, _ in segs[:-1]]
     return decode_record_batches_rows(
         b"".join(blob for _, _, blob in segs), n_features)
+
+
+def decode_log(broker, n_features):
+    return decode_segs(list(broker._segs[0]), n_features)
+
+
+def tap_publish(gen):
+    """→ the list every published segment is also appended to: the log
+    itself is trimmed behind the sink."""
+    published, real = [], gen.broker.publish
+
+    def publish(segs):
+        published.extend(segs)
+        real(segs)
+
+    gen.broker.publish = publish
+    return published
 
 
 @pytest.mark.parametrize("encoders,piece", [(1, 512), (4, 8192), (3, 5120)])
@@ -66,6 +82,7 @@ def test_log_decodes_back_to_the_stream(monkeypatch, encoders, piece):
     monkeypatch.setattr(loadgen, "PIECE", piece)
     seed, n_warm, total = 2**31 + 5, 66_536, 66_536 + 4 * 65_536
     gen = loadgen.Generator(dict(INIT, seed=seed))
+    published = tap_publish(gen)
     try:
         gen.append(0, n_warm)
         gen.start({"loop": "closed_backlog", "chunk_records": 65_536,
@@ -77,14 +94,14 @@ def test_log_decodes_back_to_the_stream(monkeypatch, encoders, piece):
             time.sleep(0.002)
         stats = gen.stop()
         assert stats["produced"] >= total
-        offs, rows = decode_log(gen.broker, 32)
+        offs, rows = decode_segs(published, 32)
         assert np.array_equal(offs, np.arange(stats["produced"]))
         want = Stream(seed, 32, INIT["key_domain"], ZIPF, 16384).rows(
             0, stats["produced"])
         assert np.array_equal(rows.view(np.uint32), want.view(np.uint32))
         # segment boundaries do not move with the threads: 512 from the
         # start of each stretch that was appended
-        bounds = {a for a, _, _ in gen.broker._segs[0]}
+        bounds = {a for a, _, _ in published}
         assert {0, 512, n_warm, n_warm + 512} <= bounds
         assert n_warm - n_warm % 512 in bounds
     finally:
@@ -300,7 +317,9 @@ def test_an_answer_altered_in_the_window_is_not_correct(monkeypatch):
     c = res["compared"]
     assert c["warmup_score_miss_over_tol"]["value"] < 1.0
     assert c["window_score_miss_over_tol"]["value"] > 10.0
-    assert list(res)[-1] == "compared"
+    assert list(res)[-3:] == ["window", "broken", "compared"]
+    assert "window_score_miss_over_tol" in res["broken"]
+    assert list(c)[:len(res["broken"])] == res["broken"]  # the broken first
 
 
 def test_half_a_batch_left_out_is_not_correct(monkeypatch):
@@ -312,3 +331,250 @@ def test_half_a_batch_left_out_is_not_correct(monkeypatch):
     res = tiny_run(monkeypatch, halve)
     assert res["correct"] is False and res["failed"] > 0
     assert res["compared"]["offsets_lost"]["value"] > 0
+    assert "offsets_lost" in res["broken"]
+    assert list(res["compared"])[:len(res["broken"])] == res["broken"]
+
+
+# -- what PR 33 added: the table's stamps, the opening rule, the cores,
+# -- the trimmed log, ``broken`` -------------------------------------------
+
+def test_the_fill_stamps_every_slot_it_places_and_no_other():
+    from flink_jpmml_tpu.runtime.state import KeyedStateTable, StateSpec
+    from flink_jpmml_tpu.utils.metrics import MetricsRegistry
+    from lib import prefill
+
+    cap, n_keys = 61001, 45000  # the rehearsal's table: one key wraps
+    table = KeyedStateTable(
+        StateSpec(capacity=cap, key_col=0, probe=64, decay=0.999,
+                  stride=1 << 20), metrics=MetricsRegistry())
+    said = []
+    prefill.back_mirror(table, said.append)
+    assert not table._touch.any() and not table._occ.any()
+    prefill.apply_fill(table, prefill.plan_fill(n_keys, cap), said.append)
+    assert table.resident == n_keys
+    with pytest.raises(RuntimeError, match="holds no key"):
+        prefill.back_mirror(table, said.append)
+    assert np.array_equal(table._touch != 0, table._occ)
+    assert int(table._occ.sum()) == n_keys
+    # one stamp for the bulk, under every sequence number a later call
+    # takes; the key that wrapped went through the table's own routing
+    stamps = np.unique(table._touch[table._occ])
+    assert stamps[0] == 1 and stamps[-1] <= table._seq
+    assert any("resident bytes" in line and "stamped 1" in line
+               for line in said)
+    # the table finds every key where the fill put it, and stamps on
+    first = prefill.crc32_of_ids(prefill.ids_of_ranks(np.arange(0, 2000)))
+    slots, reset, _, _ = table.assign_slots(
+        np.unique(first), np.zeros(np.unique(first).size, np.int64))
+    assert not reset.any() and (slots != table.scratch).all()
+    assert (table._touch[slots] == table._seq).all()
+
+
+def test_resident_bytes_counts_the_pages_that_were_written():
+    from lib import prefill
+
+    a = np.zeros(1 << 22, np.int64)  # 32 MiB of untouched zero pages
+    before = prefill.resident_bytes(a)
+    if before is None:
+        pytest.skip("mincore is not to be had here")
+    a[:: 512] = 1  # one word a page
+    assert before < a.nbytes // 8 and prefill.resident_bytes(a) >= a.nbytes
+
+
+@pytest.mark.parametrize("n,producer", [(1, 0), (4, 1), (13, 4), (96, 12)])
+def test_the_cores_are_split_by_one_rule(n, producer):
+    from lib import cores
+
+    allowed = [3 * i + 1 for i in range(n)]  # not 0..n-1: a cpuset's own
+    s = cores.split(allowed)
+    assert len(s["producer"]) == producer and s["why"]
+    if not producer:
+        assert s["pipeline"] == [] and "nothing is pinned" in s["why"]
+        return
+    assert set(s["pipeline"]).isdisjoint(s["producer"])
+    assert sorted(s["pipeline"] + s["producer"]) == allowed
+    assert len(s["pipeline"]) >= 2 * len(s["producer"])
+    assert min(s["producer"]) > max(s["pipeline"])
+    assert 1 <= cores.encoders_for(producer) <= 8
+
+
+class _FakeChild:
+    """Answers ``delivered`` as a producer that fills its backlog
+    ``fills_after`` seconds after it was made (never: None)."""
+
+    def __init__(self, fills_after):
+        self.t0, self.fills_after = time.monotonic(), fills_after
+
+    def ask(self, **msg):
+        age = time.monotonic() - self.t0
+        return {"produced": int(1000 * age), "filled": (
+            self.fills_after is not None and age >= self.fills_after)}
+
+
+def test_the_window_does_not_open_before_the_backlog_is_full():
+    import run
+
+    child = _FakeChild(fills_after=0.6)
+    watch = run.LeadWatch(child, lambda: 0)
+    try:
+        started = {"t0": child.t0, "first_offset": 0}
+        full_after = watch.wait_for_backlog(
+            started, {"settle_s": 0.1, "backlog_records": 500}, lambda: None)
+        opened = time.monotonic() - child.t0
+    finally:
+        watch.stop()
+    assert 0.6 <= full_after <= opened < 2.0
+    # and no earlier than the settling, where the backlog fills at once
+    child = _FakeChild(fills_after=0.0)
+    watch = run.LeadWatch(child, lambda: 0)
+    try:
+        watch.wait_for_backlog({"t0": child.t0, "first_offset": 0},
+                               {"settle_s": 0.5, "backlog_records": 500},
+                               lambda: None)
+        assert time.monotonic() - child.t0 >= 0.5
+    finally:
+        watch.stop()
+
+
+def test_a_producer_that_never_fills_its_backlog_gets_no_window(
+        monkeypatch, capsys):
+    """The whole tiny run, its producer held from the start under what
+    the backlog needs: it ends by itself, with the sentence, and well
+    inside a run's time limit."""
+    import rehearse
+    import run
+
+    monkeypatch.setattr(run, "BACKLOG_WAIT_S", 1.5)
+    args = argparse.Namespace(workload=rehearse.cells()[0], seed=2**31 + 33,
+                              seconds=2.0, trace=0)
+    t0 = time.monotonic()
+    with pytest.raises(SystemExit) as stopped:
+        run.run_cell(args, overrides=rehearse.TINY_NEVER_FULL, on_chip=False)
+    assert stopped.value.code == 1 and time.monotonic() - t0 < 120.0
+    err = capsys.readouterr().err
+    assert "did not fill its backlog of 131072 records" in err
+    assert "records/s; no window was opened" in err
+
+
+@pytest.mark.parametrize("which", [0, 1])
+def test_a_starved_tiny_run_names_the_lead(which):
+    """Each cell, held from the moment its backlog was full: the window
+    opens, the pipeline drains the log, and the result says which number
+    broke (``rehearse.py``'s third run of a cell)."""
+    import jax
+
+    import rehearse
+    import run
+
+    cell = rehearse.cells()[which]
+    args = argparse.Namespace(workload=cell, seed=2**31 + 34, seconds=2.0,
+                              trace=0)
+    chips = run.load_cell(args, None)[0]["chips"]
+    if len(jax.devices()) < chips:
+        pytest.skip(f"{cell} needs {chips} devices")
+    res = run.run_cell(args, overrides=rehearse.TINY_STARVED, on_chip=False)
+    assert res["correct"] is False and res["failed"] == 0
+    # (with a cold compile cache a CPU run may also compile in its window)
+    named = [n for n in res["broken"] if n != "compilations_in_window"]
+    assert named and all(n.startswith("least_lead_records.") for n in named)
+    assert list(res["compared"])[:len(res["broken"])] == res["broken"]
+    assert res["compared"]["backlog_full_after_s"]["holds"]
+
+
+def test_compared_puts_the_broken_first_and_leaves_no_fault_unnamed():
+    import run
+
+    compared = [("warmup_score_miss_over_tol", 0.2, 1.0),
+                ("offsets_lost", 3, 0),
+                ("least_lead_records.harness", 10, 5, True),
+                ("counter.state_overflow", 0, 0),
+                ("least_lead_records.producer", None, 5, False)]
+    got = run.settle_compared(compared, ["two faults", "said"])
+    assert [e[0] for e in got] == [
+        "offsets_lost", "least_lead_records.producer",
+        "least_lead_records.harness", "counter.state_overflow",
+        "warmup_score_miss_over_tol"]
+    assert [e[3] for e in got] == [False, False, True, True, True]
+    # a fault whose check gave no number that does not hold gets one
+    got = run.settle_compared(compared[:1], ["the state check said so"])
+    assert got[0] == ("faults_without_a_number", 1, 0, False)
+    assert run.settle_compared(compared[:1], []) == [
+        ("warmup_score_miss_over_tol", 0.2, 1.0, True)]
+
+
+def test_a_trimmed_log_still_serves_every_offset_after_the_warm_up():
+    """The producer's own loop with a real consumer behind it: the log
+    is trimmed behind the sink, and what the consumer read from the
+    warm-up's end on has the digest of the stream, offset by offset."""
+    from flink_jpmml_tpu.runtime.kafka import KafkaBlockSource
+    from flink_jpmml_tpu.utils.metrics import MetricsRegistry
+
+    seed, n_warm, backlog = 2**31 + 35, 4196, 32_768
+    gen = loadgen.Generator(dict(INIT, seed=seed, pool_rows=512))
+    source, read, hi = None, hashlib.sha256(), n_warm
+    try:
+        gen.append(0, n_warm)
+        source = KafkaBlockSource(gen.broker.host, gen.broker.port, "bench",
+                                  n_cols=32, max_wait_ms=5,
+                                  metrics=MetricsRegistry())
+        source.seek(n_warm)
+        gen.start({"loop": "closed_backlog", "chunk_records": 8192,
+                   "backlog_records": backlog,
+                   "producer_max_records_per_s": None}, n_warm)
+        deadline = time.monotonic() + 120.0
+        while hi < n_warm + 12 * backlog and time.monotonic() < deadline:
+            item = source.poll()
+            if item is None:
+                continue
+            first, rows = item
+            assert first == hi
+            read.update(np.ascontiguousarray(rows).tobytes())
+            hi = first + rows.shape[0]
+            gen.note_delivered(hi)
+        stats = gen.stop()
+        assert hi >= n_warm + 12 * backlog
+        # the log was trimmed: its first segment starts past the
+        # warm-up, within two backlogs and a chunk of the sink
+        kept_from = gen.broker._segs[0][0][0]
+        assert n_warm < kept_from and hi - kept_from <= 2 * backlog + 8192
+        assert stats["backlog_full_after_s"] is not None
+    finally:
+        if source is not None:
+            source.close()
+        gen.close()
+    want = Stream(seed, 32, INIT["key_domain"], ZIPF, 512).rows(n_warm, hi)
+    assert read.hexdigest() == hashlib.sha256(want.tobytes()).hexdigest()
+
+
+def test_a_producer_held_from_the_fill_keeps_to_its_rate_after_it():
+    gen = loadgen.Generator(dict(INIT, seed=4))
+    try:
+        gen.start({"loop": "closed_backlog", "chunk_records": 8192,
+                   "backlog_records": 65_536, "producer_max_from": "filled",
+                   "producer_max_records_per_s": 20_000}, 0)
+        deadline = time.monotonic() + 60.0
+        while not gen.filled and time.monotonic() < deadline:
+            time.sleep(0.005)
+        assert gen.filled
+        full = gen.broker.produced
+        gen.note_delivered(full)  # the sink takes the whole backlog
+        time.sleep(1.0)
+        stats = gen.stop()
+        assert 8192 <= stats["produced"] - full <= 20_000 * 1.5 + 8192
+    finally:
+        gen.close()
+
+
+def test_the_score_thread_reader_books_the_mesh_stages():
+    stages = {"drain": 0.10, "encode": 0.10, "route": 0.10, "h2d": 0.10,
+              "shard": 0.25, "unshard": 0.05, "queue_wait": 0.10, "sink": 0.05,
+              "fetch": 5.0, "decode": 5.0, "prefetch_wait": 5.0}
+    hist = {f'stage_seconds{{stage="{k}"}}': {"sum": v, "n": 3}
+            for k, v in stages.items()}
+    ctx = {"snap0": {"histograms": {}, "counters": {}, "ts": 10.0},
+           "snap1": {"histograms": hist, "counters": {}, "ts": 11.0}}
+    read = byname.load("layer_metrics", "score_thread_booked_frac.sat").read
+    assert read(ctx) == pytest.approx(85.0)
+    del hist['stage_seconds{stage="shard"}']
+    del hist['stage_seconds{stage="unshard"}']
+    assert read(ctx) == pytest.approx(55.0)  # one chip books neither
