@@ -50,6 +50,13 @@ Stage semantics (who observes what):
 - ``route``     — keyed state's host routing on a state-armed
                   dispatch: key hashing, ``maybe_renorm``,
                   ``assign_slots``, the pad rows;
+- ``claim``     — inside ``route``, and booked there too (a span is
+                  a whole interval): the numpy claim rounds of
+                  ``KeyedStateTable.route`` for the records its native
+                  pass left — fresh keys, full probe windows, the
+                  evictions. Nothing is booked where no record is left
+                  (a stream of resident keys), so a sum over the
+                  score thread's stages leaves ``claim`` out;
 - ``shard``     — the keyed shuffle of a state table over a mesh
                   (runtime/shuffle.py): owner and rank of each held
                   record, the cut where a chip's bucket fills
@@ -115,9 +122,9 @@ from flink_jpmml_tpu.obs import trace as trace_mod
 from flink_jpmml_tpu.utils.metrics import Histogram, MetricsRegistry
 
 STAGES = (
-    "fetch", "decode", "prefetch_wait", "drain", "encode", "route", "shard",
-    "h2d", "queue_wait", "device", "readback", "unshard", "sink", "commit",
-    "prof_sample",
+    "fetch", "decode", "prefetch_wait", "drain", "encode", "route", "claim",
+    "shard", "h2d", "queue_wait", "device", "readback", "unshard", "sink",
+    "commit", "prof_sample",
 )
 
 # which thread each stage is observed on — rendered as the fjt-top
@@ -133,6 +140,7 @@ STAGE_THREADS = {
     "drain": "score",
     "encode": "score",
     "route": "score",
+    "claim": "score",
     "shard": "score",
     "h2d": "score",
     "queue_wait": "score",
@@ -143,6 +151,11 @@ STAGE_THREADS = {
     "commit": "score",
     "prof_sample": "score",
 }
+
+# stages whose spans lie inside another stage's on the same thread:
+# their time is the outer stage's too, so a sum over stages leaves them
+# out (``summary``'s shares are of the un-nested total)
+NESTED_IN = {"claim": "route"}
 
 # the name a stage's span carries on the profiler's clock
 ANNOTATION_PREFIX = "fjt."
@@ -525,7 +538,8 @@ def summary(struct_or_registry) -> Optional[dict]:
         if h.count() == 0:
             continue
         s = h.sum()
-        total += s
+        if stage not in NESTED_IN:
+            total += s
         out[stage] = {
             "n": h.count(),
             "total_ms": round(1000.0 * s, 3),

@@ -24,10 +24,17 @@ Division of labor — host routes, device accumulates:
   (``_native/fjt_native.cpp``, which also hashes the block's key
   column); the records it leaves go through vectorized rounds over
   their deduped keys: probe the window, claim empties, evict the
-  least-recently-touched slot when the window is full. Which step
-  resolves a record follows from the mirror alone, and the rounds
-  alone (the library missing) give the same answer. No device round
-  trip is involved in routing.
+  least-recently-touched slot when the window is full. A key that is
+  not resident and finds its window full takes the slot of its window
+  that was touched longest ago and not in this routing call; where
+  several keys of one call want the same slot the smallest hash has
+  it and the others choose again from what is left of their own
+  windows, so a key that loses an eviction race keeps its state
+  (``_claim_rounds`` states the rule in full). A key goes to the
+  scratch row (``state_overflow``) only when every slot of its window
+  was touched in this very call. Which step resolves a record follows
+  from the mirror alone, and the rounds alone (the library missing)
+  give the same answer. No device round trip is involved in routing.
 - **Device values.** The table's VALUES — one fixed-width f32 vector
   per slot (counts, sums, decayed counters in product form, last-seen
   stride, min/max) — live in a single ``[rows, STATE_WIDTH]`` device
@@ -103,6 +110,7 @@ from typing import Any, Callable, Dict, Optional
 
 import numpy as np
 
+from flink_jpmml_tpu.obs import attr
 from flink_jpmml_tpu.obs import recorder as flight
 from flink_jpmml_tpu.parallel.partitioner import stable_hash, stable_hash_vec
 from flink_jpmml_tpu.runtime import native
@@ -238,6 +246,7 @@ class KeyedStateTable:
         # records ``route`` left to its claim rounds (0 on a stream of
         # resident keys: the native pass resolved every one)
         self._c_pending = m.counter("state_route_pending")
+        self._ledger = attr.ledger_for(m)
         self._c_rollbacks = m.counter("state_rollbacks")
         self._g_resident = m.gauge("state_resident_keys")
         self._g_occupancy = m.gauge("state_occupancy_frac")
@@ -443,7 +452,15 @@ class KeyedStateTable:
         what that pass leaves (fresh keys, a hole before the key's row,
         an exhausted window) goes through ``_claim_rounds``, as every
         record does where the native library cannot be built.
-        ``state_route_pending`` counts the records left to the rounds.
+        ``state_route_pending`` counts the records left to the rounds,
+        and the ledger's ``claim`` stage their time (inside the
+        caller's ``route`` span). A key the rounds find no slot for in
+        a full window evicts the least recently touched slot of that
+        window that this call has not touched, and a key that loses
+        such a slot to another key of the call chooses again: the rule
+        is ``_claim_rounds``'. Only a key whose whole window this call
+        has touched is answered with the scratch slot
+        (``state_overflow``).
 
         ``held`` are the slots of records an earlier call routed and no
         dispatch has taken yet: they count as touched by this call, so
@@ -474,7 +491,11 @@ class KeyedStateTable:
             self._touch[hs[hs < self.capacity]] = seq
         n_todo = int(todo.sum())
         if n_todo:
-            slots[todo], reset[todo], c = self._claim_rounds(khash[todo], seq)
+            # a stage of its own inside the caller's ``route`` span:
+            # nothing is booked where the rounds do not run
+            with self._ledger.span("claim", n=n_todo):
+                slots[todo], reset[todo], c = self._claim_rounds(
+                    khash[todo], seq)
             collided += c
             self._c_pending.inc(n_todo)
         if n_bypass < B:
@@ -496,37 +517,64 @@ class KeyedStateTable:
 
     def _claim_rounds(self, khash: np.ndarray, seq: int):
         """Resolve records by vectorized rounds over their unique keys:
-        probe a bounded linear window a slot a round, take a match,
-        claim an empty, and evict the least-recently-touched slot of a
-        window that is full → ``(slots int32, reset bool, collided)``,
-        one of each a record, ``collided`` the unique keys not resolved
-        at their home slot."""
+        probe a bounded linear window a slot a round (after the home
+        slot a window that holds no empty slot is read whole: nothing
+        can change in it), take a match, claim an empty, and evict
+        from a window that is full →
+        ``(slots int32, reset bool, collided)``, one of each a record,
+        ``collided`` the unique keys not resolved at their home slot.
+
+        **The eviction rule** (written down here and nowhere else). A
+        routing call touches a slot when a record of it belongs to the
+        key that lives there, when it gives the slot to a key, and when
+        ``held`` names it; a touched slot carries the call's number as
+        its stamp, newer than every stamp before. A key of the call
+        that is not resident, and whose probe window has no empty slot,
+        takes a slot from the key that lives there, whose row is reset
+        before the gather:
+
+        1. it names the slot of its own window that was touched longest
+           ago, of those this call has not touched; of two slots with
+           the same stamp, the one that comes first on the way from the
+           key's home slot through its window;
+        2. where several keys of the call name the same slot, the key
+           with the smallest hash has it; the slot is then touched by
+           this call and nobody's to name;
+        3. every key that named a slot and did not get it names again,
+           by 1, from what is left of its own window, until each key
+           has a slot: losing a race costs a key nothing but its first
+           choice;
+        4. a key has nothing to name only when this call has touched
+           every slot of its window. It alone is answered with the
+           scratch slot, its records folded nowhere
+           (``state_overflow``).
+
+        An EMPTY slot is won as it always was: in probe order, one
+        claimant a slot a round, the smallest hash first."""
         uk, inv = np.unique(khash, return_inverse=True)
-        nu = uk.shape[0]
-        base = uk.astype(np.int64) % self.capacity
-        slot_u = np.full(nu, -1, np.int64)
-        reset_u = np.zeros(nu, bool)
+        cap, probe = self.capacity, self.spec.probe
+        base = uk.astype(np.int64) % cap
+        slot_u = np.full(uk.shape[0], -1, np.int64)
+        reset_u = np.zeros(uk.shape[0], bool)
         keys_h, occ, touch = self._keys, self._occ, self._touch
         collided = 0
-        for p in range(self.spec.probe):
-            pending = slot_u < 0
-            if not pending.any():
+        idx = np.arange(uk.shape[0])  # the keys still probing, ascending
+        for p in range(probe):
+            if not idx.size:
                 break
-            cand = (base + p) % self.capacity
-            hit = pending & occ[cand] & (keys_h[cand] == uk)
-            slot_u[hit] = cand[hit]
+            cand = (base[idx] + p) % cap
+            taken = occ[cand]
+            hit = taken & (keys_h[cand] == uk[idx])
+            slot_u[idx[hit]] = cand[hit]
             # stamp at hit/claim time, not batch end: the evict
             # round must see THIS batch's slots as untouchable
             touch[cand[hit]] = seq
-            pending &= ~hit
-            empty = pending & ~occ[cand]
-            idx = np.flatnonzero(empty)
-            if idx.size:
+            e = np.flatnonzero(~taken)
+            if e.size:
                 # one claimant per empty slot per round (np.unique
                 # keeps the first); losers keep probing
-                _, first = np.unique(cand[idx], return_index=True)
-                win = idx[first]
-                c = cand[win]
+                _, first = np.unique(cand[e], return_index=True)
+                win, c = idx[e[first]], cand[e[first]]
                 slot_u[win] = c
                 occ[c] = True
                 keys_h[c] = uk[win]
@@ -534,37 +582,50 @@ class KeyedStateTable:
                 reset_u[win] = True
                 self.resident += win.size
                 self._c_inserts.inc(win.size)
+            idx = idx[slot_u[idx] < 0]
             if p == 0:
                 # catalogue semantic: home slot held by a DIFFERENT
                 # key — a fresh key claiming its empty home slot is
                 # not a collision, so count after the claim round
-                collided = int((slot_u < 0).sum())
+                collided = int(idx.size)
+                # Whoever is off its home slot has its whole window
+                # read at once. A window without an empty slot cannot
+                # change in the rounds to come (they only claim
+                # empties): its key is on the first slot that holds
+                # its hash, or waits for the eviction; only a key that
+                # sees an empty slot goes on a slot a round
+                W = (base[idx, None] + np.arange(probe)[None, :]) % cap
+                full = occ[W].all(axis=1)
+                at = keys_h[W] == uk[idx, None]
+                found = full & at.any(axis=1)
+                s = W[found, at[found].argmax(axis=1)]
+                slot_u[idx[found]] = s
+                touch[s] = seq
+                idx = idx[~full]
+        # probe window exhausted: the eviction rule of the docstring
         pend = np.flatnonzero(slot_u < 0)
-        if pend.size:
-            # probe window exhausted: evict the least-recently-
-            # touched slot in each key's window — but never one
-            # touched THIS batch (another key just landed there);
-            # keys that lose the eviction race overflow to scratch
-            W = (base[pend, None]
-                 + np.arange(self.spec.probe)[None, :]) % self.capacity
-            t = touch[W]
-            vic = W[np.arange(pend.size), np.argmin(t, axis=1)]
-            fresh_vic = touch[vic] < seq
+        W = (base[pend, None] + np.arange(probe)[None, :]) % cap
+        while pend.size:
+            # 1: argmin takes the first of equal stamps, in probe order
+            vic = W[np.arange(pend.size), np.argmin(touch[W], axis=1)]
+            named = touch[vic] < seq
+            if not named.all():
+                # 4: the oldest stamp of the window is this call's own
+                self._c_overflow.inc(int(pend.size - named.sum()))
+                pend, W, vic = pend[named], W[named], vic[named]
+            # 2: ``pend`` ascends with the hash, and np.unique keeps
+            # the first to name a slot
             _, first = np.unique(vic, return_index=True)
-            winner = np.zeros(pend.size, bool)
-            winner[first] = True
-            winner &= fresh_vic
-            win = pend[winner]
-            c = vic[winner]
-            if win.size:
-                keys_h[c] = uk[win]
-                touch[c] = seq
-                slot_u[win] = c
-                reset_u[win] = True
-                self._c_evictions.inc(win.size)
-            lost = int(pend.size - win.size)
-            if lost:
-                self._c_overflow.inc(lost)
+            win, c = pend[first], vic[first]
+            keys_h[c] = uk[win]
+            touch[c] = seq
+            slot_u[win] = c
+            reset_u[win] = True
+            self._c_evictions.inc(win.size)
+            # 3: the others go again
+            lost = np.ones(pend.size, bool)
+            lost[first] = False
+            pend, W = pend[lost], W[lost]
         slot_r = np.where(slot_u >= 0, slot_u, np.int64(self.scratch))
         return slot_r[inv].astype(np.int32), reset_u[inv], collided
 

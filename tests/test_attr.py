@@ -170,6 +170,22 @@ class TestStageLedger:
         assert attr.summary(MetricsRegistry()) is None
         assert attr.summary({}) is None
 
+    def test_a_nested_stage_is_no_term_of_the_total(self):
+        """``claim`` lies inside ``route`` (ISSUE 34): its time is
+        route's too, so the shares are of the total without it."""
+        m = MetricsRegistry()
+        led = attr.StageLedger(m)
+        led.observe("route", 0.06)
+        led.observe("claim", 0.03)
+        led.observe("encode", 0.04)
+        s = attr.summary(m)
+        assert attr.NESTED_IN["claim"] == "route"
+        assert attr.STAGE_THREADS["claim"] == attr.STAGE_THREADS["route"]
+        assert s["route"]["share"] == pytest.approx(0.6, abs=0.01)
+        assert s["claim"]["share"] == pytest.approx(0.3, abs=0.01)
+        assert s["route"]["share"] + s["encode"]["share"] == pytest.approx(
+            1.0, abs=0.01)
+
 
 class TestExemplarFlightLinkage:
     def test_top_bucket_observation_links_scrape_to_flight(self):

@@ -11,3 +11,4 @@ sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
 
 from test_harness import *  # noqa: E402,F401,F403
 from test_mesh_cell import *  # noqa: E402,F401,F403
+from test_churn_cell import *  # noqa: E402,F401,F403

@@ -12,6 +12,9 @@ contract extended to state: a DLQ'd batch must never leave folds in
 the table (rollback-to-snapshot semantics, deterministic with no
 checkpoint pinned)."""
 
+import os
+import sys
+
 import numpy as np
 import pytest
 
@@ -292,6 +295,187 @@ class TestNativeRouteParity:
     def test_random_stream_at_load_09(self, monkeypatch):
         pending = _route_both_ways(_random_stream(), 64, 8, monkeypatch)
         assert 0 < pending < 200 * 48
+
+
+# -- the eviction race on a full table (ISSUE 34) -----------------------------
+#
+# Keys of one call whose windows share their least recently touched slot
+# all name it. The smallest hash has it; the others name again from what
+# is left of their own windows, and only a key whose whole window this
+# call has touched goes to scratch (``_claim_rounds``' docstring). Every
+# case runs both ways (``_route_both_ways``: native pass + rounds against
+# the rounds alone, slots, resets, mirror and counters after every call)
+# and is then held to the slots the rule gives, written out by hand.
+# Capacity 16, probe 4; ``_AT_3`` fills the window 3..6 in call 1 and call
+# 2 touches all of it but slot 3, so slot 3 is every newcomer's first
+# choice and slots 4, 5, 6 (equal stamps) follow in probe order.
+
+_FULL_AT_3 = [_call(_AT_3, 0), _call([19, 35, 51], 4)]
+_RACE_CASES = {
+    # 67 < 83: 67 has slot 3, 83 names again and takes 4
+    "two_fresh_keys_one_victim": (
+        _call([67, 83, 67], 7), [3, 4, 3], [67, 83, 35, 51], (2, 0)),
+    "three_fresh_keys_one_victim": (
+        _call([99, 67, 83], 7), [5, 3, 4], [67, 83, 99, 51], (3, 0)),
+    # 51's record touches slot 6: the three fresh keys share 3, 4, 5
+    "a_resident_key_of_the_call_is_nobodys_victim": (
+        _call([51, 99, 83, 67], 7), [6, 5, 4, 3], [67, 83, 99, 51], (3, 0)),
+    # slot 3 is held: 67's first choice is 4, 83 loses it and takes 5
+    "a_victim_stamped_by_held": (
+        _call([83, 67], 7, held=[3]), [5, 4], [3, 67, 83, 51], (2, 0)),
+    # five fresh keys, four slots: 131 finds its whole window touched
+    "a_window_wholly_touched_in_the_call_overflows": (
+        _call([131, 67, 83, 99, 115], 7), [16, 3, 4, 5, 6],
+        [67, 83, 99, 115], (4, 1)),
+}
+
+
+class TestEvictionRace:
+    @pytest.mark.parametrize("name", sorted(_RACE_CASES))
+    def test_a_loser_goes_again_in_its_own_window(self, name, monkeypatch):
+        race, slots, window, (evicted, overflowed) = _RACE_CASES[name]
+        if native_mod.available():
+            _route_both_ways(_FULL_AT_3 + [race], 16, 4, monkeypatch)
+        t, m = _table(16, 4)
+        for call in _FULL_AT_3:
+            t.route(call["khash"], np.arange(
+                call["first"], call["first"] + call["khash"].size))
+        seq = t._seq + 1
+        kh = race["khash"]
+        got, reset, _ = t.route(
+            kh, np.arange(7, 7 + kh.size), held=race.get("held"))
+        assert got.tolist() == slots
+        fresh = np.isin(kh, [67, 83, 99, 115]) & (got != t.scratch)
+        assert np.array_equal(reset, fresh)
+        assert t._keys[3:7].tolist() == window and t._occ[3:7].all()
+        touched = sorted(set(slots) - {16} | set(race.get("held", [])))
+        assert (t._touch[touched] == seq).all()
+        assert (np.delete(t._touch[3:7], np.array(touched) - 3) < seq).all()
+        c = m.struct_snapshot()["counters"]
+        assert (c["state_evictions"], c["state_overflow"]) == (
+            evicted, overflowed)
+        assert c["state_inserts"] == 4 and t.resident == 4
+
+    def test_the_rounds_book_a_claim_stage_only_when_they_run(self):
+        t, m = _table(capacity=8, probe=3)
+        key = 'stage_seconds{stage="claim"}'
+        t.route(np.array([0, 8], np.uint32), np.arange(2))
+        n1 = m.struct_snapshot()["histograms"][key]["n"]
+        t.route(np.array([0, 8, 8], np.uint32), np.arange(2, 5))
+        h = m.struct_snapshot()["histograms"][key]
+        # resident keys alone: the native pass leaves nothing, no span
+        assert h["n"] == (n1 if native_mod.available() else n1 + 1)
+
+
+# A "latest" stream (YCSB workload D's key arrival, the benchmark's key
+# mix) over a FULL table at probe 8, 200 routing calls, against the
+# benchmark's plain reference (``benchmark/reference/churn_ref.py``: plain
+# loops, nothing of this module in it) under the same rule: the same keys
+# admitted and evicted, every key in the reference's slot, and every
+# key's row (count, score sum) as the reference says, with the fold
+# written out in numpy (reset rows zeroed, then one add a record).
+
+_BENCH = os.path.join(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))), "benchmark")
+
+
+def _parent_claim_rounds(self, khash, seq):
+    """``_claim_rounds`` as it was before ISSUE 34, for the probing of a
+    FULL table (no empty slot, so no claim): a key that loses an
+    eviction race overflows to scratch."""
+    uk, inv = np.unique(khash, return_inverse=True)
+    base = uk.astype(np.int64) % self.capacity
+    W = (base[:, None] + np.arange(self.spec.probe)[None, :]) % self.capacity
+    at = self._occ[W] & (self._keys[W] == uk[:, None])
+    slot_u = np.where(at.any(axis=1), W[np.arange(uk.size), at.argmax(1)], -1)
+    self._touch[slot_u[slot_u >= 0]] = seq
+    reset_u = np.zeros(uk.size, bool)
+    pend = np.flatnonzero(slot_u < 0)
+    if pend.size:
+        vic = W[pend, np.argmin(self._touch[W[pend]], axis=1)]
+        fresh_vic = self._touch[vic] < seq
+        _, first = np.unique(vic, return_index=True)
+        winner = np.zeros(pend.size, bool)
+        winner[first] = True
+        winner &= fresh_vic
+        win, c = pend[winner], vic[winner]
+        self._keys[c], self._touch[c] = uk[win], seq
+        slot_u[win], reset_u[win] = c, True
+        self._c_evictions.inc(win.size)
+        self._c_overflow.inc(int(pend.size - win.size))
+    slot_r = np.where(slot_u >= 0, slot_u, np.int64(self.scratch))
+    return slot_r[inv].astype(np.int32), reset_u[inv], int(
+        (slot_u != base).sum())
+
+
+def _latest_stream_against_the_reference(seed):
+    """→ what differs between the table and the plain reference after
+    200 calls: ``{name: count}``."""
+    if _BENCH not in sys.path:
+        sys.path.insert(0, _BENCH)
+    from lib import byname, keys as keys_mod, prefill
+    from reference import churn_ref
+
+    latest = byname.load("lib/keymix", "latest")
+    fill_full = byname.load("paths", "block_full").fill_full
+    cap, probe, domain = 4093, 8, 16000
+    loaded = latest.loaded_of(domain)
+    fill = fill_full(cap, probe, loaded, 64, lambda line: None)
+    t, m = _table(cap, probe)
+    t._keys[:], t._occ[:], t._touch[:] = fill["keys"], fill["occ"], fill["touch"]
+    t.resident, t._seq = cap, int(fill["touch"].max())
+    ref = churn_ref.WindowTable(
+        fill["keys"].copy(), fill["occ"].copy(), fill["touch"].copy(), probe)
+    want = churn_ref.Rows()
+    first_rows = prefill.initial_rows(seed, np.arange(cap + 1))[:, :2].astype(
+        np.float64)
+    first_rows[cap] = 0.0
+    rows = first_rows.copy()
+    rng = np.random.default_rng([34, seed])
+    n = 20000
+    mix = {"kind": "latest", "insert_share": 0.05, "zipf_constant": 0.99}
+    kh = t.hash_keys(keys_mod.rank_to_id(latest.ranks(0, seed, domain, mix, n)))
+    scores = rng.normal(size=n)
+    cuts = np.sort(rng.choice(np.arange(1, n), 199, replace=False))
+    for lo, hi in zip(np.r_[0, cuts], np.r_[cuts, n]):
+        slots, reset, _ = t.route(kh[lo:hi], np.arange(lo, hi))
+        rows[np.unique(slots[reset])] = 0.0
+        np.add.at(rows, slots, np.stack(
+            [np.ones(hi - lo), scores[lo:hi]], axis=1))
+        want.fold(ref, kh[lo:hi], scores[lo:hi])
+    c = m.struct_snapshot()["counters"]
+    hashes = sorted(want.slot)
+    slot = np.array([want.slot[h] for h in hashes])
+    want_n, want_s = want.expected(hashes, first_rows[slot])
+    return {
+        "admitted": int(c["state_inserts"] + c["state_evictions"]
+                        - ref.admitted),
+        "evicted": int(c["state_evictions"] - ref.evicted),
+        "overflowed": int(c["state_overflow"]), "evictions": ref.evicted,
+        "keys_elsewhere": int((t._keys[slot] != np.array(
+            hashes, np.uint32)).sum()),
+        "counts": int((rows[slot, 0] != want_n).sum()),
+        "sums": int((np.abs(rows[slot, 1] - want_s) > 1e-9).sum()),
+        "scratch_records": int(rows[cap, 0]),
+    }
+
+
+@pytest.mark.parametrize("seed", [3, 2**31 + 17])
+def test_a_latest_stream_over_a_full_table_equals_the_plain_reference(seed):
+    d = _latest_stream_against_the_reference(seed)
+    assert d.pop("evictions") > 1000
+    assert d == dict.fromkeys(d, 0)
+
+
+def test_the_parents_rounds_fail_the_plain_reference(monkeypatch):
+    """The same comparison on ``_claim_rounds`` as it was: keys that lose
+    an eviction race overflow, their records land on the scratch row, and
+    admissions, slots and rows part from the reference's."""
+    monkeypatch.setattr(native_mod, "available", lambda: False)
+    monkeypatch.setattr(KeyedStateTable, "_claim_rounds", _parent_claim_rounds)
+    d = _latest_stream_against_the_reference(3)
+    assert d["overflowed"] > 0 and d["scratch_records"] > 0
+    assert d["admitted"] != 0 and d["counts"] > 0
 
 
 @pytest.fixture(scope="module")

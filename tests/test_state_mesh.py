@@ -517,6 +517,49 @@ def test_no_eviction_takes_a_row_a_held_record_points_to(
         assert stolen > 0 and c["state_evictions"] > 0
 
 
+def test_an_eviction_race_under_a_held_tail_drops_nobodys_state():
+    """The race of ISSUE 34 through the shuffle on four chips, with a cut
+    and a held tail (the mesh cell does not evict; a mesh deployment
+    may). The table is full; its least recently touched slot is key
+    103's, then 104..107's; a hot key cuts the dispatch and the tail
+    holds the slots of 100, 101 and 102. Three keys the table has never
+    seen arrive in ONE routing call: all name 103's slot, the smallest
+    hash has it, the others choose again among 104..107's, nobody takes
+    a held slot and nobody overflows."""
+    m = MetricsRegistry()
+    t = KeyedStateTable(StateSpec(capacity=8, probe=8), metrics=m,
+                        mesh=_mesh(4))
+    sh = shuffle_mod.KeyShuffle(t, 4, 4, (1,), 16, m)
+    ledger = attr.ledger_for(m)
+
+    def block(keys, first):
+        X = np.zeros((len(keys), 4), np.float32)
+        X[:, 0] = keys
+        sh.feed(X, np.arange(first, first + len(keys)))
+
+    old = t.hash_keys(np.arange(100, 108))
+    home, _, _ = t.route(old, np.arange(8))
+    t.route(old[4:], np.arange(8, 12))
+    assert t.resident == 8 and sorted(home.tolist()) == list(range(8))
+    block([100] * 5 + [101, 102], 12)
+    X, offs, n, plan = sh.take(ledger)
+    assert n == 4 and plan.cut and sh.pending == 3
+    held = set(home[:3].tolist())
+    block([200, 201, 202], 19)
+    stolen = 0
+    while sh.pending:
+        X, offs, n, plan = sh.take(ledger)
+        stolen += _stolen(t, X, plan)
+    fresh = t.hash_keys(np.arange(200, 203))
+    at = {int(h): int(np.flatnonzero(t._keys == h)[0]) for h in fresh}
+    c = m.struct_snapshot()["counters"]
+    assert stolen == 0
+    assert (c["state_evictions"], c["state_overflow"]) == (3, 0)
+    assert len(set(at.values())) == 3 and not held & set(at.values())
+    assert at[int(fresh.min())] == int(home[3])
+    assert np.isin(old[:3], t._keys).all() and old[3] not in t._keys
+
+
 @pytest.mark.skipif(not native.available(), reason="no native library")
 def test_route_pending_counts_what_the_native_pass_leaves_to_the_rounds():
     """Through the shuffle, cuts and held tails included: a stream whose
