@@ -5,43 +5,68 @@ EXISTING scoring dispatch. A state-armed dispatch runs ONE compiled
 program per batch:
 
     out            = member kernel(params, X)        # unchanged
-    derived[B, 8]  = gather(S, slots) → session features
-    S'             = scatter-add/min/max(S, slots, f(out, w, rel))
+    sorted         = sort(slots)            # the dispatch, grouped by row
+    derived[B, 8]  = gather(S, sorted) → session features
+    S'             = set(S, one row a group, f(row, out, w, rel))
 
-The state stage is pure XLA gather/scatter over the batch's slot
-vector, appended to the scoring program, and composes with EVERY
-backend the scorer already has: XLA, Pallas (the state ops wrap the
-scan-chunked kernel, outside the Pallas grid), fused-encode, and
-cross-model packs. No new Pallas kernel is warranted — but the work is
-O(batch) only where a write covers WHOLE ROWS. On the chip the table
-is column-major, ``f32[rows, 8]{0,1:T(8,128)}``: one slot's row is one
-lane of an (8, 128) tile. What the TPU compiler makes of a table write
-(read off a v5e compile; tests/test_v5e_compile.py, PERF.md §5):
+The state stage is pure XLA over the batch's slot vector, appended to
+the scoring program, and composes with EVERY backend the scorer already
+has: XLA, Pallas (the state ops wrap the scan-chunked kernel, outside
+the Pallas grid), fused-encode, and cross-model packs. No new Pallas
+kernel is warranted — but the work is O(batch) only where a write
+covers WHOLE ROWS. On the chip the table is column-major,
+``f32[rows, 8]{0,1:T(8,128)}``: one slot's row is one lane of an
+(8, 128) tile. What the TPU compiler makes of a table write (read off a
+v5e compile; tests/test_v5e_compile.py, PERF.md §5):
 
 - a scatter of whole rows (``S.at[slots].set/.max/.min(rows8)``) stays
-  a native scatter, in place on the donated buffer: ~5.5 ms for 65,536
+  a native scatter, in place on the donated buffer: ~6 ms for 65,536
   records at 200M slots, duplicates and all;
 - a scatter into ONE column (``S.at[slots, c].max``, what last_t, min
   and max were until PR 25) flattens the table a column at a time into
   ``f32[rows * 8]``, scatters there and copies back: O(table) a
-  dispatch, 9.6 GB of temporaries, ~430 of a dispatch's 665 ms. Those
-  three are now two whole-row scatters whose other columns carry the
-  operation's identity (−inf for max, +inf for min);
+  dispatch, 9.6 GB of temporaries, ~430 of a dispatch's 665 ms;
 - a scatter into a SLICE of columns (what the add of the five
   accumulator columns was until PR 28) is expanded to a ``while`` of
   one iteration a record, ~3.5 µs each: ~230 ms for 65,536 records.
-  The add is now a whole-row scatter too, its other three columns
-  carrying −0.0 (``x + (−0.0)`` is ``x`` for every float32, −0.0 and
-  ±inf included; +0.0 would turn a stored −0.0 into +0.0): no
-  ``while`` is left in the program.
+
+So the table is touched twice a dispatch, and both times by whole rows
+(PR 35; until then a reset, an add, a max and a min each walked it,
+24.5 ms of a 65,536-record dispatch where this form takes 8.7; numbers:
+a v5e, the deployment's table, PERF.md §6):
+
+- the dispatch is sorted by slot on the chip. ONE gather reads the
+  sorted rows (0.9–1.4 ms), and a group that carries a fresh-slot mark on
+  any of its records starts from ``_INIT_ROW`` instead (a ``where`` on
+  the gathered rows: no reset pass over the table);
+- each group's five sums, two maxima and minimum are scanned along the
+  sorted order in log2(B) shifted steps (``_group_scan``, ~0.5 ms), no
+  table access, the total on the group's last record;
+- ONE scatter sets ``combine(prior row, totals)`` for the last record
+  of each group, whole rows, every other record's aim off the table
+  and dropped. It costs ~92 ns an INDEX, dropped or not, aimed at one
+  row or at many (6.0 ms of the 8.7): compacting the live rows to the
+  front buys nothing, a shorter index vector would.
+
+What the sort may carry is set by what it costs to COMPILE, not to run
+(0.3 ms): the TPU compiler takes 4 s for an unstable sort of 65,536
+``(slot, index)`` pairs, 8 s for the stable one, 40–185 s once a
+float32 is among the keys or six operands ride along, and a pipeline
+compiles one program a dispatch size. So the sort is ``(slot, index)``,
+unstable, and score, rel, w and reset follow it through one ``[B, 4]``
+row gather; ``derived`` goes back to arrival order through the sort's
+inverse (a 1-D scatter of ``[B]``, which compiles to a native scatter;
+a ``[B, 8]`` row scatter makes the compiler sort again).
 
 Batch-consistent read semantics: every record's DERIVED features
-reflect the table as of the BATCH start (one gather before the
-batch's updates commit), and the updates themselves are scatter-ADD /
--MIN / -MAX with product-form decay weights — commutative and
-associative, so the committed state is independent of record order
-within the batch and replay-exact across restarts (the checkpoint
-parity pin in bench --stateful).
+reflect the table as of the BATCH start (the one gather, after the
+reset), and the update is order-free where float32 allows: counts (a
+group's length), last_t and the extrema are exact whatever the order;
+the three score sums and the decayed count are float32 sums in the
+order the sort leaves a group in, a function of the dispatch alone —
+the same dispatch folds to the same bytes every time (replay-exact
+across restarts, the checkpoint parity pin in bench --stateful), and a
+permuted one to the same bytes but for the rounding of those sums.
 
 Donation: when the caller donates, BOTH the staged batch and the state
 buffer are donated (``donate_argnums=(1, 2)``) — the state update is
@@ -49,10 +74,10 @@ in-place on device, so steady-state state memory is one ``[rows, 8]``
 buffer regardless of dispatch depth.
 
 Bypassed records (shed replay below the exactly-once high-water, pad
-rows) arrive with ``slot == scratch`` and weight 0: they read the
-scratch row (zeros → derived zeros) and their scatter contributions
-land on the scratch row, which the program zeroes before returning —
-by construction they cannot mutate any key's state.
+rows) arrive with ``slot == scratch`` and weight 0: they are the
+scratch row's group, read it (zeros → derived zeros) and write it, and
+the program zeroes it before returning — by construction they cannot
+mutate any key's state.
 
 Over a mesh (a scorer from ``QuantizedScorer.on_mesh``) the same
 ``state_fn`` runs under ``shard_map`` on the data axis: forest
@@ -61,15 +86,16 @@ operands sharded on their leading axis. A chip runs the forest and
 ``_state_step`` on its own piece of the table and its own records, with
 LOCAL rows and a scratch row of its own (``KeyedStateTable.locate``;
 the host sorts a dispatch by owner first, runtime/shuffle.py). No
-collective is in the program and no chip sees another chip's rows;
-donation and the in-place whole-row scatters hold shard by shard
-(tests/test_v5e_compile.py).
+collective is in the program (the sort is a chip's own) and no chip
+sees another chip's rows; donation and the one in-place whole-row
+scatter hold shard by shard (tests/test_v5e_compile.py).
 """
 
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 from flink_jpmml_tpu.compile import common
 from flink_jpmml_tpu.runtime.state import (
@@ -93,14 +119,18 @@ _INIT_ROW = (0.0, 0.0, 0.0, 0.0, 0.0, 0.0, float("inf"), float("-inf"))
 _DCOUNT_FLOOR = 1e-30
 
 
-def _rows(like, identity, cols):
-    """``[B, STATE_WIDTH]`` update rows for one whole-row scatter:
-    ``cols`` maps a column to its ``[B]`` values, every other column
-    carries the scatter operation's ``identity``."""
-    fill = jnp.full_like(like, identity)
-    return jnp.stack(
-        [cols.get(c, fill) for c in range(STATE_WIDTH)], axis=1
-    )
+def _group_scan(op, x, idx, first):
+    """Inclusive scan of ``x[..., B]`` with ``op`` inside each group of
+    the sorted dispatch, in log2(B) shifted steps and no table access:
+    record ``i`` takes in record ``i - d`` where that one is of its
+    group (``first[i]`` is the group's first index). A group's total
+    ends on its last record. The order of the partial results is a
+    function of the sorted order alone."""
+    d = 1
+    while d < x.shape[-1]:
+        x = jnp.where(idx - d >= first, op(x, jnp.roll(x, d, axis=-1)), x)
+        d *= 2
+    return x
 
 
 def _state_step(S, score, slots, rel, w, reset, scratch, decay):
@@ -112,18 +142,39 @@ def _state_step(S, score, slots, rel, w, reset, scratch, decay):
     λ^-rel (0 for bypassed rows) · ``reset[B]`` fresh-slot marks →
     ``(derived[B, 8], S')``."""
     f32 = jnp.float32
-    score = score.astype(f32)
-    rel = rel.astype(f32)
-    w = w.astype(f32)
+    B = slots.shape[0]
     # the scopes are metadata on the traced ops (the op_name a device
     # trace shows them under); the lowered program is the same without
     with jax.named_scope("fjt.fold.gather"):
-        init = jnp.asarray(_INIT_ROW, f32)
-        # fresh slots re-initialize; rows with nothing to reset aim the
-        # write at the scratch row (re-zeroed at the end regardless)
-        sel = jnp.where(reset, slots, scratch)
-        S = S.at[sel].set(init)
-        pre = S[slots]
+        idx = lax.iota(jnp.int32, B)
+        # group the dispatch by slot: the slot and the record's index
+        # are all the sort carries (what its compile costs: module
+        # docstring), the other operands follow it by one gather
+        slot_s, perm = lax.sort((slots, idx), num_keys=1, is_stable=False)
+        ops = jnp.stack(
+            [score.astype(f32), rel.astype(f32), w.astype(f32),
+             reset.astype(f32)], axis=1,
+        )[perm]
+        score_s, rel_s, w_s, reset_s = (
+            ops[:, 0], ops[:, 1], ops[:, 2], ops[:, 3] > 0
+        )
+        edge = slot_s[1:] != slot_s[:-1]
+        start = jnp.concatenate([jnp.ones((1,), bool), edge])
+        last = jnp.concatenate([edge, jnp.ones((1,), bool)])
+        first = lax.cummax(jnp.where(start, idx, 0))
+        end = lax.cummin(jnp.where(last, idx, B - 1), reverse=True)
+        # a fresh slot re-initializes for its whole group, wherever in
+        # it the mark sits: the nearest marked record before or after
+        # lies inside the group
+        fresh = (
+            (lax.cummax(jnp.where(reset_s, idx, -1)) >= first)
+            | (lax.cummin(jnp.where(reset_s, idx, B), reverse=True) <= end)
+        )
+        # THE read of the table: every record of a key reads its row as
+        # of the batch's start, after the reset
+        pre = jnp.where(
+            fresh[:, None], jnp.asarray(_INIT_ROW, f32), S[slot_s]
+        )
         count = pre[:, COL_COUNT]
         seen = count > 0
         safe = jnp.maximum(count, 1.0)
@@ -132,33 +183,55 @@ def _state_step(S, score, slots, rel, w, reset, scratch, decay):
         # product form: stored U = Σ λ^-rel_i, decayed count as of this
         # record's stride = U · λ^rel (≤ U); the decayed mean is the
         # ratio, where λ^rel cancels — epoch-independent by construction
-        dcount = pre[:, COL_DCOUNT] * jnp.power(f32(decay), rel)
+        dcount = pre[:, COL_DCOUNT] * jnp.power(f32(decay), rel_s)
         dmean = pre[:, COL_DSUM] / jnp.maximum(
             pre[:, COL_DCOUNT], _DCOUNT_FLOOR
         )
-        gap = rel - pre[:, COL_LAST_T]
+        gap = rel_s - pre[:, COL_LAST_T]
         derived = jnp.stack(
             [count, mean, var, dcount, dmean, gap,
              pre[:, COL_MIN], pre[:, COL_MAX]],
             axis=1,
         )
         derived = jnp.where(seen[:, None], derived, f32(0.0))
+        # back in arrival order, by the sort's inverse
+        derived = derived[
+            jnp.zeros_like(perm).at[perm].set(
+                idx, unique_indices=True, mode="promise_in_bounds"
+            )
+        ]
     with jax.named_scope("fjt.fold.scatter"):
-        # commutative scatter updates. The extrema are whole-row
-        # scatters, native and in place on the TPU (module docstring):
-        # a column an operation does not touch carries that operation's
-        # identity — max(x, -inf) and min(x, +inf) are exact, also on a
-        # fresh row's ±inf. The add's identity is -0.0, not 0.0:
-        # x + (-0.0) is x bit for bit, a stored -0.0 minimum included
-        S = S.at[slots].add(_rows(score, -0.0, {
-            COL_COUNT: jnp.ones_like(score), COL_SUM: score,
-            COL_SQSUM: score * score, COL_DCOUNT: w, COL_DSUM: w * score,
-        }))
-        S = S.at[slots].max(_rows(
-            score, -jnp.inf, {COL_LAST_T: rel, COL_MAX: score}
-        ))
-        S = S.at[slots].min(_rows(score, jnp.inf, {COL_MIN: score}))
-        # bypass/pad contributions all landed on the scratch row — zero
+        # a group's totals, on its last record: float32 sums in the
+        # sorted order (a count is the group's length, exact), the
+        # extrema exact
+        sums = _group_scan(
+            jnp.add,
+            jnp.stack([score_s, score_s * score_s, w_s, w_s * score_s]),
+            idx, first,
+        )
+        tops = _group_scan(
+            jnp.maximum, jnp.stack([rel_s, score_s]), idx, first
+        )
+        low = _group_scan(jnp.minimum, score_s, idx, first)
+        new = {
+            COL_COUNT: pre[:, COL_COUNT] + (idx - first + 1).astype(f32),
+            COL_SUM: pre[:, COL_SUM] + sums[0],
+            COL_SQSUM: pre[:, COL_SQSUM] + sums[1],
+            COL_DCOUNT: pre[:, COL_DCOUNT] + sums[2],
+            COL_DSUM: pre[:, COL_DSUM] + sums[3],
+            COL_LAST_T: jnp.maximum(pre[:, COL_LAST_T], tops[0]),
+            COL_MIN: jnp.minimum(pre[:, COL_MIN], low),
+            COL_MAX: jnp.maximum(pre[:, COL_MAX], tops[1]),
+        }
+        # THE write: the last record of each group sets its row whole,
+        # every other record's aim is off the table and dropped. A
+        # whole-row set is the form the TPU keeps native and in place
+        # (module docstring)
+        S = S.at[jnp.where(last, slot_s, S.shape[0])].set(
+            jnp.stack([new[c] for c in range(STATE_WIDTH)], axis=1),
+            mode="drop",
+        )
+        # bypassed and pad records are the scratch row's group — zero
         # it so snapshots stay clean and the next batch's bypass reads
         # zeros
         S = S.at[scratch].set(jnp.zeros((STATE_WIDTH,), f32))

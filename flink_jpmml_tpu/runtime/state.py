@@ -38,21 +38,22 @@ Division of labor — host routes, device accumulates:
 - **Device values.** The table's VALUES — one fixed-width f32 vector
   per slot (counts, sums, decayed counters in product form, last-seen
   stride, min/max) — live in a single ``[rows, STATE_WIDTH]`` device
-  buffer that only the fused kernel reads or writes, via gather +
-  scatter-add/min/max over the batch's slot vector. That is O(batch)
-  only as far as the compiler keeps each scatter native: on the TPU
+  buffer that only the fused kernel reads or writes: it groups the
+  batch by slot, gathers each touched row once and sets it once, the
+  group's adds, min and max folded in between. That is O(batch)
+  only as far as the compiler keeps the scatter native: on the TPU
   the buffer is column-major, tiled ``T(8,128)`` (a slot's row is one
-  lane of a tile), whole-row scatters run in place, and a write of
+  lane of a tile), a whole-row scatter runs in place, and a write of
   part of a row used to cost a flat copy of the table, O(capacity) a
   dispatch, or a loop over the records (compile/statekernel.py says
-  which write is which today). The buffer is DONATED to each dispatch,
+  which write is which). The buffer is DONATED to each dispatch,
   so the update is in-place: steady-state state memory is one buffer,
   not one per in-flight batch.
 
 Decayed counters ride in **product form**: a record at stride
 ``t = offset // stride`` contributes ``λ^(epoch - t)`` (≥ 1) to the
 decayed count column, and the decayed value *as of* stride ``t`` is
-``column · λ^(t - epoch)`` — a pure scatter-ADD per record, so updates
+``column · λ^(t - epoch)`` — a pure ADD per record, so updates
 are order-independent and replay-exact, with a rare O(capacity)
 renormalization sweep when the exponent range grows (``maybe_renorm``)
 instead of an O(capacity) decay multiply per batch. Time is a pure
@@ -120,12 +121,12 @@ from flink_jpmml_tpu.utils.metrics import MetricsRegistry
 # one fixed-width state vector per key; the column layout is the
 # kernel ABI (compile/statekernel.py) and the snapshot format
 STATE_WIDTH = 8
-COL_COUNT = 0      # records seen (scatter-add 1)
+COL_COUNT = 0      # records seen (add 1)
 COL_SUM = 1        # sum of scores
 COL_SQSUM = 2      # sum of score^2
-COL_DCOUNT = 3     # decayed count, product form (scatter-add λ^-rel)
+COL_DCOUNT = 3     # decayed count, product form (add λ^-rel)
 COL_DSUM = 4       # decayed score sum, product form
-COL_LAST_T = 5     # last-seen stride relative to epoch (scatter-max)
+COL_LAST_T = 5     # last-seen stride relative to epoch (max)
 COL_MIN = 6        # min score (+inf until first)
 COL_MAX = 7        # max score (-inf until first)
 

@@ -636,6 +636,35 @@ def _scratch_only(rng, B):
     return np.zeros(B, np.int64), np.zeros(B, bool), np.ones(B, bool)
 
 
+def _late_reset_mark(rng, B):
+    # a freshly claimed slot whose mark rides ONE record of its group,
+    # never the first to arrive: the whole group starts from _INIT
+    slots = rng.integers(100, 130, size=B)
+    reset = np.zeros(B, bool)
+    for s in np.unique(slots)[::2]:
+        reset[rng.choice(np.flatnonzero(slots == s)[1:])] = True
+    return slots, reset, np.zeros(B, bool)
+
+
+def _one_slot(rng, B):
+    return np.full(B, 7), np.zeros(B, bool), np.zeros(B, bool)
+
+
+def _no_duplicate(rng, B):
+    # every record a group of its own (as many as the table has rows
+    # for), a third of them on fresh slots
+    slots = rng.permutation(_SCRATCH)[:B]
+    return slots, slots >= 650, np.zeros(slots.shape[0], bool)
+
+
+_CASES = pytest.mark.parametrize(
+    "case",
+    [_heavy_duplicates, _fresh_slots, _bypassed_rows, _scratch_only,
+     _late_reset_mark, _one_slot, _no_duplicate],
+    ids=lambda f: f.__name__.strip("_"),
+)
+
+
 def _numpy_fold(S, score, slots, rel, w, reset):
     """The same transition in float64 numpy, one ufunc.at a column."""
     S = S.astype(np.float64)
@@ -673,6 +702,7 @@ def _dispatch(case, rng, B):
     ``(score, slots, rel, w, reset)`` as ``_state_step`` takes them,
     and the bypass marks."""
     slots, reset, bypass = case(rng, B)
+    B = slots.shape[0]  # a case may have fewer records than asked for
     slots = np.where(bypass, _SCRATCH, slots).astype(np.int32)
     score = rng.normal(0.0, 3.0, size=B).astype(np.float32)
     rel = rng.integers(3, 9, size=B).astype(np.float32)
@@ -693,11 +723,7 @@ def _jit_step():
 
 
 class TestStateStep:
-    @pytest.mark.parametrize(
-        "case",
-        [_heavy_duplicates, _fresh_slots, _bypassed_rows, _scratch_only],
-        ids=lambda f: f.__name__.strip("_"),
-    )
+    @_CASES
     def test_matches_float64_numpy_fold(self, case):
         rng = np.random.default_rng(25)
         (score, slots, rel, w, reset), bypass = _dispatch(case, rng, 512)
@@ -766,17 +792,13 @@ class TestStateStep:
         )
         assert (S1[rows, COL_SUM] != S0[rows, COL_SUM]).all()
 
-    @pytest.mark.parametrize(
-        "case",
-        [_heavy_duplicates, _fresh_slots, _bypassed_rows, _scratch_only],
-        ids=lambda f: f.__name__.strip("_"),
-    )
+    @_CASES
     def test_same_batch_twice_gives_the_same_bytes(self, case):
-        """Replay-exact: a native scatter may sum a slot's duplicates
-        in another order than a loop over the records would, but the
-        same dispatch folded into the same table sums them in the SAME
-        order every time (here on the CPU; PERF.md §6, PR 28 has the
-        chip's reading)."""
+        """Replay-exact: the sort that groups a dispatch by slot may
+        put a slot's duplicates in another order than they arrived in,
+        but the same dispatch folded into the same table sums them in
+        the SAME order every time (here on the CPU; PERF.md §6, PR 35
+        has the chip's reading)."""
         rng = np.random.default_rng(28)
         operands, _ = _dispatch(case, rng, 4096)
         S0, step = _prior_table(rng), _jit_step()
@@ -786,13 +808,58 @@ class TestStateStep:
         )
         assert once == twice
 
-    def test_every_table_scatter_writes_whole_rows(self):
+    @pytest.mark.parametrize("terms", ["dyadic", "floats"])
+    @pytest.mark.parametrize(
+        "case",
+        [_heavy_duplicates, _bypassed_rows, _late_reset_mark, _no_duplicate],
+        ids=lambda f: f.__name__.strip("_"),
+    )
+    def test_a_permuted_dispatch_folds_to_the_same_table(self, case, terms):
+        """The fold groups a dispatch by slot itself, so the order its
+        records arrive in decides nothing but the order of the float32
+        additions inside a group. ``derived`` goes with the records
+        byte for byte (it reads the table as of the batch's start);
+        counts, last_t and the extrema come out byte for byte; the sums
+        byte for byte wherever float32 addition is exact (``dyadic``:
+        scores in 1/8ths, weights 1, 2 or 4) and inside the fold's
+        error bound otherwise (``floats``: the in-group order follows
+        an unstable sort of (slot, index), which a permutation moves)."""
+        rng = np.random.default_rng(35)
+        (score, slots, rel, w, reset), _ = _dispatch(case, rng, 1024)
+        S0 = _prior_table(rng)
+        if terms == "dyadic":
+            score = (rng.integers(-64, 65, size=score.shape) / 8.0).astype(
+                np.float32)
+            w = np.where(w > 0, np.exp2(rng.integers(0, 3, size=w.shape)),
+                         0.0).astype(np.float32)
+            S0[:200, _SUM_COLS] = np.round(S0[:200, _SUM_COLS] * 64) / 64
+        step = _jit_step()
+        d1, S1 = (np.asarray(a) for a in step(S0, score, slots, rel, w, reset))
+        p = rng.permutation(slots.shape[0])
+        d2, S2 = (np.asarray(a) for a in step(
+            S0, score[p], slots[p], rel[p], w[p], reset[p]))
+        assert d2.tobytes() == d1[p].tobytes()
+        assert S2[:, _EXACT_COLS].tobytes() == S1[:, _EXACT_COLS].tobytes()
+        if terms == "dyadic":
+            assert S2.tobytes() == S1.tobytes()
+        else:
+            _, _, lost = _numpy_fold(
+                S0, score.astype(np.float64), slots, rel.astype(np.float64),
+                w.astype(np.float64), reset)
+            assert (
+                np.abs(S2[:, _SUM_COLS] - S1[:, _SUM_COLS])
+                <= 2 * np.finfo(np.float32).eps * lost[:, _SUM_COLS]
+            ).all()
+
+    def test_the_table_is_written_once_and_in_whole_rows(self):
         """Tripwire: on the TPU the table is column-major, tiled
         (8, 128), and a scatter into part of a row is lowered to a flat
         copy of the whole table (one column) or a loop over the records
         (a slice of columns: the add of the five accumulator columns
-        until PR 28). Every scatter of the fold whose operand is the
-        table has to carry an update window of all 8 columns."""
+        until PR 28). The fold has ONE gather that reads the table and
+        ONE scatter of records into it (PR 35), and that scatter and
+        the scratch row's zeroing carry an update window of all 8
+        columns."""
         import re
 
         import jax
@@ -804,18 +871,24 @@ class TestStateStep:
             sds((_ROWS, 8), f32), sds((B,), f32), sds((B,), jnp.int32),
             sds((B,), f32), sds((B,), f32), sds((B,), jnp.bool_),
         ).compiler_ir("stablehlo")
-        windows = []
+        windows, gathers = [], 0
 
         def walk(op):
+            nonlocal gathers
             for region in op.regions:
                 for block in region.blocks:
                     for o in block.operations:
                         walk(o.operation)
-                        if o.operation.name != "stablehlo.scatter":
+                        name = o.operation.name
+                        if name not in ("stablehlo.scatter",
+                                        "stablehlo.gather"):
                             continue
-                        table, _, upd = (x.type for x in o.operands)
-                        if list(table.shape) != [_ROWS, 8]:
+                        if list(o.operands[0].type.shape) != [_ROWS, 8]:
                             continue
+                        if name == "stablehlo.gather":
+                            gathers += 1
+                            continue
+                        upd = o.operands[2].type
                         # an empty window is left out of the text
                         m = re.search(
                             r"update_window_dims = \[([\d, ]*)\]",
@@ -827,9 +900,9 @@ class TestStateStep:
                         ])))
 
         walk(module.operation)
-        # reset, add, max, min, and the scratch row's zeroing
-        assert len(windows) >= 5, windows
-        assert all(wd == 8 for wd in windows), windows
+        # the groups' rows, and the scratch row's zeroing
+        assert windows == [8, 8], windows
+        assert gathers == 1
 
 
 class TestNamedScopes:

@@ -25,6 +25,8 @@ from flink_jpmml_tpu.runtime.state import (
     COL_COUNT,
     COL_DCOUNT,
     COL_LAST_T,
+    COL_MAX,
+    COL_MIN,
     COL_SQSUM,
     COL_SUM,
     STATE_WIDTH,
@@ -330,7 +332,11 @@ def test_the_comparison_catches_a_planted_fault(gbm, monkeypatch, plant):
 
 # -- direct dispatches: same batches, so derived rows compare too -------------
 
-def _fold(gbm, table, X, batch=96):
+def _fold(gbm, table, X, batch=96, shuffled=None):
+    """``X`` through direct dispatches of ``batch`` records; with
+    ``shuffled`` (a Generator) each dispatch's records, offsets with
+    them, go in a drawn order and its results are put back in the
+    stream's."""
     import jax
 
     from flink_jpmml_tpu.runtime.pipeline import dispatch_quantized
@@ -338,11 +344,12 @@ def _fold(gbm, table, X, batch=96):
     q = gbm.quantized_scorer()
     outs = []
     for lo in range(0, X.shape[0], batch):
-        out, derived = dispatch_quantized(
-            q, X[lo:lo + batch], state=table,
-            offsets=np.arange(lo, min(lo + batch, X.shape[0])))
         n = min(batch, X.shape[0] - lo)
-        outs.append((np.asarray(out)[:n], np.asarray(derived)[:n]))
+        p = np.arange(n) if shuffled is None else shuffled.permutation(n)
+        out, derived = dispatch_quantized(
+            q, X[lo:lo + n][p], state=table, offsets=lo + p)
+        back = np.argsort(p)
+        outs.append((np.asarray(out)[:n][back], np.asarray(derived)[:n][back]))
     jax.block_until_ready(table.values)
     return (np.concatenate([o for o, _ in outs]),
             np.concatenate([d for _, d in outs]))
@@ -368,6 +375,31 @@ def test_direct_dispatch_scores_and_derived_rows_agree(gbm, D):
                           many.read_rows(every)[:, COL_COUNT])
     assert np.allclose(one.read_rows(every), many.read_rows(every),
                        rtol=1e-5, atol=1e-6)
+
+
+@pytest.mark.parametrize("D", (2, 4))
+def test_a_permuted_dispatch_folds_to_the_same_pieces(gbm, D):
+    """Each chip groups its bucket by slot itself (compile/statekernel.py),
+    so the order a dispatch's records arrive in decides nothing but
+    the order of the float32 additions inside a group: scores go
+    with their records byte for byte, counts, last_t and the extrema
+    come out byte for byte (in the table and in the derived rows that
+    read them), the sums inside float32's rounding."""
+    X = _stream(700, keys=60)
+    _, straight = _tables(D)
+    _, permuted = _tables(D)
+    s1, d1 = _fold(gbm, straight, X)
+    s2, d2 = _fold(gbm, permuted, X, shuffled=np.random.default_rng(35))
+    assert s1.tobytes() == s2.tobytes()
+    seen_gap_min_max = [0, 5, 6, 7]  # state.DERIVED_FIELDS
+    assert (d1[:, seen_gap_min_max].tobytes()
+            == d2[:, seen_gap_min_max].tobytes())
+    assert np.allclose(d1, d2, rtol=1e-5, atol=1e-6)
+    every = np.arange(CAPACITY)
+    a, b = straight.read_rows(every), permuted.read_rows(every)
+    exact = [COL_COUNT, COL_LAST_T, COL_MIN, COL_MAX]
+    assert a[:, exact].tobytes() == b[:, exact].tobytes()
+    assert np.allclose(a, b, rtol=1e-5, atol=1e-6)
 
 
 @pytest.mark.parametrize("D", (2, 4))

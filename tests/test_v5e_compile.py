@@ -43,11 +43,30 @@ def no_compile_cache():
     compilation_cache.reset_cache()
 
 
+def _table_accesses(text, rows):
+    """``(gathers, scatters)`` of a compiled program's text whose
+    operand is a ``[rows, 8]`` table, each by its result shape. The
+    fold's small permutations (a dispatch's operands put in slot order,
+    its derived rows put back) are gathers and scatters of ``[B]`` and
+    never of the table."""
+    table = f"f32[{rows},8]"
+    shape_of = dict(re.findall(r"(%[\w.\-]+) = (\S+) ", text))
+    hits = re.findall(r"= (\S+) (gather|scatter)\((%[\w.\-]+),", text)
+    return tuple(
+        [res for res, op, operand in hits
+         if op == kind and shape_of.get(operand, "").startswith(table)]
+        for kind in ("gather", "scatter")
+    )
+
+
 @pytest.mark.parametrize("batch", [16384, 65536])
-def test_state_fold_scatters_in_place(one_chip, no_compile_cache, batch):
+def test_state_fold_reads_and_writes_the_table_once(
+        one_chip, no_compile_cache, batch):
     """The fold of ``benchmark/configs/gbm500_keyed.json`` (200,000,000
-    slots, 6.4 GB, donated): the reset, the add and the extrema are
-    native scatters of whole rows, in place. The chip keeps the table
+    slots, 6.4 GB, donated): ONE gather reads the table and ONE native
+    scatter of whole rows writes it, in place (PR 35: the dispatch is
+    grouped by slot on the chip; until then a reset, an add, a max and
+    a min each walked the table). The chip keeps the table
     column-major, tiled (8, 128); a scatter into one column made the
     compiler flatten the table a column at a time
     (``f32[1600002048]``, 9.6 GB of temporaries), and one into a slice
@@ -76,12 +95,15 @@ def test_state_fold_scatters_in_place(one_chip, no_compile_cache, batch):
     text = compiled.as_text()
     table_bytes = rows * 8 * 4
     assert mem.alias_size_in_bytes >= table_bytes
-    assert mem.temp_size_in_bytes < 10_000_000, mem.temp_size_in_bytes
+    # the two row gathers that permute a dispatch take their [B, 4]
+    # and [B, 8] operands row-major, a row padded to 128 lanes: 33.5 MB
+    # at 65,536 records, and the largest thing the fold allocates
+    assert mem.temp_size_in_bytes < table_bytes // 100, mem.temp_size_in_bytes
     assert f"f32[{rows * 8}]" not in text
     assert not re.findall(r"\bwhile\(", text)
-    scatters = re.findall(r"= (\S+) scatter\(", text)
-    assert len(scatters) >= 4, scatters  # reset, add, max, min
-    assert all(s.startswith(f"f32[{rows},8]") for s in scatters), scatters
+    gathers, scatters = _table_accesses(text, rows)
+    assert len(gathers) == 1, gathers
+    assert len(scatters) == 1, scatters
 
 
 @pytest.fixture(scope="module")
@@ -108,8 +130,10 @@ def test_mesh_state_program_keeps_the_table_on_its_chip(
     ``statekernel.entry_for`` builds it for a scorer on a mesh, at the
     table of ``benchmark/configs/gbm500_keyed_mesh4.json`` (560,000,000
     slots: 140,000,256 rows, 4.48 GB a chip): every chip folds its own
-    piece in place. No collective is in the program (so none touches
-    the table), no ``while`` is in it nor in its one-chip twin (every
+    piece in place, with ONE gather that reads the piece and ONE scatter
+    that writes it (each chip sorts its own bucket by slot: the sort
+    is local). No collective is in the program (so none touches the
+    table), no ``while`` is in it nor in its one-chip twin (every
     table write is a native scatter: a loop would walk a bucket's pad
     rows as it walks records), the donated table is aliased shard by
     shard and the temporaries stay under 1% of a shard. The forest here
@@ -176,6 +200,9 @@ def test_mesh_state_program_keeps_the_table_on_its_chip(
     assert mem.temp_size_in_bytes < shard_bytes // 100, mem.temp_size_in_bytes
     assert f"f32[{layout.shard_rows},8]" in text  # a chip's own piece
     assert f"f32[{D * layout.shard_rows},8]" not in text
+    for prog in (text, one_text):
+        gathers, scatters = _table_accesses(prog, layout.shard_rows)
+        assert (len(gathers), len(scatters)) == (1, 1), (gathers, scatters)
 
 
 def test_mesh_renorm_sweeps_every_piece_in_place(mesh_2x2, no_compile_cache):
