@@ -26,6 +26,10 @@
 #include <thread>
 #include <vector>
 
+#if defined(__SSE2__)
+#include <emmintrin.h>
+#endif
+
 using namespace std::chrono;
 
 namespace {
@@ -580,28 +584,42 @@ int64_t fjt_kafka_decode_fixed(const uint8_t* buf, int64_t len,
 }  // extern "C"
 
 // ---------------------------------------------------------------------------
-// Keyed-state routing (runtime/state.py KeyedStateTable.route's fast path).
+// Keyed-state routing (runtime/state.py KeyedStateTable.route).
 //
 // The host mirror of the state table is three flat arrays (uint32 key
 // hashes, occupancy bytes, int64 LRU stamps), hundreds of millions of
 // entries in a deployment, and every record of every batch has to find its
-// key's slot in them. Two passes, one call each, both bit-exact twins of the
-// numpy code they stand in for (which stays as the fallback and the tests'
-// oracle):
+// key's slot in them. Three entries, one call each, all bit-exact twins of
+// the numpy code they stand in for (which stays as the fallback of a host
+// that cannot build this file, and as the tests' oracle):
 //
 //  - fjt_state_hash_f32: the key column of a raw f32 block → the uint32
 //    stable hash, parallel/partitioner.py stable_hash_vec of the column cast
 //    to int64: CRC32 (zlib's) over b"i" + the key's low
 //    abs(key).bit_length()//8 + 1 bytes, little-endian two's complement;
-//  - fjt_state_resolve: per record, in arrival order, walk the probe window
-//    from hash % capacity and stop at the first slot that matches (a hit:
-//    write the slot, stamp it) or is empty, or at the window's end (both:
-//    the record is left pending for the caller's claim/evict rounds). It
-//    writes neither keys nor occupancy, so what it resolves no claim or
-//    eviction of the same call can change.
+//  - fjt_state_resolve, route's first pass: per record, in arrival order,
+//    walk the probe window from hash % capacity and stop at the first slot
+//    that matches (a hit: write the slot, stamp it) or is empty, or at the
+//    window's end (both: the record is left pending). It writes neither keys
+//    nor occupancy, so what it resolves no claim or eviction of the same
+//    call can change;
+//  - fjt_state_claim, route's second pass over what the first left: the
+//    claim rounds and the eviction of KeyedStateTable._claim_rounds, whose
+//    docstring is the one statement of the rule. The numpy body of that
+//    method is the same rounds, and what a host without this library runs.
 //
-// Every probe is a cache miss in a table of that size; the walk prefetches
-// the home slots of the records a few places ahead.
+// Every probe is a cache miss in a table of that size, and a full table's
+// keys lie anywhere in their windows, so the resolve pass runs as a pipeline
+// over its own misses: kRouteAhead records ahead of a record it asks for the
+// home slot's lines, kRouteNear records ahead it walks the window (it only
+// reads what the pass never writes, so the answer is the one a walk at the
+// record's turn would give) and asks for the line of the stamp, and at the
+// record itself it writes the answer and stamps. The walks of the records
+// in between are independent of each other and of the stamps, so the lines
+// a walk misses on its way are fetched beside the next walks' instead of
+// one after another. (Asking for every line a window can span, ahead of the
+// walk, was measured slower on the TPU host than asking for the home line
+// alone: seven requests a record crowd out the ones in use.)
 // ---------------------------------------------------------------------------
 
 namespace {
@@ -615,11 +633,132 @@ inline int64_t f32_to_i64(float f) {
     return static_cast<int64_t>(f);
 }
 
-constexpr uint64_t kRouteAhead = 16;  // records prefetched ahead of the walk
+// how many records ahead of a record's turn the resolve pass asks for its
+// home slot's lines, and walks its window (measured on the TPU host, on the
+// 200M-slot mirror, twenty pairs from 8/4 to 64/32: on a full table none is
+// more than 6% faster than 16 and 8, and on a table whose keys sit by their
+// home slots this pair alone matches the walk at the record's turn)
+constexpr uint64_t kRouteAhead = 16;
+constexpr uint64_t kRouteNear = 8;
 
-// hash % capacity, as a 32-bit division wherever the capacity allows one
-inline uint64_t home_slot(uint32_t h, uint64_t capacity) {
-    return capacity >> 32 ? h : h % static_cast<uint32_t>(capacity);
+// hash % capacity without a division a record (Lemire's fastmod: exact for
+// every 32-bit hash and capacity); a capacity past 2^32 holds every hash
+struct HomeOf {
+    uint64_t m;
+    uint64_t capacity;
+    explicit HomeOf(uint64_t cap)
+        : m(cap >> 32 ? 0 : ~uint64_t(0) / cap + 1), capacity(cap) {}
+    uint64_t operator()(uint32_t h) const {
+        if (capacity >> 32) return h;
+        return uint64_t((static_cast<unsigned __int128>(m * h) * capacity) >> 64);
+    }
+};
+
+#if defined(__SSE2__)
+// Of sixteen slots, bit j set where slot j is empty or holds the hash.
+inline uint32_t ends_of_16(const uint32_t* k, const uint8_t* o, __m128i h) {
+    auto at = [&](int j) {
+        return _mm_cmpeq_epi32(
+            _mm_loadu_si128(reinterpret_cast<const __m128i*>(k + j)), h);
+    };
+    const __m128i held = _mm_packs_epi16(_mm_packs_epi32(at(0), at(4)),
+                                         _mm_packs_epi32(at(8), at(12)));
+    const __m128i empty = _mm_cmpeq_epi8(
+        _mm_loadu_si128(reinterpret_cast<const __m128i*>(o)),
+        _mm_setzero_si128());
+    return uint32_t(_mm_movemask_epi8(_mm_or_si128(held, empty)));
+}
+#endif
+
+// Where a key's walk of its window ends: → p, the slots passed, and c, the
+// slot reached: the first that is empty or holds the hash, p == probe where
+// the window has neither. A window that does not wrap is read sixteen slots
+// a step where the host has the compares for it: a full table's keys lie
+// anywhere in their windows, and four in ten records of a stream that admits
+// keys walk all of theirs.
+inline uint32_t walk_window(const uint32_t* keys, const uint8_t* occ,
+                            uint32_t h, uint64_t home, uint32_t probe,
+                            uint64_t capacity, uint64_t& c) {
+    c = home;
+    if (!occ[c] || keys[c] == h) return 0;
+    uint32_t p = 0;
+#if defined(__SSE2__)
+    if (home + probe <= capacity) {
+        const __m128i hv = _mm_set1_epi32(int32_t(h));
+        for (; p + 16 <= probe; p += 16) {
+            const uint32_t ends = ends_of_16(keys + home + p, occ + home + p, hv);
+            if (ends) {
+                p += uint32_t(__builtin_ctz(ends));
+                c = home + p;
+                return p;
+            }
+        }
+        c = home + p;  // what a window that is no multiple of 16 has left
+    }
+#endif
+    for (; p < probe && occ[c] && keys[c] != h; ++p)
+        if (++c == capacity) c = 0;
+    return p;
+}
+
+// The slot of a window with the oldest stamp, the first of equals on the way
+// from the home slot → its stamp.
+inline int64_t oldest_of_window(const int64_t* touch, uint64_t home,
+                                uint32_t probe, uint64_t capacity,
+                                uint64_t& best) {
+    if (home + probe <= capacity && probe % 4 == 0) {
+        const int64_t* w = touch + home;
+        int64_t m[4] = {w[0], w[1], w[2], w[3]};
+        // the least of four lanes, without a branch: stamps fall at random
+        // along a window and a compare-and-jump a slot mispredicts a dozen
+        // times a window
+        for (uint32_t p = 4; p < probe; p += 4)
+            for (int j = 0; j < 4; ++j)
+                m[j] ^= (w[p + j] ^ m[j]) & -int64_t(w[p + j] < m[j]);
+        const int64_t a = m[0] < m[1] ? m[0] : m[1];
+        const int64_t b = m[2] < m[3] ? m[2] : m[3];
+        const int64_t oldest = a < b ? a : b;
+        uint32_t p = 0;
+        while (w[p] != oldest) ++p;
+        best = home + p;
+        return oldest;
+    }
+    uint64_t c = home;
+    best = c;
+    int64_t oldest = touch[c];
+    for (uint32_t p = 1; p < probe; ++p) {
+        if (++c == capacity) c = 0;
+        if (touch[c] < oldest) {
+            oldest = touch[c];
+            best = c;
+        }
+    }
+    return oldest;
+}
+
+// (hash << 32 | index) ascending by hash: three stable passes over the
+// hash's bits, 11, 11 and 10 of them
+void sort_by_hash(std::vector<uint64_t>& a) {
+    const size_t n = a.size();
+    std::vector<uint64_t> b(n);
+    std::vector<uint32_t> count(3 * 2048, 0);
+    for (uint64_t v : a) {
+        ++count[(v >> 32) & 2047];
+        ++count[2048 + ((v >> 43) & 2047)];
+        ++count[4096 + (v >> 54)];
+    }
+    for (int pass = 0; pass < 3; ++pass) {
+        uint32_t* c = count.data() + 2048 * pass;
+        uint32_t at = 0;
+        for (int d = 0; d < 2048; ++d) {
+            const uint32_t k = c[d];
+            c[d] = at;
+            at += k;
+        }
+        const int shift = 32 + 11 * pass;
+        for (uint64_t v : a) b[c[(v >> shift) & 2047]++] = v;
+        a.swap(b);
+    }
 }
 
 }  // namespace
@@ -653,41 +792,221 @@ void fjt_state_hash_f32(const uint8_t* col, uint64_t n, int64_t row_stride,
 
 // khash [n]; apply [n] (0: the record bypasses the table and is skipped);
 // keys/occ/touch [capacity]: the mirror; probe: the window; seq: this
-// call's stamp. Writes slots[i] and touch for a hit, pending[i] = 1 for a
-// record left to the caller (slots[i] untouched), 0 otherwise. → the hits
-// found past their home slot, counted once a key: a key's first hit of a
-// call is the one that finds its slot not yet stamped seq.
+// call's stamp. Writes slots[i] and touch for a hit, and the index of every
+// record left to the caller into todo [n], ascending (its slots[i]
+// untouched) → how many those are. *collided: the hits found past their
+// home slot, counted once a key: a key's first hit of a call is the one
+// that finds its slot not yet stamped seq.
 uint64_t fjt_state_resolve(const uint32_t* khash, const uint8_t* apply,
                            uint64_t n, const uint32_t* keys,
                            const uint8_t* occ, int64_t* touch,
                            uint64_t capacity, uint32_t probe, int64_t seq,
-                           int32_t* slots, uint8_t* pending) {
-    uint64_t collided = 0;
-    for (uint64_t i = 0; i < n; ++i) {
-        if (i + kRouteAhead < n) {
-            const uint64_t a = home_slot(khash[i + kRouteAhead], capacity);
-            __builtin_prefetch(occ + a);
+                           int32_t* slots, int64_t* todo,
+                           uint64_t* collided) {
+    // a walk's answer on its way to the record's turn: the slot (-1: left
+    // to the caller, -2: not applied) and whether it lies past the home slot
+    struct Walked { int64_t slot; bool past_home; };
+    constexpr uint64_t kRing = 64;  // a power of two past kRouteNear
+    Walked ring[kRing];
+    static_assert(kRouteNear < kRing && kRouteNear < kRouteAhead, "ring");
+    const HomeOf home_slot(capacity);
+    uint64_t n_todo = 0, n_collided = 0;
+    for (uint64_t t = 0; t < n + kRouteNear; ++t) {
+        const uint64_t far = t + (kRouteAhead - kRouteNear);
+        if (far < n && apply[far]) {
+            const uint64_t a = home_slot(khash[far]);
             __builtin_prefetch(keys + a);
+            __builtin_prefetch(occ + a);
             __builtin_prefetch(touch + a, 1);
         }
-        pending[i] = 0;
-        if (!apply[i]) continue;
-        const uint32_t h = khash[i];
-        uint64_t c = home_slot(h, capacity);
-        uint32_t p = 0;
-        for (; p < probe && occ[c] && keys[c] != h; ++p)
-            if (++c == capacity) c = 0;
-        if (p < probe && occ[c]) {
-            slots[i] = static_cast<int32_t>(c);
-            if (touch[c] != seq) {
-                touch[c] = seq;
-                collided += p != 0;
+        if (t < n) {
+            Walked& w = ring[t % kRing];
+            w.slot = -2;
+            if (apply[t]) {
+                uint64_t c;
+                const uint32_t p = walk_window(
+                    keys, occ, khash[t], home_slot(khash[t]),
+                    probe, capacity, c);
+                w.slot = -1;
+                if (p < probe && occ[c]) {
+                    w.slot = static_cast<int64_t>(c);
+                    w.past_home = p != 0;
+                    if (p) __builtin_prefetch(touch + c, 1);
+                }
             }
-        } else {
-            pending[i] = 1;
+        }
+        if (t < kRouteNear) continue;
+        const uint64_t i = t - kRouteNear;
+        const Walked& w = ring[i % kRing];
+        if (w.slot >= 0) {
+            slots[i] = static_cast<int32_t>(w.slot);
+            if (touch[w.slot] != seq) {
+                touch[w.slot] = seq;
+                n_collided += w.past_home;
+            }
+        } else if (w.slot == -1) {
+            todo[n_todo++] = static_cast<int64_t>(i);
         }
     }
-    return collided;
+    *collided = n_collided;
+    return n_todo;
+}
+
+// khash [n]: the records of one routing call that fjt_state_resolve left
+// pending (or, had it not run, every applied record of the call), each to
+// be answered with slots[i] (capacity: the scratch slot) and reset[i] (its
+// key was given the slot by this call). The rounds and the eviction rule
+// are KeyedStateTable._claim_rounds' (runtime/state.py), stated there and
+// not here; what follows is how they are run.
+//
+// The unique hashes ascend, so "the smallest hash first" is "the first in
+// the list". Round 0 looks at every key's home slot. A key off its home
+// slot then has its window read once: a match before any empty slot is
+// its slot (nothing before it can change: the rounds only fill empties); a
+// window without either waits for the eviction; a key that sees an empty
+// slot first joins the rounds at that slot's round, since the slots before
+// it hold other keys and will. The rounds go a slot a round over the keys
+// still probing, in ascending order, so an empty slot goes to the first to
+// reach it and the others see it taken. The eviction rounds are two passes
+// each: every key waiting names its slot from the stamps as the round found
+// them, THEN the named slots are awarded in ascending order (an award
+// stamps seq, which is how a later claimant of the round sees it gone).
+// counts [4]: inserts, evictions, overflows, and the unique keys not
+// resolved at their home slot.
+void fjt_state_claim(const uint32_t* khash, uint64_t n, uint32_t* keys,
+                     uint8_t* occ, int64_t* touch, uint64_t capacity,
+                     uint32_t probe, int64_t seq, int32_t* slots,
+                     uint8_t* reset, uint64_t* counts) {
+    counts[0] = counts[1] = counts[2] = counts[3] = 0;
+    if (!n) return;
+    const HomeOf home_slot(capacity);
+    std::vector<uint64_t> order(n);
+    for (uint64_t i = 0; i < n; ++i) order[i] = uint64_t(khash[i]) << 32 | i;
+    sort_by_hash(order);
+    // the unique keys, ascending: hash, where its records start in `order`,
+    // and its answer (-1: none yet)
+    std::vector<uint32_t> uk, first;
+    for (uint64_t j = 0; j < n; ++j)
+        if (!j || order[j] >> 32 != order[j - 1] >> 32) {
+            uk.push_back(uint32_t(order[j] >> 32));
+            first.push_back(uint32_t(j));
+        }
+    const size_t m = uk.size();
+    first.push_back(uint32_t(n));
+    std::vector<int64_t> slot_u(m, -1);
+    std::vector<uint8_t> reset_u(m, 0);
+
+    auto claim_empty = [&](size_t u, uint64_t c) {
+        occ[c] = 1;
+        keys[c] = uk[u];
+        touch[c] = seq;
+        slot_u[u] = int64_t(c);
+        reset_u[u] = 1;
+        ++counts[0];
+    };
+
+    // round 0: the home slots
+    std::vector<uint32_t> rest;
+    for (size_t u = 0; u < m; ++u) {
+        const uint64_t c = home_slot(uk[u]);
+        if (!occ[c]) {
+            claim_empty(u, c);
+        } else if (keys[c] == uk[u]) {
+            slot_u[u] = int64_t(c);
+            touch[c] = seq;
+        } else {
+            rest.push_back(uint32_t(u));
+        }
+    }
+    counts[3] = rest.size();
+
+    // the windows of the keys off their home slot, each read once
+    std::vector<uint32_t> probing, round_of;  // a key and the round it joins
+    for (uint32_t u : rest) {
+        uint64_t c;
+        const uint32_t p = walk_window(
+            keys, occ, uk[u], home_slot(uk[u]), probe, capacity, c);
+        if (p == probe) continue;  // waits for the eviction
+        if (occ[c]) {
+            slot_u[u] = int64_t(c);
+            touch[c] = seq;
+        } else {
+            probing.push_back(u);
+            round_of.push_back(p);
+        }
+    }
+
+    // the rounds, a slot a round
+    for (uint32_t p = 1; p < probe && !probing.empty(); ++p) {
+        size_t kept = 0;
+        for (size_t k = 0; k < probing.size(); ++k) {
+            const uint32_t u = probing[k];
+            bool go_on = true;
+            if (round_of[k] <= p) {
+                const uint64_t c = (home_slot(uk[u]) + p) % capacity;
+                if (!occ[c]) {
+                    claim_empty(u, c);
+                    go_on = false;
+                } else if (keys[c] == uk[u]) {
+                    slot_u[u] = int64_t(c);
+                    touch[c] = seq;
+                    go_on = false;
+                }
+            }
+            if (go_on) {
+                probing[kept] = u;
+                round_of[kept++] = round_of[k];
+            }
+        }
+        probing.resize(kept);
+        round_of.resize(kept);
+    }
+
+    // the eviction: whoever has no slot yet, ascending
+    std::vector<uint32_t> waiting;
+    for (uint32_t u : rest)
+        if (slot_u[u] < 0) waiting.push_back(u);
+    std::vector<uint64_t> named(waiting.size());
+    while (!waiting.empty()) {
+        size_t w = 0;
+        for (uint32_t u : waiting) {
+            // 1: the oldest stamp, the first of equals in probe order
+            uint64_t best;
+            const int64_t oldest = oldest_of_window(
+                touch, home_slot(uk[u]), probe, capacity, best);
+            if (oldest < seq) {
+                waiting[w] = u;
+                named[w++] = best;
+            } else {
+                ++counts[2];  // 4: the whole window is this call's
+            }
+        }
+        waiting.resize(w);
+        size_t lost = 0;
+        for (size_t k = 0; k < w; ++k) {
+            const uint32_t u = waiting[k];
+            const uint64_t c = named[k];
+            if (touch[c] != seq) {  // 2: the first to name it
+                keys[c] = uk[u];
+                touch[c] = seq;
+                slot_u[u] = int64_t(c);
+                reset_u[u] = 1;
+                ++counts[1];
+            } else {
+                waiting[lost++] = u;  // 3: names again
+            }
+        }
+        waiting.resize(lost);
+    }
+
+    for (size_t u = 0; u < m; ++u) {
+        const int32_t s = int32_t(slot_u[u] < 0 ? int64_t(capacity) : slot_u[u]);
+        for (uint32_t j = first[u]; j < first[u + 1]; ++j) {
+            const uint32_t i = uint32_t(order[j]);
+            slots[i] = s;
+            reset[i] = reset_u[u];
+        }
+    }
 }
 
 }  // extern "C"

@@ -183,7 +183,7 @@ def _load() -> Optional[ctypes.CDLL]:
             ctypes.c_int64,                   # row stride (bytes)
             ctypes.POINTER(ctypes.c_uint32),  # out hashes [n]
         ]
-        lib.fjt_state_resolve.restype = ctypes.c_uint64  # collided
+        lib.fjt_state_resolve.restype = ctypes.c_uint64  # records left
         lib.fjt_state_resolve.argtypes = [
             ctypes.POINTER(ctypes.c_uint32),  # khash [n]
             ctypes.POINTER(ctypes.c_uint8),   # apply [n]
@@ -195,7 +195,22 @@ def _load() -> Optional[ctypes.CDLL]:
             ctypes.c_uint32,                  # probe
             ctypes.c_int64,                   # seq
             ctypes.POINTER(ctypes.c_int32),   # slots [n] (hits written)
-            ctypes.POINTER(ctypes.c_uint8),   # out pending [n]
+            ctypes.POINTER(ctypes.c_int64),   # out: the records left [n]
+            ctypes.POINTER(ctypes.c_uint64),  # out: collided
+        ]
+        lib.fjt_state_claim.restype = None
+        lib.fjt_state_claim.argtypes = [
+            ctypes.POINTER(ctypes.c_uint32),  # khash [n]
+            ctypes.c_uint64,                  # n
+            ctypes.POINTER(ctypes.c_uint32),  # mirror keys (claimed)
+            ctypes.POINTER(ctypes.c_uint8),   # mirror occupancy (claimed)
+            ctypes.POINTER(ctypes.c_int64),   # mirror touch (stamped)
+            ctypes.c_uint64,                  # capacity
+            ctypes.c_uint32,                  # probe
+            ctypes.c_int64,                   # seq
+            ctypes.POINTER(ctypes.c_int32),   # out slots [n]
+            ctypes.POINTER(ctypes.c_uint8),   # out reset [n]
+            ctypes.POINTER(ctypes.c_uint64),  # out counts [4]
         ]
         _lib = lib
         return _lib
@@ -477,16 +492,41 @@ def state_resolve(
     """One pass of ``KeyedStateTable.route`` over a batch: every applied
     record whose key is found in its probe window, before any empty
     slot, gets its slot written into ``slots`` and stamped ``seq`` in
-    ``touch``. → ``(pending bool[n], collided)``: the records left to
-    the caller's rounds, and the keys found past their home slot. The
-    arrays are the table's own (contiguous uint32 / bool / int64);
-    callers check :func:`available` first."""
+    ``touch``. → ``(todo int64[k], collided)``: the indices of the
+    records left to the caller's rounds, ascending, and the keys found
+    past their home slot. The arrays are the table's own (contiguous
+    uint32 / bool / int64); callers check :func:`available` first."""
     n = khash.shape[0]
-    pending = np.empty(n, bool)
-    collided = _load().fjt_state_resolve(
+    todo = np.empty(n, np.int64)
+    collided = ctypes.c_uint64(0)
+    k = _load().fjt_state_resolve(
         _ptr(khash, ctypes.c_uint32), _ptr(apply, ctypes.c_uint8), n,
         _ptr(keys, ctypes.c_uint32), _ptr(occ, ctypes.c_uint8),
         _ptr(touch, ctypes.c_int64), keys.shape[0], probe, seq,
-        _ptr(slots, ctypes.c_int32), _ptr(pending, ctypes.c_uint8),
+        _ptr(slots, ctypes.c_int32), _ptr(todo, ctypes.c_int64),
+        ctypes.byref(collided),
     )
-    return pending, int(collided)
+    return todo[:k], int(collided.value)
+
+
+def state_claim(
+    khash: np.ndarray, keys: np.ndarray, occ: np.ndarray,
+    touch: np.ndarray, probe: int, seq: int,
+) -> Tuple[np.ndarray, np.ndarray, Tuple[int, int, int, int]]:
+    """``KeyedStateTable._claim_rounds`` in one native call: its rounds
+    and its eviction rule over the records ``khash`` (contiguous uint32),
+    claiming and evicting in the table's own arrays. → ``(slots int32[n],
+    reset bool[n], (inserts, evictions, overflows, collided))``, the
+    scratch slot spelled ``capacity``. Callers check :func:`available`
+    first."""
+    n = khash.shape[0]
+    slots = np.empty(n, np.int32)
+    reset = np.empty(n, bool)
+    counts = np.zeros(4, np.uint64)
+    _load().fjt_state_claim(
+        _ptr(khash, ctypes.c_uint32), n, _ptr(keys, ctypes.c_uint32),
+        _ptr(occ, ctypes.c_uint8), _ptr(touch, ctypes.c_int64),
+        keys.shape[0], probe, seq, _ptr(slots, ctypes.c_int32),
+        _ptr(reset, ctypes.c_uint8), _ptr(counts, ctypes.c_uint64),
+    )
+    return slots, reset, tuple(int(c) for c in counts)

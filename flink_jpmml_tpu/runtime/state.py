@@ -22,8 +22,9 @@ Division of labor — host routes, device accumulates:
   is resident, found in its bounded linear probe window before any
   empty slot, is resolved by one native pass over the batch
   (``_native/fjt_native.cpp``, which also hashes the block's key
-  column); the records it leaves go through vectorized rounds over
-  their deduped keys: probe the window, claim empties, evict the
+  column); the records it leaves go through rounds over their deduped
+  keys (a second native call; vectorized numpy on a host that cannot
+  build the library): probe the window, claim empties, evict the
   least-recently-touched slot when the window is full. A key that is
   not resident and finds its window full takes the slot of its window
   that was touched longest ago and not in this routing call; where
@@ -396,7 +397,7 @@ class KeyedStateTable:
     def assign_slots(self, khash: np.ndarray, offsets=None):
         """Resolve one batch of key hashes to table slots on the host:
         ``route`` (one native pass over the batch for the keys that are
-        resident, vectorized numpy rounds for the rest), the
+        resident, the claim rounds for the rest), the
         exactly-once high-water and the decay operands — the per-batch
         routing cost beside the key hash.
 
@@ -473,10 +474,10 @@ class KeyedStateTable:
         seq = self._seq
         offs = np.asarray(offsets, np.int64)
         apply = offs >= self.skip_until
-        n_bypass = int(B - apply.sum())
+        n_bypass = B - int(np.count_nonzero(apply))
         slots = np.full(B, self.scratch, np.int32)
         reset = np.zeros(B, bool)
-        todo, collided = apply, 0
+        collided = 0
         if B and native.available():
             # a hit changes neither ``_occ`` nor ``_keys`` and every
             # slot before it is occupied, so no claim or eviction below
@@ -487,10 +488,12 @@ class KeyedStateTable:
                 khash, apply, self._keys, self._occ, self._touch,
                 self.spec.probe, seq, slots,
             )
+        else:
+            todo = np.flatnonzero(apply)
         if held is not None and len(held):
             hs = np.asarray(held, np.int64)
             self._touch[hs[hs < self.capacity]] = seq
-        n_todo = int(todo.sum())
+        n_todo = todo.shape[0]
         if n_todo:
             # a stage of its own inside the caller's ``route`` span:
             # nothing is booked where the rounds do not run
@@ -500,10 +503,10 @@ class KeyedStateTable:
             collided += c
             self._c_pending.inc(n_todo)
         if n_bypass < B:
-            hits = int(
-                (apply & (slots != self.scratch) & ~reset).sum()
-            )
-            self._c_hits.inc(hits)
+            # a record with a slot applies, and one that is reset has a
+            # slot: the hits are the rest of those with a slot
+            self._c_hits.inc(int(np.count_nonzero(slots != self.scratch)
+                                 - np.count_nonzero(reset)))
             self._c_collisions.inc(collided)
         self._c_records.inc(B)
         if n_bypass:
@@ -551,7 +554,22 @@ class KeyedStateTable:
            (``state_overflow``).
 
         An EMPTY slot is won as it always was: in probe order, one
-        claimant a slot a round, the smallest hash first."""
+        claimant a slot a round, the smallest hash first.
+
+        Where the native library is built one call of it runs these
+        rounds (``fjt_state_claim``); the numpy body below is the same
+        rounds for a host without it, and what the tests hold the
+        native form to, table and answers byte for byte."""
+        if native.available():
+            slots, reset, (ins, evicted, overflowed, collided) = (
+                native.state_claim(
+                    np.ascontiguousarray(khash, np.uint32), self._keys,
+                    self._occ, self._touch, self.spec.probe, seq))
+            self.resident += ins
+            self._c_inserts.inc(ins)
+            self._c_evictions.inc(evicted)
+            self._c_overflow.inc(overflowed)
+            return slots, reset, collided
         uk, inv = np.unique(khash, return_inverse=True)
         cap, probe = self.capacity, self.spec.probe
         base = uk.astype(np.int64) % cap

@@ -171,6 +171,99 @@ class TestStateHash:
         assert np.array_equal(t.hash_block(X), want)
 
 
+def _plain_resolve(khash, apply, keys, occ, touch, probe, seq, slots):
+    """``fjt_state_resolve`` as a plain walk, a record at a time and
+    nothing ahead of the record: → ``(pending, collided)``."""
+    cap = keys.shape[0]
+    pending, collided = np.zeros(khash.size, bool), 0
+    for i, h in enumerate(khash.tolist()):
+        if not apply[i]:
+            continue
+        c, p = h % cap, 0
+        while p < probe and occ[c] and keys[c] != h:
+            c, p = (c + 1) % cap, p + 1
+        if p < probe and occ[c]:
+            slots[i] = c
+            if touch[c] != seq:
+                touch[c] = seq
+                collided += p != 0
+        else:
+            pending[i] = True
+    return pending, collided
+
+
+def _mirror(rng, cap, load):
+    """A mirror that linear probing has filled to ``load``, and the
+    hashes that live in it."""
+    keys, occ = np.zeros(cap, np.uint32), np.zeros(cap, bool)
+    hashes = rng.choice(2**32, int(cap * load), replace=False).astype(
+        np.uint32)
+    for h in hashes.tolist():
+        c = h % cap
+        while occ[c]:
+            c = (c + 1) % cap
+        keys[c], occ[c] = h, True
+    return keys, occ, rng.integers(1, 50, cap).astype(np.int64), hashes
+
+
+_RESOLVE_N = (0, 1, 15, 16, 17, 33, 65536)
+
+
+@needs_native
+class TestStateResolve:
+    """``fjt_state_resolve`` walks a record's window some records ahead
+    of its stamp; the answers are those of a walk at the record itself:
+    the records left pending, ``slots``, every stamp and the count, for
+    any ``n``, the ones shorter than the pipeline included."""
+
+    @pytest.mark.parametrize("n", _RESOLVE_N)
+    @pytest.mark.parametrize("cap,probe,load", [
+        (4099, 8, 0.9), (257, 64, 0.98), (5, 9, 0.8)])
+    def test_equals_a_plain_walk(self, n, cap, probe, load):
+        rng = np.random.default_rng([n, cap])
+        keys, occ, touch, hashes = _mirror(rng, cap, load)
+        # resident keys (many of them more than once: ``collided`` counts
+        # a key once a call), keys the table has not got, records that
+        # do not apply
+        khash = np.where(
+            rng.random(n) < 0.7, hashes[rng.integers(0, hashes.size, n)],
+            rng.integers(0, 2**32, n)).astype(np.uint32)
+        apply = rng.random(n) < 0.9
+        seq = 77
+        want_slots = np.full(n, cap, np.int32)
+        want_touch = touch.copy()
+        want_pending, want_collided = _plain_resolve(
+            khash, apply, keys, occ, want_touch, probe, seq, want_slots)
+        slots = np.full(n, cap, np.int32)
+        keys0, occ0 = keys.copy(), occ.copy()
+        todo, collided = native.state_resolve(
+            khash, apply, keys, occ, touch, probe, seq, slots)
+        assert todo.dtype == np.int64
+        assert np.array_equal(todo, np.flatnonzero(want_pending))
+        assert np.array_equal(slots, want_slots)
+        assert np.array_equal(touch, want_touch)
+        assert collided == want_collided
+        assert np.array_equal(keys, keys0) and np.array_equal(occ, occ0)
+        if n == 65536:
+            assert 0 < todo.size < apply.sum() and collided > 0
+
+    def test_a_key_twice_in_a_call_collides_once(self):
+        # 19 lives behind 3, a slot past its home; 35 is not resident
+        keys = np.zeros(16, np.uint32)
+        occ = np.zeros(16, bool)
+        keys[3:5], occ[3:5] = [3, 19], True
+        touch = np.zeros(16, np.int64)
+        khash = np.array([19, 3, 19, 35, 19, 7], np.uint32)
+        apply = np.array([1, 1, 1, 1, 0, 1], bool)
+        slots = np.full(6, 16, np.int32)
+        todo, collided = native.state_resolve(
+            khash, apply, keys, occ, touch, 4, 9, slots)
+        assert slots.tolist() == [4, 3, 4, 16, 16, 16]
+        assert todo.tolist() == [3, 5]
+        assert collided == 1
+        assert touch[3:5].tolist() == [9, 9] and touch.sum() == 18
+
+
 class TestBlockPipeline:
     @pytest.fixture()
     def iris_model(self, assets_dir):

@@ -254,9 +254,12 @@ def _random_stream():
     return calls
 
 
-def _route_both_ways(case, capacity, probe, monkeypatch):
-    """→ the native table's ``state_route_pending`` at the end."""
-    (a, ma), (b, mb) = (_table(capacity, probe) for _ in range(2))
+def _route_both_ways(case, capacity, probe, monkeypatch, tables=None):
+    """→ the native table's ``state_route_pending`` at the end.
+    ``tables``: two ``(table, metrics)`` alike to start from, where not
+    from empty ones."""
+    (a, ma), (b, mb) = tables or (
+        _table(capacity, probe) for _ in range(2))
     for i, call in enumerate(case):
         kh = call["khash"]
         offs = np.arange(call["first"], call["first"] + kh.size)
@@ -367,6 +370,203 @@ class TestEvictionRace:
         assert h["n"] == (n1 if native_mod.available() else n1 + 1)
 
 
+# -- the rounds, natively (ISSUE 37) -------------------------------------------
+#
+# Where the library is built ``_claim_rounds`` is one native call; its numpy
+# body is the same rounds. Both get the same mirror and the same records
+# (every record of the call, so the rounds' own hits run too) and have to
+# leave the same table, answers and counts. A case is a mirror written
+# slot by slot ``{slot: (hash, stamp)}``, the call's hashes and its
+# ``held`` slots; capacity 16 and probe 4 unless it says otherwise, and the
+# call's stamp is 9, newer than every stamp of a mirror.
+
+_SEQ = 9
+# the window 3..6 of the keys at home 3, and 7 behind it: every slot the
+# keys at homes 3 and 4 can name is taken, the stamps rising with the slot
+_FULL_3_TO_7 = {3: (3, 1), 4: (19, 2), 5: (35, 3), 6: (51, 4), 7: (7, 5)}
+_CLAIM_CASES = {
+    # 51 holds slot 3 and 4..7 are empty: 4 takes its home and 20 the slot
+    # behind it, 3 reaches the first slot nobody took in round 3, and 19
+    # and 35, behind it all the way, evict: 19 has 51's slot and 35, whose
+    # window is this call's by then, overflows
+    "empty_slots_contended_round_by_round": dict(
+        mirror={3: (51, 1)}, khash=[35, 4, 19, 20, 3, 19],
+        slots=[16, 4, 3, 5, 6, 3], window={3: 19, 4: 4, 5: 20, 6: 3},
+        counts=(3, 1, 1), collided=4),
+    # 67, 83 and 99 name slot 3 and 67 has it; 116, the LARGEST hash, names
+    # slot 4 in the same round and has it, though it is 83's second
+    # choice: 83 and 99 name again from 5 and 6
+    "three_keys_name_one_victim": dict(
+        mirror=_FULL_3_TO_7, khash=[116, 99, 83, 67],
+        slots=[4, 6, 5, 3], window={3: 67, 4: 116, 5: 83, 6: 99, 7: 7},
+        counts=(0, 4, 0), collided=4),
+    # slots 3 and 5 are held for records not dispatched yet: 67 and 83
+    # share 4 and 6
+    "held_slots_are_nobodys_victim": dict(
+        mirror=_FULL_3_TO_7, khash=[83, 67], held=[3, 5, 16],
+        slots=[6, 4], window={3: 3, 4: 67, 5: 35, 6: 83},
+        counts=(0, 2, 0), collided=2),
+    # 19's record touches slot 4; four fresh keys want the three slots left
+    "a_window_this_call_has_touched_whole": dict(
+        mirror=_FULL_3_TO_7, khash=[131, 19, 67, 83, 99],
+        slots=[16, 4, 3, 5, 6], window={3: 67, 4: 19, 5: 83, 6: 99},
+        counts=(0, 3, 1), collided=5),
+    # home 14: the window is 14, 15, 0, 1, and 46's record touches slot 0:
+    # the stamps of the other three fall 14, 15, 1
+    "a_window_that_wraps_the_end_of_the_table": dict(
+        mirror={14: (14, 4), 15: (30, 3), 0: (46, 1), 1: (62, 2)},
+        khash=[78, 46, 94, 110], slots=[1, 0, 15, 14],
+        window={14: 110, 15: 94, 0: 46, 1: 78},
+        counts=(0, 3, 0), collided=4),
+    # three slots, a window of five: slots 1 and 2 are named twice
+    "a_window_wider_than_the_table": dict(
+        capacity=3, probe=5, mirror={0: (3, 2), 1: (1, 3), 2: (2, 1)},
+        khash=[4, 7, 10, 13], slots=[2, 0, 1, 3],
+        window={0: 7, 1: 10, 2: 4}, counts=(0, 3, 1), collided=4),
+    "a_hole_in_a_window_wider_than_the_table": dict(
+        capacity=3, probe=5, mirror={1: (1, 3)},
+        khash=[4, 7, 1], slots=[2, 0, 1],
+        window={0: 7, 1: 1, 2: 4}, counts=(2, 0, 0), collided=2),
+    "no_record": dict(mirror=_FULL_3_TO_7, khash=[], slots=[],
+                      window={3: 3}, counts=(0, 0, 0), collided=0),
+}
+
+
+def _with_mirror(mirror, capacity=16, probe=4):
+    t, m = _table(capacity, probe)
+    for s, (h, stamp) in mirror.items():
+        t._keys[s], t._occ[s], t._touch[s] = h, True, stamp
+    t.resident, t._seq = len(mirror), _SEQ - 1
+    return t, m
+
+
+def _claim_both_ways(mirror, khash, monkeypatch, held=(), capacity=16,
+                     probe=4):
+    """→ the native side's ``(table, slots, reset, collided, counters)``,
+    the numpy body's held equal to it."""
+    kh = np.asarray(khash, np.uint32)
+    out = []
+    for masked in (False, True):
+        t, m = _with_mirror(mirror, capacity, probe)
+        hs = np.asarray(held, np.int64)
+        t._touch[hs[hs < capacity]] = _SEQ  # as ``route`` stamps them
+        with monkeypatch.context() as mp:
+            if masked:
+                mp.setattr(native_mod, "available", lambda: False)
+            slots, reset, collided = t._claim_rounds(kh, _SEQ)
+        assert slots.dtype == np.int32 and reset.dtype == bool
+        c = m.struct_snapshot()["counters"]
+        out.append((t, slots, reset, int(collided), tuple(int(c[n]) for n in (
+            "state_inserts", "state_evictions", "state_overflow"))))
+    (a, *got), (b, *want) = out
+    for name, x, y in zip(("slots", "reset", "collided", "counters"),
+                          got, want):
+        assert np.array_equal(x, y), (name, x, y)
+    for name in ("_keys", "_occ", "_touch"):
+        assert np.array_equal(getattr(a, name), getattr(b, name)), name
+    assert a.resident == b.resident
+    return out[0]
+
+
+def _awarded_while_naming(t, khash, seq):
+    """The eviction of a FULL table in ONE ascending pass a round, each
+    key awarded its slot the moment it names it: not the rule. A key then
+    sees the stamps of the smaller hashes of its own round and takes its
+    second choice a round early."""
+    cap, probe = t.capacity, t.spec.probe
+    for h in sorted(set(np.asarray(khash).tolist())):
+        W = [(h % cap + p) % cap for p in range(probe)]
+        c = min(W, key=lambda s: t._touch[s])  # the first of equal stamps
+        assert t._touch[c] < seq
+        t._keys[c], t._touch[c] = h, seq
+
+
+@pytest.mark.skipif(not native_mod.available(), reason="no native library")
+class TestNativeRounds:
+    @pytest.mark.parametrize("name", sorted(_CLAIM_CASES))
+    def test_the_native_rounds_leave_the_numpy_bodys_table(
+            self, name, monkeypatch):
+        case = dict(_CLAIM_CASES[name])
+        want = {k: case.pop(k) for k in (
+            "slots", "window", "counts", "collided")}
+        t, slots, reset, collided, counts = _claim_both_ways(
+            monkeypatch=monkeypatch, **case)
+        assert slots.tolist() == want["slots"]
+        assert {s: int(t._keys[s]) for s in want["window"]} == want["window"]
+        assert (counts, collided) == (want["counts"], want["collided"])
+        # a record is reset where its key was given its slot by this call
+        was = {h for h, _ in case["mirror"].values()}
+        fresh = [h not in was and s != t.scratch
+                 for h, s in zip(case["khash"], want["slots"])]
+        assert reset.tolist() == fresh
+        assert t.resident == len(case["mirror"]) + counts[0]
+        # every slot answered is stamped by the call, and no other but held
+        stamped = set(np.flatnonzero(t._touch == _SEQ).tolist())
+        held = {s for s in case.get("held", ()) if s < t.capacity}
+        assert stamped == (set(want["slots"]) - {t.scratch}) | held
+
+    @pytest.mark.parametrize("name", sorted(_CLAIM_CASES))
+    def test_through_route_as_through_the_rounds_alone(
+            self, name, monkeypatch):
+        case = _CLAIM_CASES[name]
+        geometry = (case.get("capacity", 16), case.get("probe", 4))
+        tables = [_with_mirror(case["mirror"], *geometry) for _ in range(2)]
+        kh = np.asarray(case["khash"], np.uint32)
+        _route_both_ways(
+            [_call(kh, 0, held=case.get("held")), _call(kh[::-1], kh.size)],
+            *geometry, monkeypatch, tables=tables)
+        assert {s: int(tables[0][0]._keys[s]) for s in case["window"]} == (
+            case["window"])
+
+    def test_two_passes_a_round_are_told_from_one(self, monkeypatch):
+        """The case of three keys and one victim is built so that an
+        award made in the naming pass gives ANOTHER table: 83 would see
+        67's stamp on slot 3, name slot 4 a round early and take it from
+        116, which the rule gives it to."""
+        case = _CLAIM_CASES["three_keys_name_one_victim"]
+        t, *_ = _claim_both_ways(
+            case["mirror"], case["khash"], monkeypatch)
+        one, _ = _with_mirror(case["mirror"])
+        _awarded_while_naming(one, case["khash"], _SEQ)
+        assert one._keys[3:8].tolist() == [67, 83, 99, 116, 7]
+        assert t._keys[3:8].tolist() == [67, 116, 83, 99, 7]
+        # and the plain reference, which knows nothing of either, agrees
+        # with the two passes
+        if _BENCH not in sys.path:
+            sys.path.insert(0, _BENCH)
+        from reference import churn_ref
+
+        ref, _ = _with_mirror(case["mirror"])
+        answer = churn_ref.WindowTable(
+            ref._keys, ref._occ, ref._touch, 4).call(case["khash"])
+        assert ref._keys[3:8].tolist() == [67, 116, 83, 99, 7]
+        assert answer[83] == (5, True) and answer[116] == (4, True)
+
+    def test_random_tables_and_calls(self, monkeypatch):
+        """300 mirrors of 2 to 40 slots, windows of 1 to 12 (wider than
+        the table too), holes and stamps at random, calls of 0 to 60
+        records of which half are resident keys."""
+        rng = np.random.default_rng(37)
+        seen = np.zeros(3, np.int64)
+        for _ in range(300):
+            cap, probe = int(rng.integers(2, 41)), int(rng.integers(1, 13))
+            mirror = {}
+            for h in rng.choice(400, int(cap * rng.random()), replace=False):
+                for p in range(min(probe, cap)):
+                    if (h + p) % cap not in mirror:
+                        mirror[(int(h) + p) % cap] = (
+                            int(h), int(rng.integers(1, 6)))
+                        break
+            resident = [h for h, _ in mirror.values()] or [0]
+            n = int(rng.integers(0, 61))
+            khash = np.where(rng.random(n) < 0.5, rng.choice(resident, n),
+                             rng.integers(0, 400, n))
+            held = rng.integers(0, cap + 1, int(rng.integers(0, 4)))
+            seen += _claim_both_ways(
+                mirror, khash, monkeypatch, held, cap, probe)[4]
+        assert (seen > 100).all()  # inserts, evictions, overflows
+
+
 # A "latest" stream (YCSB workload D's key arrival, the benchmark's key
 # mix) over a FULL table at probe 8, 200 routing calls, against the
 # benchmark's plain reference (``benchmark/reference/churn_ref.py``: plain
@@ -460,8 +660,14 @@ def _latest_stream_against_the_reference(seed):
     }
 
 
+@pytest.mark.parametrize("rounds", ["native", "numpy"])
 @pytest.mark.parametrize("seed", [3, 2**31 + 17])
-def test_a_latest_stream_over_a_full_table_equals_the_plain_reference(seed):
+def test_a_latest_stream_over_a_full_table_equals_the_plain_reference(
+        seed, rounds, monkeypatch):
+    if rounds == "numpy":
+        monkeypatch.setattr(native_mod, "available", lambda: False)
+    elif not native_mod.available():
+        pytest.skip("no native library")
     d = _latest_stream_against_the_reference(seed)
     assert d.pop("evictions") > 1000
     assert d == dict.fromkeys(d, 0)
