@@ -1720,8 +1720,10 @@ class KafkaBlockSource(_KafkaSourceBase, BlockSource):
     time into a ``kafka_decode_s`` counter — the consumer-thread half
     of the stream's host budget, reported next to the score loop's
     ``encode_s`` so the bench's ``kafka_mode`` can say where consumer
-    CPU goes (``decode_ms``) — plus the base class's fetch-latency
-    histogram and per-partition ``kafka_lag`` gauges."""
+    CPU goes (``decode_ms``), a ``kafka_crc32c_hw`` gauge (1 where the
+    batches' CRC32C runs on the CPU's crc32 instruction, 0 on the byte
+    table) — plus the base class's fetch-latency histogram and
+    per-partition ``kafka_lag`` gauges."""
 
     # network source with real fetch latency: the pipelines wrap it in
     # a prefetch sidecar (runtime/prefetch.py) unless disabled
@@ -1733,6 +1735,14 @@ class KafkaBlockSource(_KafkaSourceBase, BlockSource):
         self._decode_s = (
             metrics.counter("kafka_decode_s") if metrics is not None else None
         )
+        if metrics is not None:
+            from flink_jpmml_tpu.runtime import native
+
+            # every batch's CRC32C runs on the crc32 instruction (1) or
+            # on none (0): the host's CPU picks, once
+            metrics.gauge("kafka_crc32c_hw").set(
+                float(native.crc32c_impl() == "sse4.2")
+            )
         # per-slot decoded row buffers: slot → [rows...] contiguous from
         # that slot's next needed partition offset (multi-partition only)
         self._rbufs: Dict[int, np.ndarray] = {}

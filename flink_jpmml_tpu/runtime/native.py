@@ -176,6 +176,12 @@ def _load() -> Optional[ctypes.CDLL]:
             ctypes.c_int64,                   # out capacity (records)
             ctypes.POINTER(ctypes.c_int64),   # out offsets [cap]
         ]
+        for name in ("fjt_crc32c", "fjt_crc32c_table"):
+            fn = getattr(lib, name)
+            fn.restype = ctypes.c_uint32
+            fn.argtypes = [ctypes.c_void_p, ctypes.c_int64]
+        lib.fjt_crc32c_hw.restype = ctypes.c_int
+        lib.fjt_crc32c_hw.argtypes = []
         lib.fjt_state_hash_f32.restype = None
         lib.fjt_state_hash_f32.argtypes = [
             ctypes.c_void_p,                  # first record's key (f32)
@@ -363,6 +369,36 @@ def kafka_decode_fixed(
         raise ValueError(f"malformed record batch (native rc={rc})")
     n = int(rc)
     return offs[:n].copy(), out[:n].copy()
+
+
+def _crc32c_with(fn_name: str, data) -> Optional[int]:
+    lib = _load()
+    if lib is None:
+        return None
+    buf = np.frombuffer(data, np.uint8)  # zero-copy, read-only view
+    return int(getattr(lib, fn_name)(buf.ctypes.data, buf.size))
+
+
+def crc32c(data) -> Optional[int]:
+    """CRC32C of ``data`` as both Kafka codecs compute it: on the CPU's
+    crc32 instruction where the host has SSE4.2 (:func:`crc32c_impl`),
+    else through the byte table. None when the library is missing."""
+    return _crc32c_with("fjt_crc32c", data)
+
+
+def crc32c_table(data) -> Optional[int]:
+    """The byte-table CRC32C alone, whatever the host (the fallback,
+    kept callable so tests hold both to the Python oracle)."""
+    return _crc32c_with("fjt_crc32c_table", data)
+
+
+def crc32c_impl() -> Optional[str]:
+    """Which CRC32C the library chose at load: ``"sse4.2"`` or
+    ``"table"``; None when the library is missing."""
+    lib = _load()
+    if lib is None:
+        return None
+    return "sse4.2" if lib.fjt_crc32c_hw() else "table"
 
 
 def bucketize(

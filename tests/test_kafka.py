@@ -28,6 +28,7 @@ from flink_jpmml_tpu.runtime.kafka import (
 )
 from flink_jpmml_tpu.runtime.sinks import CollectSink
 from flink_jpmml_tpu.utils.config import BatchConfig, RuntimeConfig
+from flink_jpmml_tpu.utils.metrics import MetricsRegistry as _MReg
 
 
 class TestProtocolBytes:
@@ -125,6 +126,114 @@ class TestNativeRowDecode:
         bad[-1] ^= 0xFF
         with pytest.raises(ValueError, match="CRC32C"):
             decode_record_batches_rows(bytes(bad), 4)
+
+
+def _native_or_skip():
+    from flink_jpmml_tpu.runtime import native
+
+    if not native.available():
+        pytest.skip("native library unavailable")
+    return native
+
+
+def _batch_with_body_residue(residue: int, n_cols: int = 3):
+    """→ (rows, raw): one encoded batch whose CRC-covered body (all but
+    its first 21 bytes) is ``residue`` bytes past a multiple of 8."""
+    rows = np.random.default_rng(residue).normal(size=(64, n_cols))
+    rows = rows.astype(np.float32)
+    for n in range(1, 65):
+        raw = encode_record_batch(0, [rows[i].tobytes() for i in range(n)])
+        if (len(raw) - 21) % 8 == residue:
+            return rows[:n], raw
+    raise AssertionError(f"no record count gives residue {residue}")
+
+
+class TestNativeCrc32c:
+    """Both CRC32C implementations of the native library — the one the
+    Kafka codecs use (the crc32 instruction on an SSE4.2 host) and the
+    byte table — equal the Python oracle on every tail length, on both
+    sides of a word boundary, at every start alignment."""
+
+    @staticmethod
+    def _cases(case):
+        rng = np.random.default_rng(38)
+        if case == "vector":
+            return [(b"123456789", 0xE3069283)]
+        if case == "empty":
+            return [(b"", 0)]
+        if case == "lengths":
+            data = rng.integers(0, 256, 4103, dtype=np.uint8).tobytes()
+            ns = list(range(73)) + list(range(4093, 4104))
+            return [(data[:n], crc32c(data[:n])) for n in ns]
+        if case == "offsets":
+            # memoryview slices: the pointer handed over really starts
+            # 0-7 bytes past the buffer's own alignment
+            data = rng.integers(0, 256, 4200, dtype=np.uint8).tobytes()
+            view = memoryview(data)
+            return [
+                (view[k : k + n], crc32c(data[k : k + n]))
+                for k in range(8)
+                for n in (0, 1, 7, 8, 9, 15, 16, 17, 63, 4097)
+            ]
+        assert case == "batch_body"  # 512 records of 32 f32: ~70 KB
+        rows = rng.normal(size=(512, 32)).astype(np.float32)
+        raw = encode_record_batch(0, [r.tobytes() for r in rows])
+        assert len(raw) > 70_000
+        return [(raw[21:], crc32c(raw[21:]))]
+
+    @pytest.mark.parametrize(
+        "case", ["vector", "empty", "lengths", "offsets", "batch_body"]
+    )
+    @pytest.mark.parametrize("impl", ["crc32c", "crc32c_table"])
+    def test_matches_python_oracle(self, impl, case):
+        fn = getattr(_native_or_skip(), impl)
+        for data, want in self._cases(case):
+            assert fn(data) == want, (impl, case, len(data))
+
+    def test_impl_is_named(self):
+        native = _native_or_skip()
+        assert native.crc32c_impl() in ("sse4.2", "table")
+
+    @pytest.mark.parametrize("back", range(1, 9))
+    def test_decode_rejects_a_flip_in_the_tail(self, back):
+        """A body of 8k+7 bytes: its last seven are the tail loop's, the
+        eighth from the end the last word's."""
+        native = _native_or_skip()
+        _, raw = _batch_with_body_residue(7)
+        assert native.kafka_decode_fixed(raw, 12) is not None
+        bad = bytearray(raw)
+        bad[-back] ^= 0x01
+        with pytest.raises(ValueError, match="CRC32C"):
+            native.kafka_decode_fixed(bytes(bad), 12)
+
+    @pytest.mark.parametrize("residue", range(8))
+    def test_encode_byte_exact_every_residue(self, residue):
+        native = _native_or_skip()
+        rows, ref = _batch_with_body_residue(residue)
+        got = native.kafka_encode_fixed(rows.view(np.uint8), 0)
+        assert got == ref
+
+    def test_block_source_reports_the_impl(self):
+        native = _native_or_skip()
+        broker = MiniKafkaBroker(topic="c")
+        try:
+            broker.append_rows(np.ones((4, 2), np.float32))
+            m = _MReg()
+            src = KafkaBlockSource(
+                broker.host, broker.port, "c", n_cols=2, metrics=m,
+                max_wait_ms=10,
+            )
+            try:
+                while src.poll() is None:
+                    pass
+            finally:
+                src.close()
+            snap = m.struct_snapshot()
+            want = float(native.crc32c_impl() == "sse4.2")
+            assert snap["gauges"]["kafka_crc32c_hw"]["value"] == want
+            assert snap["counters"]["kafka_decode_s"] > 0
+        finally:
+            broker.close()
 
 
 class TestProtocolFuzz:
@@ -1353,9 +1462,6 @@ class TestEventTimeStrictInterleave:
                 src.close()
         finally:
             broker.close()
-
-
-from flink_jpmml_tpu.utils.metrics import MetricsRegistry as _MReg
 
 
 class TestDecodePoisonRouting:
