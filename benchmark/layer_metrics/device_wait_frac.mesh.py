@@ -1,0 +1,5 @@
+"""``device_wait_frac.sat`` read in the mesh cell, where it moves
+``records_per_s.mesh``: the same reader."""
+from lib import byname
+
+read = byname.load("layer_metrics", "device_wait_frac.sat").read

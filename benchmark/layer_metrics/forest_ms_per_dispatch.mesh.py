@@ -1,0 +1,5 @@
+"""``forest_ms_per_dispatch.sat`` read in the mesh cell, where it moves
+``records_per_s.mesh``: the same reader."""
+from lib import byname
+
+read = byname.load("layer_metrics", "forest_ms_per_dispatch.sat").read

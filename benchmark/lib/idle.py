@@ -13,9 +13,12 @@ from typing import Iterable, Optional
 
 import numpy as np
 
-# the score thread blocked on its ring: a program that lacks the span
-# has not split its drain's wait from its copies, and reports nothing
+# the score thread blocked on its ring: a program that books no such
+# stage has not split its drain's wait from its copies, and reports
+# nothing; a traced stretch in which the thread never waited holds no
+# such span, and its chips were starved for none of their idle time
 STARVED = ("fjt.drain_wait",)
+STARVED_STAGE = 'stage_seconds{stage="drain_wait"}'
 
 
 def union(intervals) -> np.ndarray:
@@ -67,12 +70,13 @@ def idle_points(ctx: dict, names: Iterable[str],
     """Of the chips' traced idle time, the share spent under a host span
     named in ``names`` and none named in ``less``, in % of chips ×
     stretch; None without a trace or where the program has no
-    ``STARVED`` span."""
+    ``STARVED`` span, in its stage ledger or in the trace."""
     tr = ctx.get("trace") or {}
     if not tr.get("devices") or not tr.get("window_s"):
         return None
     spans = tr.get("host_spans", [])
-    if not any(nm in STARVED for nm, _, _ in spans):
+    booked = STARVED_STAGE in (ctx.get("snap1") or {}).get("histograms", {})
+    if not booked and not any(nm in STARVED for nm, _, _ in spans):
         return None
     names, less = set(names), set(less)
     u = minus(union([(s, e) for nm, s, e in spans if nm in names]),

@@ -32,10 +32,11 @@ GB of fresh pages in 30 s, faulted in beside a pipeline that faults in
 its own mirror); no consumer of a closed backlog reads behind its sink.
 
 The producer has to out-run the program's own ingest (one prefetch
-sidecar fetches and decodes 1.86M records/s on the chip's host, PERF.md
-§5), or the window measures the producer. The stream's rows are made at
-6M records/s; the native record-batch encode (its CRC32C over every
-byte) takes 0.5 us a record and releases the interpreter lock. So one
+sidecar fetches and decodes about 4M records/s on a TPU v5e host,
+PERF.md §5), or the window measures the producer. The stream's rows are
+made at 6M records/s; the native record-batch encode (its CRC32C over
+every byte, on the CPU's crc32 instruction where it has one) takes 51 ns
+a record on that host and releases the interpreter lock. So one
 producer thread decides what to append, as before, and ``ENCODERS``
 threads draw and encode it in pieces of ``PIECE`` records, which the
 producer publishes in offset order as they come ready, handing out the

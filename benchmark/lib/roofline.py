@@ -2,8 +2,14 @@
 state-armed scoring program, from the configuration's shapes alone —
 not the kernel's padded MXU count, not the bytes XLA happens to move.
 
-Scoring, per record: one comparison per level of each tree and one add
-per tree. Bytes, per dispatch: the forest once (a feature index and a
+The forest's shape is the model kind's to state
+(``models/<model_kind>.py``, ``forest_shape(model) -> (trees, inner
+nodes, leaves, walk)``: the nodes and leaves summed over the forest,
+the walk the comparisons a record makes in one tree), so a forest of
+any shape is counted by what it is, whatever implements it.
+
+Scoring, per record: ``walk`` comparisons in each tree and one add per
+tree. Bytes, per dispatch: the forest once (a feature index and a
 threshold rank per inner node, a float32 per leaf), and per record the
 rank-wire row in (one byte a feature), the score out, and the state
 fold: the key's row read, the derived row out, the row written back,
@@ -16,25 +22,31 @@ rank-wire row in and the score out.
 
 from __future__ import annotations
 
-
-def ops(cfg: dict, n_records: float) -> float:
-    m = cfg["model"]
-    return n_records * m["n_trees"] * (m["depth"] + 1)
+from . import byname
 
 
-def _forest_bytes(m: dict) -> float:
-    inner, leaves = 2 ** m["depth"] - 1, 2 ** m["depth"]
-    return m["n_trees"] * (inner * 2 + leaves * 4)
+def forest_shape(cfg: dict):
+    """→ (trees, inner nodes, leaves, walk), as the model kind states
+    them for the configuration's ``model``. The counts below take it
+    as ``shape`` where a caller has it already: the kind's module is
+    loaded once a reading."""
+    return byname.load("models", cfg["model_kind"]).forest_shape(cfg["model"])
 
 
-def forest_bytes_moved(cfg: dict, n_records: float) -> float:
-    m = cfg["model"]
-    return _forest_bytes(m) + n_records * (m["n_features"] + 4)
+def ops(cfg: dict, n_records: float, shape=None) -> float:
+    n_trees, _, _, walk = shape or forest_shape(cfg)
+    return n_records * n_trees * (walk + 1)
 
 
-def bytes_moved(cfg: dict, n_records: float) -> float:
+def forest_bytes_moved(cfg: dict, n_records: float, shape=None) -> float:
+    _, inner, leaves, _ = shape or forest_shape(cfg)
+    return inner * 2 + leaves * 4 + n_records * (
+        cfg["model"]["n_features"] + 4)
+
+
+def bytes_moved(cfg: dict, n_records: float, shape=None) -> float:
     row = 4 * int(cfg["state"]["width_f32"])
-    return forest_bytes_moved(cfg, n_records) + n_records * (
+    return forest_bytes_moved(cfg, n_records, shape) + n_records * (
         3 * row + (4 + 4 + 4 + 1))
 
 
@@ -48,10 +60,13 @@ def least_seconds(cfg: dict, n_records: float, peaks: dict):
     """→ (seconds, binding roof). The rank wire compares integers, so
     the compute roof is the chip's int8 peak (the higher one: the share
     is never flattered)."""
-    return _least(ops(cfg, n_records), bytes_moved(cfg, n_records), peaks)
+    shape = forest_shape(cfg)
+    return _least(ops(cfg, n_records, shape),
+                  bytes_moved(cfg, n_records, shape), peaks)
 
 
 def forest_least_seconds(cfg: dict, n_records: float, peaks: dict):
     """The forest kernel alone → (seconds, binding roof)."""
-    return _least(ops(cfg, n_records), forest_bytes_moved(cfg, n_records),
-                  peaks)
+    shape = forest_shape(cfg)
+    return _least(ops(cfg, n_records, shape),
+                  forest_bytes_moved(cfg, n_records, shape), peaks)

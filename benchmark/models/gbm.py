@@ -14,7 +14,13 @@ configuration's ``model_kind``, with:
   must answer for float32 rows ``X``, with nothing of the program in
   it;
 - ``SCORE_RTOL``, ``SCORE_ATOL``: how far a delivered score may lie
-  from the reference, from the precision the configuration states.
+  from the reference, from the precision the configuration states;
+- ``forest_shape(model) -> (trees, inner nodes, leaves, walk)``: the
+  forest as ``lib/roofline.py`` counts it, nodes and leaves summed over
+  the trees, ``walk`` the comparisons a record makes in one tree. A kind
+  whose trees are not complete (grown leaf-wise, pruned) states its own
+  ``walk`` here and says why: the mean path its records take, since a
+  longer one counts work the algorithm does not do.
 """
 
 from lib import gbm
@@ -39,3 +45,9 @@ def generate(seed: int, model: dict):
 
 write_pmml = gbm.write_pmml
 reference_scores = gbm_ref.scores
+
+
+def forest_shape(model: dict):
+    """Complete trees of ``depth``: every record compares once a level."""
+    t, d = model["n_trees"], model["depth"]
+    return t, t * (2 ** d - 1), t * 2 ** d, d

@@ -29,11 +29,11 @@ for p in (ROOT, BENCH):
         sys.path.insert(0, p)
 
 from lib import byname, prefill  # noqa: E402
+from lib.manifest import manifest_faults  # noqa: E402
 from lib.stream import BLOCK, Stream  # noqa: E402
 from reference import churn_ref  # noqa: E402
 
 CELL = "gbm500_keyed_churn.kafka_saturated"
-SIBLING = "gbm500_keyed.kafka_saturated"
 LOAD_FAULTS = ("least_lead_records.harness", "least_lead_records.producer")
 MIX = {"kind": "latest", "insert_share": 0.05, "zipf_constant": 0.99}
 FULL_DOMAIN = 320000000
@@ -255,34 +255,20 @@ def test_the_deployment_states_its_guarantees():
 def test_the_manifest_takes_the_cell_as_entries_alone():
     with open(os.path.join(ROOT, "BENCHMARK.json")) as fh:
         manifest = json.load(fh)
-    names = [w["name"] for w in manifest["workloads"]]
-    assert names.index(CELL) > names.index(
-        "gbm500_keyed_mesh4.kafka_saturated")
-    cell = manifest["workloads"][names.index(CELL)]
+    # where the cell stands and which lists name it: the manifest's rules
+    assert manifest_faults(manifest) == []
+    cell = next(w for w in manifest["workloads"] if w["name"] == CELL)
     assert (cell["chips"], cell["traffic"]) == (1, "kafka_saturated")
     assert len(cell["why"]) <= 200
     entry = next(c for c in manifest["configs"]
                  if c["name"] == "gbm500_keyed_churn")
     assert len(entry["source"]) <= 200 and len(entry["why"]) <= 200
-    assert set(entry["reduced"]) == set(
-        load_config("gbm500_keyed_churn")["reduced"])
-    # every metric of the one-chip sibling, and four of its own; setup_s
-    # keeps having no list
-    for group in ("end_to_end", "per_layer"):
-        for m in manifest[group]:
-            lists = m.get("workloads")
-            if m["name"].endswith(".churn"):
-                assert lists == [CELL] and m["layer"] == "state"
-                assert m["moves"] == "records_per_s"
-            elif lists is not None:
-                assert (CELL in lists) == (SIBLING in lists), m["name"]
-                assert CELL not in lists or lists[-1] == CELL
-    assert sum(m["name"].endswith(".churn")
-               for m in manifest["per_layer"]) == 4
-    assert sum(CELL in m.get("workloads", ())
-               for m in manifest["per_layer"]) == 20
-    assert "workloads" not in next(
-        m for m in manifest["end_to_end"] if m["name"] == "setup_s")
+    # its own metrics are the state layer's, and each has its reader here
+    own = {m["name"]: m for m in manifest["per_layer"]
+           if m["name"].endswith(".churn")}
+    assert set(CHURN_READERS) <= set(own)
+    for m in own.values():
+        assert (m["layer"], m["moves"]) == ("state", "records_per_s")
 
 
 # -- the readers -------------------------------------------------------------

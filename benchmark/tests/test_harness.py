@@ -1,5 +1,5 @@
-"""Tests of the benchmark's own code. Not tier-1 (the driver runs
-``tests/``; only a benchmark PR may add files, and only here):
+"""Tests of the benchmark's own code. Tier-1 takes them in through
+``tests/test_benchmark_harness.py``; on their own:
 
     JAX_PLATFORMS=cpu python3 -m pytest benchmark/tests -q -p no:cacheprovider
 
@@ -26,7 +26,7 @@ for p in (ROOT, BENCH):
     if p not in sys.path:
         sys.path.insert(0, p)
 
-from lib import byname, loadgen  # noqa: E402
+from lib import byname, loadgen, manifest, roofline  # noqa: E402
 from lib.stream import BLOCK, Stream  # noqa: E402
 
 ZIPF = {"kind": "zipf", "a": 1.1}
@@ -252,6 +252,181 @@ def test_no_deployment_is_known_by_name_in_the_harness():
             text = fh.read()
         for word in ("zipf", "gbm_ref", "gen_arrays", "ZERO_COUNTERS"):
             assert word not in text, f"{rel} names {word}"
+
+
+# -- the manifest's rules -----------------------------------------------
+
+FOURTH = "lgbm255_keyed.kafka_saturated"
+
+
+def read_manifest():
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as fh:
+        return json.load(fh)
+
+
+def with_a_fourth_cell(m):
+    """A one-chip cell of another configuration, added as the manifest's
+    rules ask: a configuration entry, the cell appended to ``workloads``,
+    its name appended to ``records_per_s``'s list and to every per-layer
+    list it would report (those of the one-chip cell that are not its
+    own), and a metric of its own, tagged for its configuration."""
+    m["configs"].append({
+        "name": "lgbm255_keyed", "source": "https://example.org/forest",
+        "file": "benchmark/configs/lgbm255_keyed.json",
+        "reduced": ["chips"], "why": "a made-up forest"})
+    m["workloads"].append({
+        "name": FOURTH, "config": "lgbm255_keyed",
+        "traffic": "kafka_saturated", "chips": 1, "why": "a made-up cell"})
+    for group in ("end_to_end", "per_layer"):
+        for metric in m[group]:
+            if manifest.ONE_CHIP in metric.get("workloads", ()):
+                metric["workloads"].append(FOURTH)
+    m["per_layer"].append({
+        "name": "leaf_walk_us_per_krec.lgbm", "unit": "us", "better": "lower",
+        "source": "program_span", "layer": "kernels",
+        "moves": "records_per_s", "workloads": [FOURTH]})
+    return m
+
+
+def load_with_fourth(entry):
+    if entry["name"] == "lgbm255_keyed":
+        return {"reduced": ["chips"]}
+    return manifest.load_config(entry)
+
+
+@pytest.mark.parametrize("fourth", [False, True])
+def test_the_manifest_keeps_its_rules(fourth):
+    m = read_manifest()
+    if fourth:
+        m = with_a_fourth_cell(m)
+        assert FOURTH in next(x for x in m["end_to_end"]
+                              if x["name"] == "records_per_s")["workloads"]
+    assert manifest.manifest_faults(m, load_with_fourth) == []
+
+
+def _metric(m, name):
+    return next(x for g in ("end_to_end", "per_layer") for x in m[g]
+                if x["name"] == name)
+
+
+def _plant(m, fault):
+    if fault == "no_list":
+        del _metric(m, "drain_wait_frac.sat")["workloads"]
+    elif fault == "no_such_cell":
+        _metric(m, "ingest_us_per_krec.sat")["workloads"].append(
+            "gbm500_keyed.kafka_paced")
+    elif fault == "churn_lists_another":
+        _metric(m, "claim_us_per_krec.churn")["workloads"].insert(
+            0, manifest.ONE_CHIP)
+    elif fault == "churn_lists_only_another":
+        _metric(m, "state_evictions_per_krec.churn")["workloads"] = [
+            manifest.MESH]
+    elif fault == "mesh_lists_another":
+        _metric(m, "hot_chip_share.mesh")["workloads"].append(manifest.CHURN)
+    elif fault == "one_chip_without_churn":
+        _metric(m, "kernel_us_per_krec.sat")["workloads"].remove(
+            manifest.CHURN)
+    elif fault == "setup_s_listed":
+        _metric(m, "setup_s")["workloads"] = list(manifest.ORDER)
+    elif fault == "mesh_first":
+        w = m["workloads"]
+        w[0], w[1] = w[1], w[0]
+    elif fault == "moves_unreported":
+        _metric(m, "hot_chip_share.mesh")["moves"] = "records_per_s"
+    elif fault == "sat_lists_mesh":
+        _metric(m, "route_us_per_krec.sat")["workloads"].append(manifest.MESH)
+    elif fault == "reduced_off":
+        next(c for c in m["configs"]
+             if c["name"] == "gbm500_keyed_churn")["reduced"].pop()
+
+
+@pytest.mark.parametrize("fourth", [False, True])
+@pytest.mark.parametrize("fault,words", [
+    ("no_list", ["per_layer drain_wait_frac.sat", "no workloads list"]),
+    ("no_such_cell", ["per_layer ingest_us_per_krec.sat",
+                      "gbm500_keyed.kafka_paced", "no cell"]),
+    ("churn_lists_another", ["per_layer claim_us_per_krec.churn", ".churn"]),
+    ("churn_lists_only_another", ["per_layer state_evictions_per_krec.churn",
+                                  ".churn", "gbm500_keyed_mesh4"]),
+    ("mesh_lists_another", ["per_layer hot_chip_share.mesh", ".mesh"]),
+    ("one_chip_without_churn", ["per_layer kernel_us_per_krec.sat",
+                                "but not gbm500_keyed_churn"]),
+    ("setup_s_listed", ["end_to_end setup_s", "has a workloads list"]),
+    ("mesh_first", ["workloads", "gbm500_keyed_mesh4.kafka_saturated comes "
+                    "before gbm500_keyed.kafka_saturated"]),
+    ("reduced_off", ["configs gbm500_keyed_churn", "reduced"]),
+    ("moves_unreported", ["per_layer hot_chip_share.mesh",
+                          "moves records_per_s,", "do not report"]),
+    ("sat_lists_mesh", ["per_layer route_us_per_krec.sat",
+                        "moves records_per_s,", "gbm500_keyed_mesh4"]),
+])
+def test_a_planted_manifest_fault_is_named(fault, words, fourth):
+    m = read_manifest()
+    if fourth:
+        m = with_a_fourth_cell(m)
+    _plant(m, fault)
+    faults = manifest.manifest_faults(m, load_with_fourth)
+    assert len(faults) == 1, faults
+    assert all(w in faults[0] for w in words), faults
+
+
+# -- the roofline's count ----------------------------------------------------
+
+def depth_count(cfg, n):
+    """The count as it was written before the model kind stated the
+    forest's shape: complete trees of ``model.depth``, the expressions
+    unchanged, so that equality is to the bit."""
+    m = cfg["model"]
+    ops = n * m["n_trees"] * (m["depth"] + 1)
+    inner, leaves = 2 ** m["depth"] - 1, 2 ** m["depth"]
+    forest = m["n_trees"] * (inner * 2 + leaves * 4) + n * (
+        m["n_features"] + 4)
+    row = 4 * int(cfg["state"]["width_f32"])
+    return ops, forest, forest + n * (3 * row + (4 + 4 + 4 + 1))
+
+
+PEAKS_V5E = {"int8_ops": 393e12, "hbm_bytes_per_s": 819e9}
+
+
+@pytest.mark.parametrize("name", [
+    "gbm500_keyed", "gbm500_keyed_mesh4", "gbm500_keyed_churn"])
+@pytest.mark.parametrize("n", [1, 16384, 65536, 262144, 43690.666666666664,
+                               150000.0 * 26.08 / 100.0])
+def test_the_roofline_counts_the_forest_as_before(name, n):
+    with open(os.path.join(BENCH, "configs", f"{name}.json")) as fh:
+        cfg = json.load(fh)
+    ops, forest, whole = depth_count(cfg, n)
+    assert roofline.ops(cfg, n) == ops
+    assert roofline.forest_bytes_moved(cfg, n) == forest
+    assert roofline.bytes_moved(cfg, n) == whole
+    t_ops = ops / PEAKS_V5E["int8_ops"]
+    for got, n_bytes in ((roofline.least_seconds(cfg, n, PEAKS_V5E), whole),
+                         (roofline.forest_least_seconds(cfg, n, PEAKS_V5E),
+                          forest)):
+        t_mem = n_bytes / PEAKS_V5E["hbm_bytes_per_s"]
+        assert got == ((t_ops, "int8_ops") if t_ops >= t_mem
+                       else (t_mem, "hbm_bytes_per_s"))
+
+
+def test_an_uneven_forest_is_counted_by_its_shape(second_deployment):
+    """Three trees grown leaf-wise: one of depth 2 (3 inner nodes, 4
+    leaves), a chain of depth 3 (3, 4) and a stump (1, 2); its records
+    walk 2.25 comparisons a tree in the mean, as the kind states."""
+    (second_deployment / "models/uneven.py").write_text(
+        "def forest_shape(model):\n    return 3, 7, 10, 2.25\n")
+    cfg = {"model_kind": "uneven", "model": {"n_features": 28},
+           "state": {"width_f32": 8}}
+    assert roofline.forest_shape(cfg) == (3, 7, 10, 2.25)
+    assert roofline.ops(cfg, 1000) == 1000 * 3 * 3.25
+    assert roofline.forest_bytes_moved(cfg, 1000) == 7 * 2 + 10 * 4 + 1000 * 32
+    assert roofline.bytes_moved(cfg, 1000) == 54 + 1000 * (32 + 3 * 32 + 13)
+
+
+def test_the_gbm_kind_states_complete_trees():
+    gbm = byname.load("models", "gbm")
+    assert gbm.forest_shape({"n_trees": 500, "depth": 6}) == (
+        500, 500 * 63, 500 * 64, 6)
+    assert gbm.forest_shape({"n_trees": 1, "depth": 1}) == (1, 1, 2, 1)
 
 
 # -- the comparison that decides ``correct`` ---------------------------
@@ -578,3 +753,55 @@ def test_the_score_thread_reader_books_the_mesh_stages():
     del hist['stage_seconds{stage="shard"}']
     del hist['stage_seconds{stage="unshard"}']
     assert read(ctx) == pytest.approx(55.0)  # one chip books neither
+
+
+# -- the mesh cell's own names for the shared readers -------------------------
+
+def _mesh_twins():
+    m = read_manifest()
+    names = {x["name"] for x in m["per_layer"]}
+    return sorted(x["name"] for x in m["per_layer"]
+                  if x["name"].endswith(".mesh")
+                  and x["name"][:-5] + ".sat" in names)
+
+
+def test_the_mesh_cell_reports_its_own_end_to_end_rate():
+    """The mesh cell reports ``records_per_s.mesh`` and not
+    ``records_per_s``; each of its per-layer metrics moves the former."""
+    m = read_manifest()
+    e2e = {x["name"]: x for x in m["end_to_end"]}
+    assert e2e["records_per_s.mesh"]["workloads"] == [manifest.MESH]
+    assert manifest.MESH not in e2e["records_per_s"]["workloads"]
+    for x in m["per_layer"]:
+        if manifest.MESH in x["workloads"]:
+            assert x["moves"] == "records_per_s.mesh", x["name"]
+    ctx = {"window": (10.0, 12.0), "batches": [(11.0, 4000)],
+           "deliveries": [(11.0, 4000)]}
+    read = byname.load("end_to_end", "records_per_s.mesh").read
+    assert read(ctx) == byname.load("end_to_end", "records_per_s").read(ctx)
+
+
+@pytest.mark.parametrize("name", _mesh_twins())
+def test_a_mesh_twin_reads_what_its_shared_reader_reads(name):
+    stages = ("fetch", "decode", "prefetch_wait", "drain", "drain_wait",
+              "encode", "h2d", "route", "renorm", "shard", "unshard",
+              "queue_wait", "readback", "sink", "commit", "telemetry")
+    hist0 = {f'stage_seconds{{stage="{k}"}}': {"sum": 0.5 * i, "n": i}
+             for i, k in enumerate(stages)}
+    hist1 = {k: {"sum": v["sum"] + 0.01 * (i + 1), "n": v["n"] + 3}
+             for i, (k, v) in enumerate(hist0.items())}
+    counters0 = {"state_records": 100.0, "state_hits": 90.0,
+                 "state_collisions": 5.0, "records_out": 100.0,
+                 "batches": 2.0}
+    counters1 = {"state_records": 4100.0, "state_hits": 4000.0,
+                 "state_collisions": 45.0, "records_out": 4100.0,
+                 "batches": 6.0}
+    ctx = {"window": (10.0, 12.0), "window_s": 2.0,
+           "snap0": {"histograms": hist0, "counters": counters0, "ts": 10.0},
+           "snap1": {"histograms": hist1, "counters": counters1, "ts": 12.0},
+           "batches": [(11.0, 4000)], "deliveries": [(11.0, 4000)],
+           "trace": None, "cfg": {}, "peaks": {}}
+    twin = byname.load("layer_metrics", name).read(ctx)
+    shared = byname.load("layer_metrics", name[:-5] + ".sat").read(ctx)
+    assert twin == shared
+

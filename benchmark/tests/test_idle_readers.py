@@ -111,6 +111,17 @@ def test_every_gap_is_read_not_the_first_two_hundred():
         40.0)
 
 
+def test_a_stretch_in_which_the_thread_never_waited_reads_zero_starved():
+    """The mesh cell's score thread finds its ring full for seconds on
+    end: a traced stretch may hold no ``fjt.drain_wait`` at all, where
+    the program's stage ledger has booked the stage all the same."""
+    tr = trace_of([(0.0, 1.0)], [("fjt.drain", 0.2, 0.5),
+                                 ("fjt.shard", 0.5, 0.6)], devices=2)
+    ctx = ctx_of({"drain_wait": 0.002, "drain": 0.5}, trace=tr)
+    assert read("idle_starved_frac.sat", ctx) == 0.0
+    assert read("idle_assembling_frac.sat", ctx) == pytest.approx(20.0)
+
+
 @pytest.mark.parametrize("name", ["idle_starved_frac.sat",
                                   "idle_assembling_frac.sat"])
 def test_a_program_without_the_wait_span_or_a_trace_reports_nothing(name):
