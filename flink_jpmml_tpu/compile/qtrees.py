@@ -69,6 +69,7 @@ from flink_jpmml_tpu.compile.common import (
 )
 from flink_jpmml_tpu.compile.trees import (
     _canon_has_halt,
+    _canon_leaves,
     _canonicalize_forest,
     pack_ensemble,
 )
@@ -87,6 +88,52 @@ _DEVICE_TABLE_BUDGET = 16 * 1024 * 1024
 _REGRESSION_METHODS = frozenset(
     ("single", "sum", "average", "weightedAverage", "max", "median")
 )
+# the int8 path sum of a leaf ranges over ±depth (``count_i8``): past
+# 127 it would wrap and mis-select leaves
+_MAX_DEPTH = 127
+# a tree's dense path matrix ([S, L], a byte a cell on the wire, four
+# while packing) grows with the square of its leaves: at most what a
+# complete tree of depth 10 makes, 1,023 splits × 1,024 leaves (about a
+# MiB), the most the wire took while the f32 path's dense depth gated it
+_MAX_TREE_PATH = 1023 * 1024
+# the XLA twin keeps its temporaries a dispatch under this share of the
+# device's memory: the rest holds what the deployment keeps there (a
+# keyed state table and the copy its renorm sweep makes)
+_TWIN_MEMORY_SHARE = 16
+
+
+def twin_bytes(rows: int, T: int, S: int, L: int) -> int:
+    """The XLA twin's temporaries for ``rows`` records at once: a
+    record's ±1 signs ``[T, S]`` and path sums ``[T, L]``, a byte each
+    (what XLA's fusions leave of the gather, the compare, the int32
+    contraction and the leaf one-hot), and two path matrices' worth
+    (``[T, S, L]`` a byte a cell) that XLA lays out again. The v5e
+    compile of a 500 × 254 × 255 forest keeps 0.563 GB for 2,048
+    records, 1.088 GB for 4,096 and 4.23 GB for 16,384, against 0.586,
+    1.107 and 4.23 GB here."""
+    return rows * T * (S + L) + 2 * T * S * L
+
+
+def twin_rows(n: int, T: int, S: int, L: int,
+              budget: Optional[int]) -> int:
+    """Records the XLA twin scores in one step of its scan over a batch
+    of ``n``: ``n`` itself where the whole batch's temporaries
+    (``twin_bytes``) fit ``budget`` bytes or no budget is known, else
+    the largest power of two whose temporaries do."""
+    if not budget or twin_bytes(n, T, S, L) <= budget:
+        return n
+    rows = 1
+    while twin_bytes(2 * rows, T, S, L) <= budget:
+        rows *= 2
+    return rows
+
+
+def _device_budget() -> Optional[int]:
+    """A ``_TWIN_MEMORY_SHARE``-th of the first device's memory, or None
+    where the backend does not say how much it has (the CPU)."""
+    stats = jax.devices()[0].memory_stats() or {}
+    limit = stats.get("bytes_limit")
+    return int(limit) // _TWIN_MEMORY_SHARE if limit else None
 
 
 @dataclass(frozen=True)
@@ -342,6 +389,23 @@ class QuantizedScorer:
     # the twins built from this scorer, one a mesh
     mesh: object = None
     _mesh_twins: dict = field(default_factory=dict)
+    # a binary logit chain (``_match_chain``): the program returns the
+    # probability of ``labels[event_class]`` alone, a ``[B]`` vector the
+    # state folds and a sink reads like a regression score; the other
+    # class's probability and the label are made from it at decode
+    event_class: Optional[int] = None
+    # the XLA twin's temporaries a dispatch may take (``twin_rows``);
+    # None where the device does not say how much memory it has
+    twin_budget: Optional[int] = None
+
+    def twin_rows(self, n: int) -> Optional[int]:
+        """Records one step of the XLA twin's scan takes in a batch of
+        ``n`` (``n`` itself: no scan); None on the Pallas kernel."""
+        if self.backend != "xla":
+            return None
+        m = self._meta
+        return twin_rows(n, int(m["trees"]), int(m["splits"]),
+                         int(m["leaves"]), self.twin_budget)
 
     # -- a scorer over a mesh -----------------------------------------------
 
@@ -693,10 +757,20 @@ class QuantizedScorer:
         if not self.is_classification:
             values = np.asarray(out, np.float32)[:n]
             return decode_batch(values.tolist(), [True] * n, None, None)
-        value, probs, lab = out
-        value = np.asarray(value, np.float32)[:n]
-        P = np.asarray(probs, np.float32)[:n]
-        idx = np.asarray(lab)[:n]
+        if self.event_class is not None:
+            # the f32 path's binary logit: [p, 1 - p] in table order,
+            # the label their argmax, the value the label's probability
+            p = np.asarray(out, np.float32)[:n]
+            P = np.empty((p.shape[0], 2), np.float32)
+            P[:, self.event_class] = p
+            P[:, 1 - self.event_class] = np.float32(1.0) - p
+            idx = P.argmax(axis=1)
+            value = P[np.arange(P.shape[0]), idx]
+        else:
+            value, probs, lab = out
+            value = np.asarray(value, np.float32)[:n]
+            P = np.asarray(probs, np.float32)[:n]
+            idx = np.asarray(lab)[:n]
         lbls = [self.labels[i] for i in idx]
         pmaps = [dict(zip(self.labels, row.tolist())) for row in P]
         return decode_batch(value.tolist(), [True] * n, lbls, pmaps)
@@ -709,13 +783,66 @@ def _split_bf16(v: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
     return np.asarray(hi), np.asarray(lo)
 
 
-def _match_ensemble(
+def _match_chain(
     doc: ir.PmmlDocument,
+) -> Optional[Tuple[ir.ModelIR, Tuple[float, float], Tuple[str, ...]]]:
+    """doc → (first segment's model, (a, b), labels) when the model is a
+    binary logit chain as JPMML writes a GBM classifier: a classification
+    ``modelChain`` of two ``<True/>`` segments, the first a regression
+    model with one ``predictedValue`` output field ``m``, the last a
+    classification ``RegressionModel`` with ``normalizationMethod="logit"``
+    whose first table is ``b + a·m`` and whose second has no term. Its
+    result is ``p = 1 / (1 + exp(-(b + a·m)))`` for the first table's
+    category and ``1 - p`` for the second (the f32 path's two-table
+    logit); None for any other document."""
+    model = doc.model
+    if not (
+        isinstance(model, ir.MiningModelIR)
+        and model.function_name == "classification"
+        and model.segmentation is not None
+        and model.segmentation.multiple_model_method == "modelChain"
+    ):
+        return None
+    segs = model.segmentation.segments
+    if len(segs) != 2 or not all(
+        isinstance(s.predicate, ir.TruePredicate) for s in segs
+    ):
+        return None
+    first, last = segs
+    outs, lr = first.output_fields, last.model
+    if (
+        len(outs) != 1
+        or outs[0].feature != "predictedValue"
+        or first.model.function_name != "regression"
+        or not isinstance(lr, ir.RegressionModelIR)
+        or lr.function_name != "classification"
+        or lr.normalization_method != "logit"
+        or len(lr.tables) != 2
+    ):
+        return None
+    event, other = lr.tables
+    terms = event.numeric_predictors
+    if (
+        len(terms) != 1
+        or terms[0].name != outs[0].name
+        or terms[0].exponent != 1.0
+        or event.categorical_predictors
+        or other.numeric_predictors
+        or other.categorical_predictors
+    ):
+        return None
+    labels = tuple(
+        t.target_category or str(i) for i, t in enumerate(lr.tables)
+    )
+    return first.model, (terms[0].coefficient, event.intercept), labels
+
+
+def _match_ensemble(
+    model: ir.ModelIR,
 ) -> Optional[Tuple[List[ir.TreeModelIR], List[float], str]]:
-    """doc → (trees, weights, method) when the model is a tree ensemble the
+    """model → (trees, weights, method) when it is a tree ensemble the
     fast path can take (regression aggregates, or classification single /
     majority votes); None otherwise."""
-    model = doc.model
     if isinstance(model, ir.TreeModelIR):
         return [model], [1.0], "single"
     if not isinstance(model, ir.MiningModelIR):
@@ -756,8 +883,11 @@ def build_quantized_scorer(
 
     Returns None when the model shape is outside the fast path's contract
     (non-regression, non-tree segments, set/equality splits, missing-value
-    strategies that null predictions, or trees too deep for the dense
-    lowering). Raises only on malformed documents.
+    strategies that null predictions, trees deeper than the int8 path
+    sum holds, or trees whose dense path matrix passes a complete depth-10
+    tree's). A binary logit chain over a linear regression ensemble
+    (``_match_chain``) is taken, its link applied in the same program.
+    Raises only on malformed documents.
 
     ``backend``: "auto" picks the Pallas VMEM-resident kernel
     (qtrees_pallas.py) on TPU when eligible (uint8 wire, fixed batch, and
@@ -773,7 +903,11 @@ def build_quantized_scorer(
         # top-level <Output> post-processing happens in CompiledModel
         # .decode; the wire's decode path doesn't carry it
         return None
-    matched = _match_ensemble(doc)
+    chain = _match_chain(doc)
+    if chain is not None and doc.targets:
+        return None
+    link, chain_labels = chain[1:] if chain is not None else (None, ())
+    matched = _match_ensemble(chain[0] if chain is not None else doc.model)
     if matched is None:
         return None
     trees, weights, method = matched
@@ -796,9 +930,26 @@ def build_quantized_scorer(
         canons, classification, depth = _canonicalize_forest(trees, ctx)
     except ModelCompilationException:
         return None
-    # int8 path sums are bounded by ±depth — beyond 127 the int8 acc/count
-    # would wrap and mis-select leaves, so such trees stay on the f32 path
-    if depth > min(config.max_dense_depth, 127):
+    # The rank wire's one depth bound is its int8 path sum's. The packed
+    # path matrix is [T, S, L] at any depth: leaves and splits size it,
+    # not depth. ``max_dense_depth`` is the f32 path's choice between its
+    # dense and iterative lowerings (trees.py), and the leaf-wise trees
+    # GBM libraries grow are deeper than its 10.
+    if depth > _MAX_DEPTH:
+        return None
+    # Leaves and splits bound the size instead, before anything dense is
+    # packed: a tree's path matrix at most ``_MAX_TREE_PATH`` cells, and
+    # where the device says its memory, one record's twin temporaries
+    # within the twin's budget (its scan's step is at least a record)
+    leaves = max(_canon_leaves(c) for c in canons)
+    S, L = max(leaves - 1, 1), max(leaves, 2)
+    twin_budget = _device_budget()
+    if S * L > _MAX_TREE_PATH or (
+        twin_budget and twin_bytes(1, len(canons), S, L) > twin_budget
+    ):
+        return None
+    # a chain's link reads one linear sum of its trees
+    if link is not None and (classification or method in ("max", "median")):
         return None
     if classification and method not in (
         "single", "majorityVote", "weightedMajorityVote"
@@ -904,6 +1055,8 @@ def build_quantized_scorer(
         lab_f = leaf_label.astype(np.float32)
 
     targets = doc.targets
+    out_labels = chain_labels or (packed.labels if classification else ())
+    event_class = 0 if link is not None else None
     repl, has_repl = extract_missing_replacements(doc.model.mining_schema, ctx)
 
     wire = QuantizedWire(
@@ -941,6 +1094,8 @@ def build_quantized_scorer(
         f"{T}:{S}:{L}:{F}:{batch_size}:{np.dtype(dtype).name}:"
         f"{int(classification)}:{method}".encode()
     )
+    if link is not None:
+        hasher.update(f"logit:{link}".encode())
     for c in cuts:
         hasher.update(c.tobytes())
     hasher.update(qthr.tobytes())
@@ -988,27 +1143,45 @@ def build_quantized_scorer(
         <= _packs.member_bytes_cap()
     )
 
+    # the twin's three stages are named scopes inside the state program's
+    # ``fjt.forest`` (metadata on the traced ops; the program is the same
+    # without): the ranks' gather and compare, the path contraction and
+    # leaf match, the leaf values and the chain's link
     def _hit(pp, Xq):
         """[B,T,L] leaf one-hot (f32 on CPU — no int8/bf16 dot kernels
         there — bf16 on TPU)."""
-        xv = Xq[:, pp["feat"]]  # [B, T, S] rank codes
-        miss = xv == sent
-        go = jnp.where(miss, pp["dleft"], xv <= pp["qthr"])
-        if on_cpu:
-            sign = jnp.where(go, 1.0, -1.0).astype(jnp.float32)
+        with jax.named_scope("fjt.forest.ranks"):
+            xv = Xq[:, pp["feat"]]  # [B, T, S] rank codes
+            miss = xv == sent
+            go = jnp.where(miss, pp["dleft"], xv <= pp["qthr"])
+            if on_cpu:
+                sign = jnp.where(go, 1.0, -1.0).astype(jnp.float32)
+            else:
+                sign = jnp.where(go, jnp.int8(1), jnp.int8(-1))
+        with jax.named_scope("fjt.forest.paths"):
+            if on_cpu:
+                acc = jnp.einsum(
+                    "bts,tsl->btl", sign, pp["P_i8"].astype(jnp.float32),
+                    preferred_element_type=jnp.float32,
+                )
+                return (
+                    acc == pp["count_i8"].astype(jnp.float32)[None]
+                ).astype(jnp.float32)
             acc = jnp.einsum(
-                "bts,tsl->btl", sign, pp["P_i8"].astype(jnp.float32),
-                preferred_element_type=jnp.float32,
-            )
-            return (
-                acc == pp["count_i8"].astype(jnp.float32)[None]
-            ).astype(jnp.float32)
-        sign = jnp.where(go, jnp.int8(1), jnp.int8(-1))
-        acc = jnp.einsum(
-            "bts,tsl->btl", sign, pp["P_i8"],
-            preferred_element_type=jnp.int32,
-        ).astype(jnp.int8)
-        return (acc == pp["count_i8"][None]).astype(jnp.bfloat16)
+                "bts,tsl->btl", sign, pp["P_i8"],
+                preferred_element_type=jnp.int32,
+            ).astype(jnp.int8)
+            return (acc == pp["count_i8"][None]).astype(jnp.bfloat16)
+
+    def _link(value):
+        """A chain's logit as the f32 path computes it (regression.py):
+        ``y = b + m·a``, then ``1 / (1 + exp(-y))``; the value itself
+        where there is no chain."""
+        if link is None:
+            return value
+        a, b = link
+        y = jnp.float32(b) + value * jnp.float32(a)
+        return 1.0 / (1.0 + jnp.exp(-y))
 
     def _pair_einsum(spec, hit, hi, lo):
         """hi+lo bf16 split contraction, f32-accurate."""
@@ -1020,55 +1193,80 @@ def build_quantized_scorer(
         ) + jnp.einsum(spec, hit, lo, preferred_element_type=jnp.float32)
 
     if not classification:
-        def qfn(pp, Xq):
+        def score_rows(pp, Xq):
             hit = _hit(pp, Xq)
-            if fused_linear:
+            with jax.named_scope("fjt.forest.leaves"):
+                if fused_linear:
+                    if stable_small:
+                        per = _pair_einsum(
+                            "btl,tl->bt", hit, pp["vhi"], pp["vlo"]
+                        )
+                        value = per.sum(axis=1)
+                    else:
+                        value = _pair_einsum(
+                            "btl,tl->b", hit, pp["vhi"], pp["vlo"]
+                        )
+                else:
+                    per_tree = jnp.einsum(
+                        "btl,tl->bt", hit.astype(jnp.float32),
+                        pp["vals_f32"], precision=jax.lax.Precision.HIGHEST,
+                    )
+                    value = (
+                        jnp.max(per_tree, axis=1)
+                        if method == "max"
+                        else jnp.median(per_tree, axis=1)
+                    )
+                value = _link(apply_targets_value(value, targets))
+                return value.astype(jnp.float32)
+    else:
+        def score_rows(pp, Xq):
+            hit = _hit(pp, Xq)
+            with jax.named_scope("fjt.forest.leaves"):
                 if stable_small:
                     per = _pair_einsum(
-                        "btl,tl->bt", hit, pp["vhi"], pp["vlo"]
+                        "btl,tlc->btc", hit, pp["phi"], pp["plo"]
                     )
-                    value = per.sum(axis=1)
+                    probs = per.sum(axis=1)
                 else:
-                    value = _pair_einsum(
-                        "btl,tl->b", hit, pp["vhi"], pp["vlo"]
+                    probs = _pair_einsum(
+                        "btl,tlc->bc", hit, pp["phi"], pp["plo"]
                     )
-            else:
-                per_tree = jnp.einsum(
-                    "btl,tl->bt", hit.astype(jnp.float32), pp["vals_f32"],
-                    precision=jax.lax.Precision.HIGHEST,
-                )
-                value = (
-                    jnp.max(per_tree, axis=1)
-                    if method == "max"
-                    else jnp.median(per_tree, axis=1)
-                )
-            value = apply_targets_value(value, targets)
-            return value.astype(jnp.float32)
-    else:
-        def qfn(pp, Xq):
-            hit = _hit(pp, Xq)
-            if stable_small:
-                per = _pair_einsum(
-                    "btl,tlc->btc", hit, pp["phi"], pp["plo"]
-                )
-                probs = per.sum(axis=1)
-            else:
-                probs = _pair_einsum(
-                    "btl,tlc->bc", hit, pp["phi"], pp["plo"]
-                )
-            if method == "single":
-                # the label is the leaf's score attribute, not argmax
-                lab = jnp.round(
-                    jnp.einsum(
-                        "btl,tl->b", hit.astype(jnp.float32), pp["lab"],
-                        precision=jax.lax.Precision.HIGHEST,
-                    )
-                ).astype(jnp.int32)
-            else:
-                lab = jnp.argmax(probs, axis=1).astype(jnp.int32)
-            value = jnp.take_along_axis(probs, lab[:, None], axis=1)[:, 0]
-            value = apply_targets_value(value, targets)
-            return value.astype(jnp.float32), probs.astype(jnp.float32), lab
+                if method == "single":
+                    # the label is the leaf's score attribute, not argmax
+                    lab = jnp.round(
+                        jnp.einsum(
+                            "btl,tl->b", hit.astype(jnp.float32), pp["lab"],
+                            precision=jax.lax.Precision.HIGHEST,
+                        )
+                    ).astype(jnp.int32)
+                else:
+                    lab = jnp.argmax(probs, axis=1).astype(jnp.int32)
+                value = jnp.take_along_axis(
+                    probs, lab[:, None], axis=1)[:, 0]
+                value = apply_targets_value(value, targets)
+                return (value.astype(jnp.float32),
+                        probs.astype(jnp.float32), lab)
+
+    def qfn(pp, Xq):
+        """The XLA twin over a batch: ``score_rows`` at once, or a scan
+        of it over steps of ``twin_rows`` records where the batch's
+        temporaries would pass ``twin_budget`` (a pad to whole steps is
+        cut off again). Every record is scored from its own row alone:
+        the scan's scores are the unscanned program's, bit for bit
+        (tests/test_qtrees_chain.py holds it)."""
+        n = Xq.shape[0]
+        rows = twin_rows(n, T, S, L, twin_budget)
+        if rows >= n:
+            return score_rows(pp, Xq)
+        steps = -(-n // rows)
+        Xs = jnp.pad(Xq, ((0, steps * rows - n), (0, 0)))
+        outs = jax.lax.map(
+            lambda x: score_rows(pp, x),
+            Xs.reshape(steps, rows, Xq.shape[1]),
+        )
+        return jax.tree_util.tree_map(
+            lambda o: o.reshape((steps * rows,) + o.shape[2:])[:n], outs
+        )
 
     # Pallas VMEM-resident kernel: uint8 wire + fixed batch, with either a
     # linear regression aggregate (the GBM hot path) or a classification
@@ -1159,9 +1357,9 @@ def build_quantized_scorer(
                     )
             else:
                 def pqfn(gp, Xq):
-                    return apply_targets_value(raw(gp, Xq), targets).astype(
-                        jnp.float32
-                    )
+                    return _link(
+                        apply_targets_value(raw(gp, Xq), targets)
+                    ).astype(jnp.float32)
 
             fused_inner = None
             if encode_stage is not None:
@@ -1197,7 +1395,8 @@ def build_quantized_scorer(
                 n_trees=T,
                 _jit_fn=built["jit_fn"],
                 backend="pallas",
-                labels=packed.labels if classification else (),
+                labels=out_labels,
+                event_class=event_class,
                 model_hash=model_hash,
                 _fused_inner=built["fused_inner"],
                 _encode_stage=encode_stage,
@@ -1277,7 +1476,9 @@ def build_quantized_scorer(
         n_trees=T,
         _jit_fn=jit_fn,
         backend="xla",
-        labels=packed.labels if classification else (),
+        labels=out_labels,
+        event_class=event_class,
+        twin_budget=twin_budget,
         model_hash=model_hash,
         _fused_inner=fused_inner,
         _encode_stage=encode_stage,
@@ -1286,8 +1487,9 @@ def build_quantized_scorer(
         # cross-model packing hook (compile/packs.py): qfn is layout-
         # agnostic (it reads whatever param tables are live), so a
         # pack stays byte-identical across bfs re-adoption; wirepack
-        # members are screened out at pack time (pack_eligible)
-        _pack_info={
+        # members are screened out at pack time (pack_eligible), and a
+        # chain, whose output is one class's probability, is not packed
+        _pack_info=None if link is not None else {
             "qfn": qfn,
             "fields": F,
             "dtype": dtype,

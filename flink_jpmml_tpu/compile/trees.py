@@ -378,6 +378,12 @@ def _canon_depth(canon: _CanonNode) -> int:
     return 1 + max(_canon_depth(canon.left), _canon_depth(canon.right))
 
 
+def _canon_leaves(canon: _CanonNode) -> int:
+    if isinstance(canon, _CanonLeaf):
+        return 1
+    return _canon_leaves(canon.left) + _canon_leaves(canon.right)
+
+
 def _canon_has_halt(canon: _CanonNode) -> bool:
     if isinstance(canon, _CanonLeaf):
         return False
