@@ -103,15 +103,38 @@ _TWIN_MEMORY_SHARE = 16
 
 
 def twin_bytes(rows: int, T: int, S: int, L: int) -> int:
-    """The XLA twin's temporaries for ``rows`` records at once: a
-    record's ±1 signs ``[T, S]`` and path sums ``[T, L]``, a byte each
-    (what XLA's fusions leave of the gather, the compare, the int32
-    contraction and the leaf one-hot), and two path matrices' worth
-    (``[T, S, L]`` a byte a cell) that XLA lays out again. The v5e
-    compile of a 500 × 254 × 255 forest keeps 0.563 GB for 2,048
-    records, 1.088 GB for 4,096 and 4.23 GB for 16,384, against 0.586,
-    1.107 and 4.23 GB here."""
+    """The XLA twin's temporaries for ``rows`` records at once, at
+    most: a record's ±1 signs ``[T, S]`` and path sums ``[T, L]``, a
+    byte each, and two path matrices' worth (``[T, S, L]`` a byte a
+    cell) that XLA lays out again. What the compiler keeps depends on
+    the forest's shape: described-v5e compiles of a 500 × 63 × 64 forest
+    keep 0, 0.261 and 1.045 GB for 2,048, 4,096 and 16,384 records,
+    against 0.134, 0.264 and 1.044 GB here; of a 500 × 254 × 255 forest,
+    whose rank codes and signs live inside the path contraction's
+    fusion, 0 to 0.6 MB at any of them, against 0.586, 1.107 and 4.23
+    GB here."""
     return rows * T * (S + L) + 2 * T * S * L
+
+
+def _rank_codes(Xq: jax.Array, feat: jax.Array, dt) -> jax.Array:
+    """Every split's rank code ``Xq[:, feat]`` (``[B, T, S]``, in the
+    wire's dtype) as a contraction of the records with the one-hot of
+    each split's field in ``dt``: exact, since a code is one product
+    with 1 among products with 0, and ``dt`` holds every code of the
+    wire (bfloat16 to 256, float32 to 2**24). On the chip the compiler
+    builds the codes where the path contraction reads them (for
+    LightGBM's 254 splits a tree, inside that contraction's own fusion:
+    never written to memory), where a gather wrote them records minor
+    and a reshape and a copy moved them again into the contraction's
+    layout. Narrowed to the wire's dtype, what a shape leaves in memory
+    is no more than the gather's (``twin_bytes`` holds for both)."""
+    onehot = jax.nn.one_hot(feat, Xq.shape[1], dtype=dt)
+    return jnp.einsum(
+        "bf,tsf->bts", Xq.astype(dt), onehot,
+        preferred_element_type=jnp.float32,
+        precision=(jax.lax.Precision.HIGHEST if dt == jnp.float32
+                   else None),
+    ).astype(Xq.dtype)
 
 
 def twin_rows(n: int, T: int, S: int, L: int,
@@ -1125,6 +1148,11 @@ def build_quantized_scorer(
 
     on_cpu = common.backend_is_cpu()
     sent = dtype(sentinel)
+    # the dtype the rank codes are built in (``_rank_codes``): bfloat16
+    # holds every uint8 code; the CPU has no bfloat16 dot kernel
+    code_dt = (
+        jnp.bfloat16 if dtype is np.uint8 and not on_cpu else jnp.float32
+    )
 
     # Order-stable reductions for pack-eligible (small) models. XLA's
     # gemv lowering for the final tree-sum contraction is context
@@ -1145,13 +1173,15 @@ def build_quantized_scorer(
 
     # the twin's three stages are named scopes inside the state program's
     # ``fjt.forest`` (metadata on the traced ops; the program is the same
-    # without): the ranks' gather and compare, the path contraction and
-    # leaf match, the leaf values and the chain's link
+    # without): the rank codes and their compare, the path contraction
+    # and leaf match, the leaf values and the chain's link. On the chip
+    # the first two are one fusion, which the trace books under
+    # ``fjt.forest.paths``
     def _hit(pp, Xq):
         """[B,T,L] leaf one-hot (f32 on CPU — no int8/bf16 dot kernels
         there — bf16 on TPU)."""
         with jax.named_scope("fjt.forest.ranks"):
-            xv = Xq[:, pp["feat"]]  # [B, T, S] rank codes
+            xv = _rank_codes(Xq, pp["feat"], code_dt)  # [B, T, S]
             miss = xv == sent
             go = jnp.where(miss, pp["dleft"], xv <= pp["qthr"])
             if on_cpu:

@@ -359,3 +359,55 @@ def test_the_chunked_twin_is_the_unchunked_bit_for_bit(
     assert len(a) == len(b)
     for u, v in zip(a, b):
         assert np.asarray(u).tobytes() == np.asarray(v).tobytes()
+
+
+def _gather_codes(Xq, feat, dt):
+    """The rank codes as a gather, ``Xq[:, feat]``: the twin's form
+    before its codes were a one-hot contraction."""
+    return Xq[:, feat]
+
+
+@pytest.mark.parametrize("scanned", [False, True])
+@pytest.mark.parametrize("doc", ["chain", "vote", "chain255"])
+def test_the_rank_codes_are_the_gathers_bit_for_bit(
+        trees, monkeypatch, doc, scanned):
+    """The twin built twice on the CPU, its rank codes once the one-hot
+    contraction (``qtrees._rank_codes``) and once the gather it
+    replaced, over records with missing values: every output array is
+    equal, bit for bit, the batch at once and scanned in steps of 8
+    records. ``chain255`` has 254 splits a tree, as LightGBM's 255
+    leaves make, a count no tile of the chip divides."""
+    import jax
+
+    if doc == "chain255":
+        t = leafwise_forest(seed=255, n_trees=3, n_leaves=255)
+        parsed = parse_pmml(chain_pmml(t))
+    else:
+        parsed = parse_pmml(chain_pmml(trees) if doc == "chain"
+                            else vote_pmml(trees))
+    n = 100
+    meta = compile_pmml(parsed).quantized_scorer()._meta
+    if doc == "chain255":
+        assert meta["splits"] == 254
+    if scanned:
+        monkeypatch.setattr(qtrees, "_device_budget", lambda: qtrees.twin_bytes(
+            8, int(meta["trees"]), int(meta["splits"]), int(meta["leaves"])))
+
+    def outputs(codes):
+        with monkeypatch.context() as m:
+            m.setattr(qtrees, "_rank_codes", codes)
+            q = compile_pmml(parsed).quantized_scorer()
+            assert q.twin_rows(n) == (8 if scanned else n)
+            F = len(q.wire.fields)
+            Xn = np.random.default_rng(7).normal(
+                0.0, 1.5, size=(n, F)).astype(np.float32)
+            Xn[::7, 1] = np.nan
+            Xq = q.wire.encode(Xn)
+            assert (Xq == q.wire.sentinel).any()
+            return jax.tree_util.tree_leaves(q.predict_wire(Xq))
+
+    a = outputs(qtrees._rank_codes)
+    b = outputs(_gather_codes)
+    assert len(a) == len(b)
+    for u, v in zip(a, b):
+        assert np.asarray(u).tobytes() == np.asarray(v).tobytes()

@@ -10,6 +10,7 @@ TPU compiler, and it does so inside a fixture, never at import."""
 import os
 import re
 
+import numpy as np
 import pytest
 
 
@@ -232,3 +233,73 @@ def test_mesh_renorm_sweeps_every_piece_in_place(mesh_2x2, no_compile_cache):
     for collective in ("all-gather", "all-reduce", "all-to-all",
                        "collective-permute", "reduce-scatter"):
         assert collective not in text, collective
+
+
+def _while_body_results(text):
+    """``(opcode, [element count of each array it writes])`` of every
+    instruction at the top level of the program's ``while`` bodies: an
+    instruction inside a fusion is the fusion's and not listed."""
+    comps, name = {}, None
+    for line in text.splitlines():
+        head = re.match(r"^(?:ENTRY )?(%[\w.\-]+) ", line)
+        if head and line.rstrip().endswith("{"):
+            name = head.group(1)
+            comps[name] = []
+        elif line.startswith("}"):
+            name = None
+        elif name:
+            comps[name].append(line)
+    out = []
+    for body in re.findall(r"\bwhile\(.*?body=(%[\w.\-]+)", text):
+        for line in comps[body]:
+            m = re.match(r"\s*(?:ROOT )?%[\w.\-]+ = (.+?) ([\w\-]+)\(", line)
+            if m:
+                sizes = [
+                    int(np.prod([int(d) for d in dims.split(",")]))
+                    for dims in re.findall(r"\w+\[([\d,]+)\]", m.group(1))
+                ]
+                out.append((m.group(2), sizes))
+    return out
+
+
+
+def test_twin_writes_its_rank_codes_at_most_once_a_step(
+        one_chip, no_compile_cache, monkeypatch):
+    """The XLA rank-wire twin of a forest of 254 splits a tree (a
+    LightGBM tree of 255 leaves; 254 is no multiple of the chip's int8
+    tile), scanned over steps of 2,048 records as a deployment's
+    device budget makes it: the ``[B, T, S]`` rank codes of a step are
+    built where the path contraction reads them. The loop body holds no
+    ``copy`` and no ``reshape`` of that many elements, and at most one
+    fusion writes that many. (A gather that wrote the codes records
+    minor, a reshape that split ``T·S`` and a copy into the contraction's
+    layout made three passes over them.) The scorer is built as on the
+    chip: its CPU branch off, no batch size, so no Pallas kernel."""
+    import jax
+    import jax.numpy as jnp
+
+    from flink_jpmml_tpu.compile import compile_pmml, qtrees
+    from flink_jpmml_tpu.pmml import parse_pmml
+    from test_qtrees_chain import chain_pmml, leafwise_forest
+
+    T, rows, n = 4, 2048, 8192
+    monkeypatch.setattr(qtrees.common, "backend_is_cpu", lambda: False)
+    monkeypatch.setattr(qtrees, "_device_budget", lambda: qtrees.twin_bytes(
+        rows, T, 254, 255))
+    q = compile_pmml(parse_pmml(chain_pmml(
+        leafwise_forest(seed=5, n_trees=T, n_leaves=255)))).quantized_scorer()
+    assert q.backend == "xla" and q._meta["splits"] == 254
+    assert q.twin_rows(n) == rows
+    params = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        q.params)
+    text = q._jit_fn.lower(
+        params, jax.ShapeDtypeStruct((n, len(q.wire.fields)), jnp.uint8,
+                                     sharding=one_chip),
+    ).compile().as_text()
+    body = _while_body_results(text)
+    assert body, "the twin is not scanned"
+    codes = T * 254 * rows
+    writers = [op for op, sizes in body if codes in sizes]
+    assert "copy" not in writers and "reshape" not in writers, writers
+    assert writers.count("fusion") <= 1, writers
