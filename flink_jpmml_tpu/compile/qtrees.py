@@ -53,7 +53,7 @@ from __future__ import annotations
 
 import hashlib
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -415,7 +415,8 @@ class QuantizedScorer:
     # a binary logit chain (``_match_chain``): the program returns the
     # probability of ``labels[event_class]`` alone, a ``[B]`` vector the
     # state folds and a sink reads like a regression score; the other
-    # class's probability and the label are made from it at decode
+    # class's probability and the label are made from it at decode. A
+    # softmax chain returns the classification triple, as a vote does
     event_class: Optional[int] = None
     # the XLA twin's temporaries a dispatch may take (``twin_rows``);
     # None where the device does not say how much memory it has
@@ -806,18 +807,36 @@ def _split_bf16(v: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
     return np.asarray(hi), np.asarray(lo)
 
 
-def _match_chain(
-    doc: ir.PmmlDocument,
-) -> Optional[Tuple[ir.ModelIR, Tuple[float, float], Tuple[str, ...]]]:
-    """doc → (first segment's model, (a, b), labels) when the model is a
-    binary logit chain as JPMML writes a GBM classifier: a classification
-    ``modelChain`` of two ``<True/>`` segments, the first a regression
-    model with one ``predictedValue`` output field ``m``, the last a
-    classification ``RegressionModel`` with ``normalizationMethod="logit"``
-    whose first table is ``b + a·m`` and whose second has no term. Its
-    result is ``p = 1 / (1 + exp(-(b + a·m)))`` for the first table's
-    category and ``1 - p`` for the second (the f32 path's two-table
-    logit); None for any other document."""
+class _Chain(NamedTuple):
+    """A classification chain the rank wire takes (``_match_chain``):
+    ``models[k]`` sums trees into one field ``m_k``, and ``links[k]`` is
+    ``(a_k, b_k)`` of the table ``b_k + a_k·m_k`` over it. ``norm`` is
+    ``"logit"`` (one sum; the event class's table first, the other
+    empty) or ``"softmax"`` (a sum and a table a class)."""
+
+    models: Tuple[ir.ModelIR, ...]
+    links: Tuple[Tuple[float, float], ...]
+    labels: Tuple[str, ...]
+    norm: str
+
+
+def _match_chain(doc: ir.PmmlDocument) -> Optional[_Chain]:
+    """doc → its ``_Chain`` when the model is a classifier chain as JPMML
+    writes a GBM classifier: a classification ``modelChain`` of
+    ``<True/>`` segments, each but the last a regression model with one
+    ``predictedValue`` output field ``m_k``, the last a classification
+    ``RegressionModel`` whose tables are each ``b_k + a_k·m_k`` over the
+    k-th field alone. Two forms:
+
+    - binary logit: one sum and two tables, the second with no term;
+      ``p = 1 / (1 + exp(-(b + a·m)))`` for the first table's category
+      and ``1 - p`` for the second (the f32 path's two-table logit);
+    - softmax: C ≥ 2 sums and C tables, ``p = softmax(b + a∘m)`` over
+      the tables' categories (JPMML-XGBoost's multi-class model).
+
+    None for any other document: another link, a table over another or
+    a second field, a predicate that is not ``<True/>``, or a count of
+    sums or tables that the form does not have."""
     model = doc.model
     if not (
         isinstance(model, ir.MiningModelIR)
@@ -827,37 +846,66 @@ def _match_chain(
     ):
         return None
     segs = model.segmentation.segments
-    if len(segs) != 2 or not all(
+    if len(segs) < 2 or not all(
         isinstance(s.predicate, ir.TruePredicate) for s in segs
     ):
         return None
-    first, last = segs
-    outs, lr = first.output_fields, last.model
-    if (
-        len(outs) != 1
-        or outs[0].feature != "predictedValue"
-        or first.model.function_name != "regression"
-        or not isinstance(lr, ir.RegressionModelIR)
-        or lr.function_name != "classification"
-        or lr.normalization_method != "logit"
-        or len(lr.tables) != 2
+    *sums, last = segs
+    lr = last.model
+    if not (
+        isinstance(lr, ir.RegressionModelIR)
+        and lr.function_name == "classification"
     ):
         return None
-    event, other = lr.tables
-    terms = event.numeric_predictors
-    if (
-        len(terms) != 1
-        or terms[0].name != outs[0].name
-        or terms[0].exponent != 1.0
-        or event.categorical_predictors
-        or other.numeric_predictors
-        or other.categorical_predictors
+    fields = []
+    for s in sums:
+        outs = s.output_fields
+        if (
+            len(outs) != 1
+            or outs[0].feature != "predictedValue"
+            or s.model.function_name != "regression"
+        ):
+            return None
+        fields.append(outs[0].name)
+    norm = lr.normalization_method
+    if norm == "logit" and len(sums) == 1 and len(lr.tables) == 2:
+        tables, rest = lr.tables[:1], lr.tables[1:]
+    elif norm == "softmax" and len(sums) >= 2 and len(lr.tables) == len(sums):
+        tables, rest = lr.tables, ()
+    else:
+        return None
+    if len(set(fields)) != len(fields) or any(
+        t.numeric_predictors or t.categorical_predictors for t in rest
     ):
         return None
+    links = []
+    for table, name in zip(tables, fields):
+        terms = table.numeric_predictors
+        if (
+            len(terms) != 1
+            or terms[0].name != name
+            or terms[0].exponent != 1.0
+            or table.categorical_predictors
+        ):
+            return None
+        links.append((terms[0].coefficient, table.intercept))
     labels = tuple(
         t.target_category or str(i) for i, t in enumerate(lr.tables)
     )
-    return first.model, (terms[0].coefficient, event.intercept), labels
+    return _Chain(tuple(s.model for s in sums), tuple(links), labels, norm)
+
+
+def _linear_coef(method: str, weights: Sequence[float]) -> Optional[np.ndarray]:
+    """Each tree's coefficient in a linear aggregate of its ensemble
+    (folded into its leaf values); None for ``max`` / ``median``."""
+    w = np.asarray(weights, np.float32)
+    if method in ("single", "sum"):
+        return np.ones(w.shape, np.float32)
+    if method == "average":
+        return np.full(w.shape, 1.0 / w.size, np.float32)
+    if method == "weightedAverage":
+        return (w / w.sum()).astype(np.float32)
+    return None
 
 
 def _match_ensemble(
@@ -908,8 +956,9 @@ def build_quantized_scorer(
     (non-regression, non-tree segments, set/equality splits, missing-value
     strategies that null predictions, trees deeper than the int8 path
     sum holds, or trees whose dense path matrix passes a complete depth-10
-    tree's). A binary logit chain over a linear regression ensemble
-    (``_match_chain``) is taken, its link applied in the same program.
+    tree's). A classifier chain over linear regression ensembles
+    (``_match_chain``: a binary logit, or a softmax over equal groups of
+    trees, one a class) is taken, its link applied in the same program.
     Raises only on malformed documents.
 
     ``backend``: "auto" picks the Pallas VMEM-resident kernel
@@ -929,11 +978,27 @@ def build_quantized_scorer(
     chain = _match_chain(doc)
     if chain is not None and doc.targets:
         return None
-    link, chain_labels = chain[1:] if chain is not None else (None, ())
-    matched = _match_ensemble(chain[0] if chain is not None else doc.model)
-    if matched is None:
+    # a binary chain's (a, b); a softmax chain's [C, 2] table of them
+    link = softmax = None
+    chain_labels: Tuple[str, ...] = ()
+    if chain is not None:
+        chain_labels = chain.labels
+        if chain.norm == "logit":
+            link = chain.links[0]
+        else:
+            softmax = np.asarray(chain.links, np.float32)
+    parts = [
+        _match_ensemble(m)
+        for m in (chain.models if chain is not None else (doc.model,))
+    ]
+    if any(m is None for m in parts):
         return None
-    trees, weights, method = matched
+    if len({len(m[0]) for m in parts}) != 1:
+        # a softmax chain's classes are equal groups of trees, in order
+        return None
+    trees = [t for m in parts for t in m[0]]
+    weights = [w for m in parts for w in m[1]]
+    method = parts[0][2]
 
     fields = doc.active_fields
     ctx = LowerCtx(
@@ -971,8 +1036,10 @@ def build_quantized_scorer(
         twin_budget and twin_bytes(1, len(canons), S, L) > twin_budget
     ):
         return None
-    # a chain's link reads one linear sum of its trees
-    if link is not None and (classification or method in ("max", "median")):
+    # a chain's link reads linear sums of its trees
+    if chain is not None and (classification or any(
+        _linear_coef(m, w) is None for _, w, m in parts
+    )):
         return None
     if classification and method not in (
         "single", "majorityVote", "weightedMajorityVote"
@@ -1045,14 +1112,13 @@ def build_quantized_scorer(
     fused_linear = False
     if not classification:
         vals = p["leaf_values"].astype(np.float32)  # [T, L]
-        if method in ("single", "sum"):
-            fused_linear, coef = True, np.ones((T,), np.float32)
-        elif method == "average":
-            fused_linear, coef = True, np.full((T,), 1.0 / T, np.float32)
-        elif method == "weightedAverage":
-            fused_linear, coef = True, (w / w.sum()).astype(np.float32)
-        else:  # max / median need the per-tree plane
-            fused_linear, coef = False, np.ones((T,), np.float32)
+        coefs = [_linear_coef(m, wt) for _, wt, m in parts]
+        # max / median need the per-tree plane
+        fused_linear = all(c is not None for c in coefs)
+        coef = (
+            np.concatenate(coefs) if fused_linear
+            else np.ones((T,), np.float32)
+        )
         vhi, vlo = _split_bf16(vals * coef[:, None])
     else:
         labels = packed.labels
@@ -1119,6 +1185,8 @@ def build_quantized_scorer(
     )
     if link is not None:
         hasher.update(f"logit:{link}".encode())
+    if softmax is not None:
+        hasher.update(b"softmax:" + softmax.tobytes())
     for c in cuts:
         hasher.update(c.tobytes())
     hasher.update(qthr.tobytes())
@@ -1222,7 +1290,40 @@ def build_quantized_scorer(
             spec, hit, hi, preferred_element_type=jnp.float32
         ) + jnp.einsum(spec, hit, lo, preferred_element_type=jnp.float32)
 
-    if not classification:
+    def _softmax_link(m):
+        """A softmax chain's link as the f32 path computes it
+        (regression.py): ``y_k = b_k + m_k·a_k``, the softmax with the
+        largest ``y`` subtracted, the label its argmax → the triple
+        (the label's probability, ``[B, C]`` probabilities, label)."""
+        with jax.named_scope("fjt.forest.link"):
+            y = jnp.asarray(softmax[:, 1]) + m * jnp.asarray(softmax[:, 0])
+            e = jnp.exp(y - jnp.max(y, axis=1, keepdims=True))
+            probs = e / jnp.sum(e, axis=1, keepdims=True)
+            lab = jnp.argmax(probs, axis=1).astype(jnp.int32)
+            value = jnp.take_along_axis(probs, lab[:, None], axis=1)[:, 0]
+            return value, probs, lab
+
+    if softmax is not None:
+        C = softmax.shape[0]
+
+        def score_rows(pp, Xq):
+            """Class k's sum over its own group of trees (the k-th
+            ``T / C`` of the forest): no leaf is read for another
+            class, then the link."""
+            hit = _hit(pp, Xq)
+            with jax.named_scope("fjt.forest.leaves"):
+                B = hit.shape[0]
+                if stable_small:
+                    per = _pair_einsum("btl,tl->bt", hit, pp["vhi"], pp["vlo"])
+                    m = per.reshape(B, C, -1).sum(axis=2)
+                else:
+                    m = _pair_einsum(
+                        "bktl,ktl->bk", hit.reshape(B, C, -1, L),
+                        pp["vhi"].reshape(C, -1, L),
+                        pp["vlo"].reshape(C, -1, L),
+                    )
+            return _softmax_link(m)
+    elif not classification:
         def score_rows(pp, Xq):
             hit = _hit(pp, Xq)
             with jax.named_scope("fjt.forest.leaves"):
@@ -1322,7 +1423,15 @@ def build_quantized_scorer(
     ):
         from flink_jpmml_tpu.compile import qtrees_pallas
 
-        if classification:
+        if softmax is not None:
+            # the vote kernel's [T, L, C] table: each tree's leaves in
+            # its class's column, the twin's bf16 pair, zeros elsewhere
+            tree_class = np.repeat(np.arange(C), T // C)
+            vals_tbl = np.zeros((T, L, C), vhi.dtype)
+            vals_lo = np.zeros((T, L, C), vlo.dtype)
+            vals_tbl[np.arange(T), :, tree_class] = vhi
+            vals_lo[np.arange(T), :, tree_class] = vlo
+        elif classification:
             # the bf16 hi/lo split pair — identical operands to the XLA
             # path, so labels match exactly and shares to f32 rounding
             vals_tbl, vals_lo = phi, plo
@@ -1372,7 +1481,10 @@ def build_quantized_scorer(
             )
             if raw is None:
                 return None
-            if classification:
+            if softmax is not None:
+                def pqfn(gp, Xq):
+                    return _softmax_link(raw(gp, Xq))  # [B, C] sums
+            elif classification:
                 def pqfn(gp, Xq):
                     probs = raw(gp, Xq)  # [B, C] vote shares
                     lab = jnp.argmax(probs, axis=1).astype(jnp.int32)
@@ -1518,8 +1630,8 @@ def build_quantized_scorer(
         # agnostic (it reads whatever param tables are live), so a
         # pack stays byte-identical across bfs re-adoption; wirepack
         # members are screened out at pack time (pack_eligible), and a
-        # chain, whose output is one class's probability, is not packed
-        _pack_info=None if link is not None else {
+        # chain, whose output is not its trees' sum, is not packed
+        _pack_info=None if chain is not None else {
             "qfn": qfn,
             "fields": F,
             "dtype": dtype,

@@ -241,10 +241,11 @@ def _state_step(S, score, slots, rel, w, reset, scratch, decay):
 def _score_of(out):
     """The scalar signal the state accumulates: the f32 value stream
     (classification outputs carry it as the triple's first element, the
-    predicted label's probability). A binary logit chain on the rank
-    wire returns one ``[B]`` vector instead, the probability of
-    ``q.labels[q.event_class]`` whatever the label: that is what it
-    folds and what its raw output carries."""
+    predicted label's probability: a vote forest's and a softmax
+    chain's). A binary logit chain on the rank wire returns one ``[B]``
+    vector instead, the probability of ``q.labels[q.event_class]``
+    whatever the label: that is what it folds and what its raw output
+    carries."""
     return out[0] if isinstance(out, tuple) else out
 
 

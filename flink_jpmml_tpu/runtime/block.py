@@ -1585,6 +1585,10 @@ class BlockPipelineBase:
     def _score(self) -> None:
         batch_cfg = self._config.batch
         records_out = self.metrics.counter("records_out")
+        # what each completed dispatch brings back for the sink: the
+        # scorer's whole output (a classifier's triple, a regression's
+        # vector), not the state's derived rows
+        readback_bytes = self.metrics.counter("readback_bytes")
         batches = self.metrics.counter("batches")
         fill = self.metrics.counter("batch_fill_records")
         # fixed-bucket histogram, not a reservoir: N workers' bucket
@@ -1646,6 +1650,10 @@ class BlockPipelineBase:
                 # plane under the model's "#state" label (state
                 # corruption surfaces as feature drift)
                 out, derived = state_mod.split_output(out)
+            readback_bytes.inc(sum(
+                int(getattr(a, "nbytes", 0))
+                for a in (out if isinstance(out, tuple) else (out,))
+            ))
             plan = meta[8] if len(meta) > 8 else None
             if plan is not None:
                 # a mesh dispatch comes back sorted by owning chip:

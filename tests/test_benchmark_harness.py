@@ -14,3 +14,4 @@ from test_mesh_cell import *  # noqa: E402,F401,F403
 from test_churn_cell import *  # noqa: E402,F401,F403
 from test_idle_readers import *  # noqa: E402,F401,F403
 from test_lgbm_cell import *  # noqa: E402,F401,F403
+from test_covtype_cell import *  # noqa: E402,F401,F403
