@@ -25,6 +25,10 @@ This module builds that wire format:
   which cuts HBM traffic ~4x; leaf values contract in a bf16 hi+lo split
   (exact to ~2^-17 relative) so the MXU stays in fast dtypes without
   giving up float32-level accuracy.
+- **The twin's complete form.** A forest of shallow trees
+  (``complete_depth``) skips the path contraction: every tree is laid
+  out as a complete heap and its leaf picked by a select tree over its
+  split compares, the leaf values the same hi+lo pair summed in float32.
 
 - **Kernel layouts (round 11).** The packed tables exist in catalogue
   variants (compile/layouts.py): breadth-first SoA split ordering,
@@ -100,19 +104,58 @@ _MAX_TREE_PATH = 1023 * 1024
 # device's memory: the rest holds what the deployment keeps there (a
 # keyed state table and the copy its renorm sweep makes)
 _TWIN_MEMORY_SHARE = 16
+# the complete form's select tree is unrolled at trace time, a select a
+# heap node (``_select_tree``): past depth 8, 255 selects a tree, the
+# traced program grows with the heap, and such trees keep the path form
+_MAX_HEAP_DEPTH = 8
 
 
-def twin_bytes(rows: int, T: int, S: int, L: int) -> int:
+def complete_depth(depth: int, S: int, L: int) -> int:
+    """The depth of the twin's select tree for a forest whose deepest
+    tree is ``depth`` deep and whose packed trees hold ``S`` splits and
+    ``L`` leaves, or 0 where the twin contracts its path matrix.
+
+    The complete form lays every tree out as a complete heap of
+    ``depth`` levels and picks its leaf by ``2**depth - 1`` selects; the
+    path form contracts every sign against every leaf, ``S · L``
+    multiply-adds a tree. The heap is taken where its padded selects and
+    leaves, ``2**(depth + 1)``, are no more than the path matrix's
+    cells: a complete depth-6 tree (128 against 63 × 64) takes it, a
+    leaf-wise tree of depth 16 or more (2**17 against 254 × 255 at
+    most) and a sparse deep one (a 20-leaf tree of depth 10: 2,048
+    against 380) keep the path matrix, as does a tree past
+    ``_MAX_HEAP_DEPTH``. Read from the forest's shape alone."""
+    if depth > _MAX_HEAP_DEPTH:
+        return 0
+    return depth if 2 ** (depth + 1) <= S * L else 0
+
+
+def twin_bytes(rows: int, T: int, S: int, L: int, depth: int = 0) -> int:
     """The XLA twin's temporaries for ``rows`` records at once, at
-    most: a record's ±1 signs ``[T, S]`` and path sums ``[T, L]``, a
-    byte each, and two path matrices' worth (``[T, S, L]`` a byte a
-    cell) that XLA lays out again. What the compiler keeps depends on
-    the forest's shape: described-v5e compiles of a 500 × 63 × 64 forest
-    keep 0, 0.261 and 1.045 GB for 2,048, 4,096 and 16,384 records,
-    against 0.134, 0.264 and 1.044 GB here; of a 500 × 254 × 255 forest,
-    whose rank codes and signs live inside the path contraction's
-    fusion, 0 to 0.6 MB at any of them, against 0.586, 1.107 and 4.23
-    GB here."""
+    most, in the form it runs (``depth``: ``complete_depth``).
+
+    The path form (``depth`` 0): a record's ±1 signs ``[T, S]`` and path
+    sums ``[T, L]``, a byte each, and two path matrices' worth
+    (``[T, S, L]`` a byte a cell) that XLA lays out again. What the
+    compiler keeps depends on the forest's shape: described-v5e compiles
+    of a 500 × 63 × 64 forest keep 0, 0.261 and 1.045 GB for 2,048, 4,096
+    and 16,384 records, against 0.134, 0.264 and 1.044 GB here; of a
+    500 × 254 × 255 forest, whose rank codes and signs live inside the
+    path contraction's fusion, 0 to 0.6 MB at any of them, against
+    0.586, 1.107 and 4.23 GB here.
+
+    The complete form: a record's compares ``[2**depth - 1, T]``, a byte
+    each, and its per-tree values, four, beside the heap's tables (split
+    fields, cuts and default directions, 6 bytes a split; leaf values, 4
+    a leaf). Described-v5e compiles of the covtype forest (21,000 trees
+    of depth 6) keep 0.246, 0.599 and 1.307 GB for 256, 512 and 1,024
+    records, against 0.374, 0.734 and 1.454 GB here: under a sixteenth
+    of a v5e's memory that is steps of 512 records, where the path form
+    took 256; a LightGBM forest of 500 × 254 × 255 keeps the path form
+    and its 2,048."""
+    if depth:
+        n = 2 ** depth - 1
+        return rows * T * (n + 4) + T * (6 * n + 4 * (n + 1))
     return rows * T * (S + L) + 2 * T * S * L
 
 
@@ -137,16 +180,102 @@ def _rank_codes(Xq: jax.Array, feat: jax.Array, dt) -> jax.Array:
     ).astype(Xq.dtype)
 
 
+def _rank_codes_i8(Xq: jax.Array, feat: jax.Array) -> jax.Array:
+    """The rank codes ``Xq[:, feat]`` (``[B, T]``) of a uint8 wire on the
+    chip's int8 units: each code moved into int8 (``x - 128``),
+    contracted with the int8 one-hot of each split's field in int32, and
+    moved back. Exact, as ``_rank_codes``; the complete form builds a
+    plane of them a heap split, where the int8 contraction read 365 ms a
+    65,536-record dispatch of the covtype forest against 404 in
+    bfloat16 (TPU v5 lite, steps of 512 records)."""
+    onehot = jax.nn.one_hot(feat, Xq.shape[1], dtype=jnp.int8)
+    shifted = (Xq.astype(jnp.int32) - 128).astype(jnp.int8)
+    return (jnp.einsum("bf,tf->bt", shifted, onehot,
+                       preferred_element_type=jnp.int32)
+            + 128).astype(Xq.dtype)
+
+
+def complete_heap(P: np.ndarray, depth: int):
+    """Every tree of a packed forest laid out as a complete heap of
+    ``depth`` levels → ``(node, owner)``.
+
+    ``P`` is the packed path matrix ``[T, S, L]`` in the wire's normal
+    form (+1: the leaf lies where the split's ``go`` holds, -1: where it
+    fails, 0: off the leaf's path), its splits in the preorder
+    ``pack_ensemble`` gives them, so a split's level on a leaf's path is
+    the count of that path's splits up to it. The heap is level-major;
+    within a level a node is numbered by the turns on its path, the
+    root's the lowest bit (1 where ``go`` fails). The children of node
+    ``k`` of level ``d`` are then ``k`` and ``k + 2**d`` of level
+    ``d + 1``: a level's selects read the two halves of the level below.
+
+    ``node[t, s]`` is split ``s``'s index in its heap, ``2**d - 1 + k``
+    (-1 for a padded slot); ``owner[t, j]`` is the packed leaf that heap
+    leaf ``j`` carries. A leaf above ``depth`` carries every heap leaf
+    of the subtree below it, whatever the padded splits there decide,
+    so each record reaches the leaf its path matrix matches. Vectorised
+    over chunks of 2,048 trees, a chunk's int32 planes tens of MB."""
+    chunk = 2048
+    T, S, L = P.shape
+    node = np.full((T, S), -1, np.int32)
+    owner = np.full((T, 1 << depth), -1, np.int32)
+    for t0 in range(0, T, chunk):
+        Pc = P[t0:t0 + chunk]
+        on = Pc != 0
+        pos = np.cumsum(on, axis=1, dtype=np.int32) - 1  # level on a path
+        code = np.where(Pc < 0, np.left_shift(1, np.maximum(pos, 0)),
+                        0).sum(axis=1, dtype=np.int32)  # [t, L] turns
+        first = on.argmax(axis=2)  # [t, S]: a leaf below each split
+        lvl = np.maximum(np.take_along_axis(
+            pos, first[:, :, None], axis=2)[..., 0], 0)
+        turns = np.take_along_axis(code, first, axis=1) & ((1 << lvl) - 1)
+        node[t0:t0 + chunk] = np.where(
+            on.any(axis=2), (1 << lvl) - 1 + turns, -1)
+        cnt = on.sum(axis=1)  # [t, L]: a leaf's depth, 0 for a pad
+        for c in range(1, depth + 1):
+            tt, ll = np.nonzero(cnt == c)
+            jj = code[tt, ll][:, None] + (
+                np.arange(1 << (depth - c)) << c)[None, :]
+            owner[t0 + tt[:, None], jj] = ll[:, None]
+    return node, owner
+
+
+def _select_tree(go: Sequence[jax.Array], table: jax.Array) -> jax.Array:
+    """The complete form's walk: ``go``, one ``[B, T]`` plane a heap
+    split, level-major (``complete_heap``), and ``table``
+    ``[2**D, *P, T]``, each heap leaf's payload → ``[B, *P, T]``, every
+    tree's payload at the leaf its record reaches. From the deepest
+    level up, node ``k`` of level ``d`` is ``where(go, v[k], v[k +
+    2**d])``: ``2**D - 1`` selects a tree, each on whole planes of the
+    result's shape, unrolled node by node so that the compiler keeps the
+    walk in one fusion (selects over whole levels, ``[B, 2**d, T]``
+    each, were a fusion a level on a described v5e, each writing its
+    level out)."""
+    lead = (1,) * (table.ndim - 2)
+    D = table.shape[0].bit_length() - 1
+    level = [table[j] for j in range(1 << D)]
+    for d in reversed(range(D)):
+        w = 1 << d
+        level = [
+            jnp.where(go[w - 1 + k].reshape(go[w - 1 + k].shape[:1] + lead
+                                             + go[w - 1 + k].shape[1:]),
+                      level[k], level[k + w])
+            for k in range(w)
+        ]
+    return level[0]
+
+
 def twin_rows(n: int, T: int, S: int, L: int,
-              budget: Optional[int]) -> int:
+              budget: Optional[int], depth: int = 0) -> int:
     """Records the XLA twin scores in one step of its scan over a batch
     of ``n``: ``n`` itself where the whole batch's temporaries
-    (``twin_bytes``) fit ``budget`` bytes or no budget is known, else
-    the largest power of two whose temporaries do."""
-    if not budget or twin_bytes(n, T, S, L) <= budget:
+    (``twin_bytes`` of the form that runs) fit ``budget`` bytes or no
+    budget is known, else the largest power of two whose temporaries
+    do."""
+    if not budget or twin_bytes(n, T, S, L, depth) <= budget:
         return n
     rows = 1
-    while twin_bytes(2 * rows, T, S, L) <= budget:
+    while twin_bytes(2 * rows, T, S, L, depth) <= budget:
         rows *= 2
     return rows
 
@@ -421,6 +550,9 @@ class QuantizedScorer:
     # the XLA twin's temporaries a dispatch may take (``twin_rows``);
     # None where the device does not say how much memory it has
     twin_budget: Optional[int] = None
+    # the depth of the XLA twin's select tree (``complete_depth``); 0
+    # where it contracts its path matrix, and on the Pallas kernel
+    twin_depth: int = 0
 
     def twin_rows(self, n: int) -> Optional[int]:
         """Records one step of the XLA twin's scan takes in a batch of
@@ -429,7 +561,8 @@ class QuantizedScorer:
             return None
         m = self._meta
         return twin_rows(n, int(m["trees"]), int(m["splits"]),
-                         int(m["leaves"]), self.twin_budget)
+                         int(m["leaves"]), self.twin_budget,
+                         self.twin_depth)
 
     # -- a scorer over a mesh -----------------------------------------------
 
@@ -1028,12 +1161,15 @@ def build_quantized_scorer(
     # Leaves and splits bound the size instead, before anything dense is
     # packed: a tree's path matrix at most ``_MAX_TREE_PATH`` cells, and
     # where the device says its memory, one record's twin temporaries
-    # within the twin's budget (its scan's step is at least a record)
+    # within the twin's budget (its scan's step is at least a record), in
+    # the form the twin takes for this shape (``complete_depth``)
     leaves = max(_canon_leaves(c) for c in canons)
     S, L = max(leaves - 1, 1), max(leaves, 2)
+    heap_depth = complete_depth(depth, S, L)
     twin_budget = _device_budget()
     if S * L > _MAX_TREE_PATH or (
-        twin_budget and twin_bytes(1, len(canons), S, L) > twin_budget
+        twin_budget
+        and twin_bytes(1, len(canons), S, L, heap_depth) > twin_budget
     ):
         return None
     # a chain's link reads linear sums of its trees
@@ -1239,11 +1375,13 @@ def build_quantized_scorer(
         <= _packs.member_bytes_cap()
     )
 
-    # the twin's three stages are named scopes inside the state program's
+    # the twin's stages are named scopes inside the state program's
     # ``fjt.forest`` (metadata on the traced ops; the program is the same
-    # without): the rank codes and their compare, the path contraction
-    # and leaf match, the leaf values and the chain's link. On the chip
-    # the first two are one fusion, which the trace books under
+    # without): the rank codes and their compare (``ranks``), the pick of
+    # each tree's leaf (``paths``: the path contraction and leaf match,
+    # or the select tree of the complete form), the leaf values and class
+    # sums (``leaves``) and a softmax chain's link. On the chip the path
+    # form's first two are one fusion, which the trace books under
     # ``fjt.forest.paths``
     def _hit(pp, Xq):
         """[B,T,L] leaf one-hot (f32 on CPU — no int8/bf16 dot kernels
@@ -1270,6 +1408,27 @@ def build_quantized_scorer(
                 preferred_element_type=jnp.int32,
             ).astype(jnp.int8)
             return (acc == pp["count_i8"][None]).astype(jnp.bfloat16)
+
+    def _walk(pp, Xq):
+        """The complete form: ``[B, *P, T]``, every tree's leaf payload
+        (``heap_vals``: ``[2**D, *P, T]``, float32 on every backend), by
+        the rank codes of each heap split, a ``[B, T]`` plane a split
+        with the trees minor (one contraction a split: one of all the
+        splits at once, ``[B, 2**D - 1, T]``, was copied out again plane
+        by plane on a described v5e), their compare, and the select
+        tree."""
+        with jax.named_scope("fjt.forest.ranks"):
+            go = []
+            for n in range(pp["heap_feat"].shape[0]):
+                if code_dt == jnp.bfloat16:
+                    xv = _rank_codes_i8(Xq, pp["heap_feat"][n])  # [B, T]
+                else:
+                    xv = _rank_codes(
+                        Xq, pp["heap_feat"][n:n + 1], code_dt)[:, 0]
+                go.append(jnp.where(xv == sent, pp["heap_dleft"][n],
+                                    xv <= pp["heap_qthr"][n]))
+        with jax.named_scope("fjt.forest.paths"):
+            return _select_tree(go, pp["heap_vals"])
 
     def _link(value):
         """A chain's logit as the f32 path computes it (regression.py):
@@ -1303,6 +1462,20 @@ def build_quantized_scorer(
             value = jnp.take_along_axis(probs, lab[:, None], axis=1)[:, 0]
             return value, probs, lab
 
+    def _regression_value(value):
+        value = _link(apply_targets_value(value, targets))
+        return value.astype(jnp.float32)
+
+    def _vote_triple(probs, lab):
+        value = jnp.take_along_axis(
+            probs, lab[:, None], axis=1)[:, 0]
+        value = apply_targets_value(value, targets)
+        return (value.astype(jnp.float32),
+                probs.astype(jnp.float32), lab)
+
+    # the complete form sums each tree's own value with a plain axis
+    # reduce of the per-tree plane: the float order ``stable_small``
+    # pins for the path form, at every size
     if softmax is not None:
         C = softmax.shape[0]
 
@@ -1310,6 +1483,11 @@ def build_quantized_scorer(
             """Class k's sum over its own group of trees (the k-th
             ``T / C`` of the forest): no leaf is read for another
             class, then the link."""
+            if heap_depth:
+                per = _walk(pp, Xq)
+                with jax.named_scope("fjt.forest.leaves"):
+                    m = per.reshape(per.shape[0], C, -1).sum(axis=2)
+                return _softmax_link(m)
             hit = _hit(pp, Xq)
             with jax.named_scope("fjt.forest.leaves"):
                 B = hit.shape[0]
@@ -1324,7 +1502,17 @@ def build_quantized_scorer(
                     )
             return _softmax_link(m)
     elif not classification:
+        def _aggregate(per_tree):
+            if method == "max":
+                return jnp.max(per_tree, axis=1)
+            return jnp.median(per_tree, axis=1)
+
         def score_rows(pp, Xq):
+            if heap_depth:
+                per = _walk(pp, Xq)
+                with jax.named_scope("fjt.forest.leaves"):
+                    return _regression_value(
+                        per.sum(axis=1) if fused_linear else _aggregate(per))
             hit = _hit(pp, Xq)
             with jax.named_scope("fjt.forest.leaves"):
                 if fused_linear:
@@ -1342,15 +1530,21 @@ def build_quantized_scorer(
                         "btl,tl->bt", hit.astype(jnp.float32),
                         pp["vals_f32"], precision=jax.lax.Precision.HIGHEST,
                     )
-                    value = (
-                        jnp.max(per_tree, axis=1)
-                        if method == "max"
-                        else jnp.median(per_tree, axis=1)
-                    )
-                value = _link(apply_targets_value(value, targets))
-                return value.astype(jnp.float32)
+                    value = _aggregate(per_tree)
+                return _regression_value(value)
     else:
         def score_rows(pp, Xq):
+            if heap_depth:
+                # each leaf's class shares, then (a single tree) its label
+                per = _walk(pp, Xq)  # [B, C (+1), T]
+                with jax.named_scope("fjt.forest.leaves"):
+                    probs = per[:, :C].sum(axis=2)
+                    lab = (
+                        jnp.round(per[:, C, 0]).astype(jnp.int32)
+                        if method == "single"
+                        else jnp.argmax(probs, axis=1).astype(jnp.int32)
+                    )
+                    return _vote_triple(probs, lab)
             hit = _hit(pp, Xq)
             with jax.named_scope("fjt.forest.leaves"):
                 if stable_small:
@@ -1372,11 +1566,7 @@ def build_quantized_scorer(
                     ).astype(jnp.int32)
                 else:
                     lab = jnp.argmax(probs, axis=1).astype(jnp.int32)
-                value = jnp.take_along_axis(
-                    probs, lab[:, None], axis=1)[:, 0]
-                value = apply_targets_value(value, targets)
-                return (value.astype(jnp.float32),
-                        probs.astype(jnp.float32), lab)
+                return _vote_triple(probs, lab)
 
     def qfn(pp, Xq):
         """The XLA twin over a batch: ``score_rows`` at once, or a scan
@@ -1386,7 +1576,7 @@ def build_quantized_scorer(
         the scan's scores are the unscanned program's, bit for bit
         (tests/test_qtrees_chain.py holds it)."""
         n = Xq.shape[0]
-        rows = twin_rows(n, T, S, L, twin_budget)
+        rows = twin_rows(n, T, S, L, twin_budget, heap_depth)
         if rows >= n:
             return score_rows(pp, Xq)
         steps = -(-n // rows)
@@ -1550,6 +1740,35 @@ def build_quantized_scorer(
     if backend == "pallas":
         return None  # forced pallas but not eligible
 
+    if heap_depth:
+        # the complete form's tables (``complete_heap``), each with the
+        # trees minor: the path matrix and its counts stay on the host
+        node, owner = complete_heap(P, heap_depth)
+        N = (1 << heap_depth) - 1
+        tt, ss = np.nonzero(node >= 0)
+        nn = node[tt, ss]
+        heap = {
+            "heap_feat": np.zeros((N, T), np.int32),
+            "heap_qthr": np.zeros((N, T), dtype),
+            "heap_dleft": np.zeros((N, T), bool),
+        }
+        for key, src in (("heap_feat", feat), ("heap_qthr", qthr),
+                         ("heap_dleft", dleft)):
+            heap[key][nn, tt] = src[tt, ss]
+        if classification:
+            # [T, L, C] shares, and a single tree's label beside them
+            tbl = phi.astype(np.float32) + plo.astype(np.float32)
+            if method == "single":
+                tbl = np.concatenate([tbl, lab_f[:, :, None]], axis=2)
+        elif fused_linear:
+            tbl = vhi.astype(np.float32) + vlo.astype(np.float32)
+        else:
+            tbl = vals
+        got = np.take_along_axis(
+            tbl, owner.reshape(owner.shape + (1,) * (tbl.ndim - 2)), axis=1)
+        heap["heap_vals"] = np.ascontiguousarray(np.moveaxis(got, 0, -1))
+        params = heap
+
     jit_fn = jax.jit(qfn, donate_argnums=(1,) if config.donate_batches else ())
     codecs = ctx.codecs
 
@@ -1572,6 +1791,8 @@ def build_quantized_scorer(
         fl = layouts_mod.flags(layout)
         if fl is None or not fl or not fl <= {"bfs", "wirepack"}:
             return None
+        if heap_depth and "bfs" in fl:
+            return None  # the heap is already breadth-first
         p2 = dict(params)
         if "bfs" in fl:
             perm = layouts_mod.bfs_split_order(params["P_i8"])
@@ -1621,6 +1842,7 @@ def build_quantized_scorer(
         labels=out_labels,
         event_class=event_class,
         twin_budget=twin_budget,
+        twin_depth=heap_depth,
         model_hash=model_hash,
         _fused_inner=fused_inner,
         _encode_stage=encode_stage,

@@ -283,7 +283,7 @@ class BoundScorer:
     Shared by the static and dynamic pipelines so the probe/backend/
     decode logic cannot diverge between them."""
 
-    def __init__(self, key: str, model, use_quantized: bool):
+    def __init__(self, key: str, model, use_quantized: bool, metrics=None):
         self.key = key
         self.model = model
         probe = getattr(model, "quantized_scorer", None)
@@ -291,6 +291,10 @@ class BoundScorer:
         self.backend = (
             f"rank_wire_{self.q.backend}" if self.q is not None else "f32"
         )
+        if metrics is not None and self.backend == "rank_wire_xla":
+            # the XLA twin's form, once a scorer is adopted: the depth
+            # of its select tree, 0 where it contracts its path matrix
+            metrics.gauge("qtrees_twin_depth").set(float(self.q.twin_depth))
 
         def decode(out, n):
             if self.q is not None:
@@ -2175,7 +2179,8 @@ class BlockPipeline(BlockPipelineBase):
             failover=failover,
             state=state,
         )
-        self._bound = BoundScorer("static", model, use_quantized)
+        self._bound = BoundScorer("static", model, use_quantized,
+                                  self.metrics)
         self.backend = self._bound.backend
         self.metrics.counter(f"scorer_backend_{self.backend}").inc()
         if self._state is not None:

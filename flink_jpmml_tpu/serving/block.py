@@ -253,7 +253,8 @@ class DynamicBlockPipeline(BlockPipelineBase):
         # probe is memoized on the CompiledModel so this is cheap, and
         # nothing pins superseded models (in-flight batches hold their
         # own decode references until sunk; the registry owns the rest)
-        bound = BoundScorer(best_mid.key(), best_model, self._use_quantized)
+        bound = BoundScorer(best_mid.key(), best_model, self._use_quantized,
+                            self.metrics)
         if hasattr(best_model, "with_dispatch_state"):
             # sharded serving: record the window geometry + partition
             # ownership on the adopted model so a degraded-mesh rebuild

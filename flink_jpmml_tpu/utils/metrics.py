@@ -815,6 +815,10 @@ _GAUGE_MERGE_MAX_PREFIXES = (
     # to evict), so MAX; resident_keys stays a sum (tables are
     # worker-local, key spaces disjoint by lane routing)
     "state_occupancy_frac",
+    # the XLA twin's form (runtime/block.py): the depth of its select
+    # tree, a property of the served forest — a sum over workers means
+    # nothing; the fleet value is the deepest
+    "qtrees_twin_depth",
 )
 _GAUGE_MERGE_MIN_PREFIXES = (
     "slo_ok", "watermark_ts", "watermark_stage_ts", "adaptive_batch",

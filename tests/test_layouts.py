@@ -20,6 +20,7 @@ from flink_jpmml_tpu.compile.qtrees import QuantizedWire, build_quantized_scorer
 from flink_jpmml_tpu.pmml import parse_pmml, parse_pmml_file
 
 from test_qtrees import _forest_xml
+from test_qtrees_chain import N_FEATURES, chain_pmml, leafwise_forest
 
 
 def _doc(tmp_path, **kw):
@@ -120,16 +121,25 @@ class TestBfsSplitOrder:
         assert perm[0].tolist() == [2, 0, 1]
 
     def test_xla_bfs_bit_exact(self, tmp_path):
-        doc = _doc(tmp_path, n_trees=15, depth=4, n_features=6)
+        # the BFS order permutes the path form's splits: leaf-wise trees
+        # deeper than a heap takes. A shallow complete forest runs the
+        # complete form, breadth-first already, and has no bfs variant
+        shallow = build_quantized_scorer(
+            _doc(tmp_path, n_trees=15, depth=4, n_features=6),
+            batch_size=64, backend="xla")
+        assert shallow.twin_depth == 4
+        assert shallow.build_variant("bfs") is None
+        doc = parse_pmml(chain_pmml(leafwise_forest()))
         q_ref = build_quantized_scorer(doc, batch_size=64, backend="xla")
         q = build_quantized_scorer(doc, batch_size=64, backend="xla")
+        assert q.twin_depth == 0
         built = q.build_variant("bfs")
         assert built is not None
         q.adopt_variant(built, "bfs")
         assert q.layout == "bfs"
         rng = np.random.default_rng(0)
         for n in (64, 64 - 9, 2 * 64 + 7):
-            X = _adversarial_X(rng, n, 6)
+            X = _adversarial_X(rng, n, N_FEATURES)
             ref = np.asarray(
                 q_ref.predict_wire(q_ref.wire.encode(X)), np.float32
             )

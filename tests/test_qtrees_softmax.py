@@ -62,9 +62,11 @@ def triple(q, X):
 
 
 def _scanned(monkeypatch, q):
+    """A budget of 8 records a step, in the form the twin runs."""
     m = q._meta
     monkeypatch.setattr(qtrees, "_device_budget", lambda: qtrees.twin_bytes(
-        8, int(m["trees"]), int(m["splits"]), int(m["leaves"])))
+        8, int(m["trees"]), int(m["splits"]), int(m["leaves"]),
+        q.twin_depth))
 
 
 @pytest.mark.parametrize("backend", ["twin", "scanned_twin", "pallas"])

@@ -303,3 +303,47 @@ def test_twin_writes_its_rank_codes_at_most_once_a_step(
     writers = [op for op, sizes in body if codes in sizes]
     assert "copy" not in writers and "reshape" not in writers, writers
     assert writers.count("fusion") <= 1, writers
+
+
+def test_the_complete_form_walks_in_one_fusion_within_twin_bytes(
+        one_chip, no_compile_cache, monkeypatch):
+    """The twin's complete form on a covtype-sized forest (21,000
+    depth-6 trees in seven class groups, 54 fields), scanned in steps of
+    512 records as a v5e's budget makes it: the select tree writes no
+    level of ``[rows, 2**d, T]`` values for any ``d`` above 0 (the walk
+    is one fusion), no split's compares are copied, and the program keeps
+    no more temporaries than ``twin_bytes`` states. The scorer is built
+    as on the chip (its CPU branch off) from a small forest of the same
+    tree shape; the parameters are handed in at the real size."""
+    import tempfile
+
+    import jax
+    import jax.numpy as jnp
+
+    from flink_jpmml_tpu.compile import packs, qtrees
+    from flink_jpmml_tpu.pmml import parse_pmml
+    from test_qtrees_softmax import xgbmc
+
+    rows, n, small, T = 512, 4096, 21, 21000
+    monkeypatch.setattr(qtrees.common, "backend_is_cpu", lambda: False)
+    monkeypatch.setattr(packs, "member_bytes_cap", lambda: 0)
+    monkeypatch.setattr(qtrees, "_device_budget", lambda: qtrees.twin_bytes(
+        rows, small, 63, 64, 6))
+    g = xgbmc.gen_arrays(46, 3, 7, 6, 54, 10, 255, 0.3, 0.5)
+    with tempfile.TemporaryDirectory() as d:
+        doc = parse_pmml(open(xgbmc.write_pmml(g, d)).read())
+    q = qtrees.build_quantized_scorer(doc)
+    assert q.backend == "xla" and q.twin_depth == 6 and q.twin_rows(n) == rows
+    params = {k: jax.ShapeDtypeStruct(
+        tuple(T if s == small else s for s in v.shape), v.dtype,
+        sharding=one_chip) for k, v in q.params.items()}
+    compiled = q._jit_fn.lower(params, jax.ShapeDtypeStruct(
+        (n, 54), jnp.uint8, sharding=one_chip)).compile()
+    body = _while_body_results(compiled.as_text())
+    assert body, "the twin is not scanned"
+    levels = {rows * T * 2 ** d for d in range(1, 6)}
+    assert not [op for op, sizes in body if levels & set(sizes)], body
+    assert not [op for op, sizes in body
+                if op.startswith("copy") and rows * T in sizes]
+    assert compiled.memory_analysis().temp_size_in_bytes <= qtrees.twin_bytes(
+        rows, T, 63, 64, 6)
